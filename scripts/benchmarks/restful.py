@@ -38,10 +38,6 @@ sys.path.insert(0, REPO)
 
 import numpy as np  # noqa: E402
 
-from vearch_tpu.utils import apply_jax_platform_env  # noqa: E402
-
-apply_jax_platform_env()
-
 from tests.datasets import make_easy, make_hard  # noqa: E402
 from vearch_tpu.cluster.standalone import StandaloneCluster  # noqa: E402
 from vearch_tpu.engine.engine import Engine, SearchRequest  # noqa: E402

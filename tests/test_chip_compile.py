@@ -1,0 +1,237 @@
+"""Compile the main-path programs for the chip, without the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described and not attached (v5e:2x2). Each case lowers one jitted entry
+point of the served IVFPQ path at the widths `chip_smoke.py` serves
+(d=128, the mirror/store capacities the code picks for 1,000,000 rows,
+nlist=2048, m=32, rerank 256 and 128, k=10 at its fetch-k tier) and compiles it:
+what the chip's compiler refuses — a block shape Mosaic cannot tile, a
+program that does not fit 16 GB — fails HERE and costs no chip time.
+A compile that passes is not a chip run and says nothing about speed.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and under xdist
+every worker imports this file but only one runs it. Keep every such
+test in THIS file (on-chip-measurement guide, section 2).
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from vearch_tpu.engine.raw_vector import RawVectorStore
+from vearch_tpu.engine.types import IndexParams, MetricType
+from vearch_tpu.index.int8_mirror import Int8Mirror
+from vearch_tpu.index.ivf import IVFPQIndex
+from vearch_tpu.ops import ivf as ivf_ops
+from vearch_tpu.ops import kmeans as km
+from vearch_tpu.ops import pallas_kernels, perf_model
+from vearch_tpu.ops import pq as pq_ops
+from vearch_tpu.ops.distance import brute_force_search
+from vearch_tpu.parallel import sharded
+from vearch_tpu.parallel.mesh import ShardedRowCache
+
+ROWS, D, K = 1_000_000, 128, 10
+RERANK, RERANK_SHALLOW = 256, 128  # chip_smoke.py's two depths
+HBM_BYTES = 16e9  # one v5e chip
+L2 = MetricType.L2
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def widths():
+    """What the code itself picks for the smoke's deployment: the index
+    defaults, the int8 mirror's and the raw store's capacity after
+    1,000,000 rows arrive in the smoke's 5,000-row batches (1-wide
+    stand-ins: capacity growth does not depend on the dimension)."""
+    index = IVFPQIndex(
+        IndexParams("IVFPQ", L2, {"ncentroids": 2048, "nsubvector": 32}),
+        RawVectorStore(D))
+    mirror = Int8Mirror(1)
+    mirror.append_quantized(np.zeros((ROWS, 1), np.int8),
+                            np.zeros(ROWS, np.float32),
+                            np.zeros(ROWS, np.float32))
+    store = RawVectorStore(1)
+    for _ in range(ROWS // 5000):
+        store.add(np.zeros((5000, 1), np.float32))
+    return {
+        "n_mirror": mirror._h8.shape[0],   # 512-aligned
+        "n_store": store.capacity,         # doubling
+        "nlist": index.nlist, "m": index.m, "ksub": index.ksub,
+        "nprobe": index.default_nprobe, "sample": index.train_sample,
+        "iters": index.train_iters,
+        "fetch_k": perf_model.bucket_fetch_k(K),
+    }
+
+
+def _fused_args(S, b, n_mirror, n_store):
+    return (S((b, D), jnp.float32), S((n_mirror, D), jnp.int8),
+            S((n_mirror,), jnp.float32), S((n_mirror,), jnp.float32),
+            S((n_mirror,), jnp.bool_), S((n_store, D), jnp.float32),
+            S((n_store,), jnp.float32))
+
+
+def _shapes(sharding):
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=sharding)
+
+
+def _report(name, compiled):
+    m = compiled.memory_analysis()
+    args, temp = m.argument_size_in_bytes, m.temp_size_in_bytes
+    print(f"{name}: arguments {args / 1e9:.3f} GB, temp {temp / 1e9:.3f} GB")
+    assert args + temp < HBM_BYTES, (name, args, temp)
+    return args, temp
+
+
+def test_widths_are_the_ones_the_code_picks(widths):
+    assert widths["n_mirror"] == 1_000_448 and widths["n_store"] == 1 << 20
+    assert (widths["nlist"], widths["m"], widths["ksub"]) == (2048, 32, 256)
+    assert widths["fetch_k"] == 16 and widths["sample"] == 262_144
+
+
+@pytest.mark.parametrize("b,r", [(8, RERANK), (64, RERANK), (1024, RERANK),
+                                 (64, RERANK_SHALLOW)])
+def test_fused_scan_rerank_compiles(one_chip, widths, b, r):
+    """The default hot path at the scheduler's row buckets (8, 64 and
+    the largest, `perf_model.ROW_BUCKETS`). B=1024 is the heavy one: two
+    [B, N] f32 score buffers of temp, half the HBM."""
+    assert {8, 64, 1024} <= set(perf_model.ROW_BUCKETS)
+    compiled = ivf_ops.int8_scan_rerank.lower(
+        *_fused_args(_shapes(one_chip), b, widths["n_mirror"],
+                     widths["n_store"]),
+        r, widths["fetch_k"], scan_metric=L2, rerank_metric=L2,
+        topk_mode="auto", storage="int8").compile()
+    _, temp = _report(f"int8_scan_rerank[B={b},r={r}]", compiled)
+    if b == 1024:
+        assert temp >= 2 * perf_model.scan_peak_bytes(b, widths["n_mirror"])
+
+
+def _probe_args(S, b, nlist, cap, n_valid):
+    return (S((b, D), jnp.float32), S((nlist, D), jnp.float32),
+            S((nlist, cap, D), jnp.int8), S((nlist,), jnp.float32),
+            S((nlist, cap), jnp.float32), S((nlist, cap), jnp.int32),
+            S((n_valid,), jnp.bool_))
+
+
+# cap: the smallest 128-multiple holding 1M/2048 rows per list, and a
+# skewed publish four times that (IVFPQIndex._bucket_shape)
+@pytest.mark.parametrize("b,cap", [(8, 512), (64, 512), (64, 2048)])
+def test_pallas_probe_kernel_compiles(one_chip, widths, monkeypatch, b, cap):
+    """The TPU-default probe kernel, compiled by Mosaic (steered from
+    here: jax.default_backend() is still the CPU in this process)."""
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    assert cap % 128 == 0 and cap >= ROWS / widths["nlist"]
+    compiled = pallas_kernels.ivfpq_probe_search_pallas.lower(
+        *_probe_args(_shapes(one_chip), b, widths["nlist"], cap,
+                     widths["n_store"]),
+        widths["nprobe"], RERANK, True).compile()
+    _report(f"ivfpq_probe_search_pallas[B={b},cap={cap}]", compiled)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_xla_probe_scan_and_rerank_compile(one_chip, widths):
+    """`probe_kernel: "xla"` and the exact rerank both probe kernels
+    hand their candidates to."""
+    S = _shapes(one_chip)
+    _report("ivfpq_candidates[B=64]", ivf_ops.ivfpq_candidates.lower(
+        *_probe_args(S, 64, widths["nlist"], 512, widths["n_store"]),
+        widths["nprobe"], RERANK, L2).compile())
+    _report("exact_rerank[B=64]", ivf_ops.exact_rerank.lower(
+        S((64, D), jnp.float32), S((64, RERANK), jnp.int32),
+        S((widths["n_store"], D), jnp.float32),
+        S((widths["n_store"],), jnp.float32),
+        widths["fetch_k"], L2).compile())
+
+
+@pytest.mark.parametrize("step", ["train_kmeans", "assign_sample",
+                                  "assign_all_rows", "train_pq",
+                                  "encode_pq"])
+def test_build_steps_compile(one_chip, widths, step):
+    """What the 1M build dispatches: coarse k-means over the training
+    sample, assignment of the sample and of every row at absorb, PQ
+    codebook training on the sample's residuals, PQ encode of all rows."""
+    S = _shapes(one_chip)
+    sample, nlist, iters = widths["sample"], widths["nlist"], widths["iters"]
+    cents = S((nlist, D), jnp.float32)
+    books = S((widths["m"], widths["ksub"], D // widths["m"]), jnp.float32)
+    lowered = {
+        "train_kmeans": lambda: km.train_kmeans.lower(
+            S((sample, D), jnp.float32), k=nlist, iters=iters),
+        "assign_sample": lambda: km.assign_clusters.lower(
+            S((sample, D), jnp.float32), cents),
+        "assign_all_rows": lambda: km.assign_clusters.lower(
+            S((ROWS, D), jnp.float32), cents),
+        "train_pq": lambda: jax.jit(functools.partial(
+            pq_ops.train_pq, m=widths["m"], ksub=widths["ksub"],
+            iters=iters)).lower(S((sample, D), jnp.float32)),
+        "encode_pq": lambda: pq_ops.encode_pq.lower(
+            S((ROWS, D), jnp.float32), books),
+    }[step]()
+    _report(step, lowered.compile())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_brute_force_search_compiles(one_chip, widths, dtype):
+    """The UNINDEXED fall-back and the shadow sampler's exact scan, at
+    the store's default dtype and at bfloat16."""
+    S, n = _shapes(one_chip), widths["n_store"]
+    compiled = brute_force_search.lower(
+        S((64, D), dtype), S((n, D), dtype), S((n,), jnp.bool_),
+        widths["fetch_k"], L2, S((n,), jnp.float32)).compile()
+    _report(f"brute_force_search[{jnp.dtype(dtype).name}]", compiled)
+
+
+def test_mesh_fused_program_compiles_for_four_chips(topo, one_chip, widths):
+    """The mesh-spanning partition's ONE program on a 4-device mesh of
+    the described chips — shapes only. Each device holds about a
+    quarter of the bytes the single-device program takes as arguments
+    (0.678 GB, as test_fused_scan_rerank_compiles prints), and the
+    candidate merge is a collective."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "query"))
+    n_mirror = ShardedRowCache(align=512).capacity(mesh, ROWS)
+    n_store = ShardedRowCache(align=128).capacity(mesh, ROWS)
+
+    def S(shape, dt, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dt, sharding=NamedSharding(mesh, P(*spec)))
+
+    fn = sharded._ivf_search_fn(mesh, RERANK, widths["fetch_k"], L2, L2,
+                                "auto", "int8", 0)
+    compiled = fn.lower(
+        S((n_mirror, D), jnp.int8, "data", None),
+        S((n_mirror,), jnp.float32, "data"),
+        S((n_mirror,), jnp.float32, "data"),
+        S((n_mirror,), jnp.bool_, "data"),
+        S((n_store, D), jnp.float32, "data", None),
+        S((n_store,), jnp.float32, "data"),
+        S((64, D), jnp.float32, "query", None)).compile()
+    per_device, _ = _report("sharded_ivf_search[4 chips, B=64]", compiled)
+    single = sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize
+        for a in _fused_args(_shapes(one_chip), 64, widths["n_mirror"],
+                             widths["n_store"]))
+    assert 0.20 < per_device / single < 0.30, (per_device, single)
+    assert "all-gather" in compiled.as_text()

@@ -91,7 +91,7 @@ def test_vl101_parallel_is_sanctioned_dispatch_layer(tmp_path):
     jitted tail-append writers as a first-class dispatch layer."""
     found = _lint_file(tmp_path, "vearch_tpu/parallel/fine.py", """\
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def build(mesh, specs, body):
             return jax.jit(shard_map(body, mesh=mesh, in_specs=specs,
@@ -104,7 +104,7 @@ def test_vl101_shard_map_outside_dispatch_layers_fires(tmp_path):
     """shard_map is a dispatch construct: the cluster plane minting one
     directly (instead of calling parallel/) still trips VL101."""
     found = _lint_file(tmp_path, "cluster/rogue_mesh.py", """\
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def scan(mesh, specs, body):
             return shard_map(body, mesh=mesh, in_specs=specs,

@@ -1,5 +1,7 @@
-"""Pallas probe-kernel tests (interpret mode on the CPU test mesh; the
-same code compiles via Mosaic on TPU)."""
+"""Pallas probe-kernel tests: results, in interpret mode — which the
+kernels choose on the `cpu` backend only. Mosaic compiles the same code
+on the `tpu` backend; tests/test_chip_compile.py compiles it for a
+described v5e, and `chip_smoke.py` runs it on the chip."""
 
 import numpy as np
 import jax
@@ -11,6 +13,7 @@ from vearch_tpu.engine.types import (
     DataType, FieldSchema, IndexParams, MetricType, TableSchema,
 )
 from vearch_tpu.ops.ivf import _coarse_probes, ivfpq_candidates
+from vearch_tpu.ops import pallas_kernels
 from vearch_tpu.ops.pallas_kernels import ivf_probe_dots, ivfpq_probe_search_pallas
 
 
@@ -75,3 +78,17 @@ def test_engine_probe_mode_uses_pallas(rng):
     res2 = eng.search(SearchRequest(vectors={"v": vecs[:5]}, k=3,
                                     index_params={"probe_kernel": "xla"}))
     assert [r.items[0].key for r in res2] == [f"d{i}" for i in range(5)]
+
+
+@pytest.mark.parametrize("backend,want", [
+    ("tpu", False), ("cpu", True), ("gpu", RuntimeError),
+    ("some_plugin", RuntimeError)])
+def test_interpret_mode_only_on_cpu_backend(monkeypatch, backend, want):
+    """Compiled on the chip, interpreted on the CPU test backend, and an
+    error anywhere else — never a silent interpreter on a device path."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match=backend):
+            pallas_kernels._interpret()
+    else:
+        assert pallas_kernels._interpret() is want

@@ -94,7 +94,6 @@ def test_profile_dispatches_match_documented_per_ivfpq_path():
     cases = {
         "ivfpq_full_fused": {"scan_mode": "full"},
         "ivfpq_full_unfused": {"scan_mode": "full", "fused_rerank": False},
-        "ivfpq_full_pallas": {"scan_mode": "full", "scan_kernel": "pallas"},
         "ivfpq_probe": {"scan_mode": "probe"},
     }
     for path, params in cases.items():

@@ -497,3 +497,53 @@ def test_rabitq_absorb_binary_search_under_lockcheck(rng):
         _assert_static_covers(edges)
     finally:
         lockcheck.reset()
+
+
+def test_concurrent_first_placement_of_raw_store():
+    """Searchers racing the FIRST device placement of a raw store (two
+    request threads on a cold engine; the shadow-recall sampler beside
+    a mesh partition's first single-device request — how the
+    four-device chip_smoke phase found it): before the placement lock a
+    second caller saw `_device` set with `_device_sqnorm` still None and
+    crashed, or tail-flushed into a half-built buffer. Every caller
+    must get the whole buffer and the sqnorm column that belongs to it."""
+    import sys
+
+    from vearch_tpu.engine.raw_vector import RawVectorStore
+    from vearch_tpu.ops.distance import host_sqnorms
+
+    rows = np.random.default_rng(3).standard_normal(
+        (40_000, 64)).astype(np.float32)
+    workers = 4 * (os.cpu_count() or 2)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for _ in range(3):
+            store = RawVectorStore(64)
+            store.add(rows)
+            barrier = threading.Barrier(workers)
+            out, errs = [], []
+
+            def place():
+                try:
+                    barrier.wait(timeout=30)
+                    out.append(store.device_buffer())
+                except Exception as e:  # noqa: BLE001 — the assertion
+                    errs.append(e)
+
+            threads = [threading.Thread(target=place)
+                       for _ in range(workers)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+            assert not errs, errs
+            assert len(out) == workers
+            want_sq = host_sqnorms(rows)
+            for base, sqn, n in out:
+                assert n == rows.shape[0]
+                np.testing.assert_array_equal(np.asarray(base)[:n], rows)
+                np.testing.assert_array_equal(np.asarray(sqn)[:n], want_sq)
+    finally:
+        sys.setswitchinterval(old)

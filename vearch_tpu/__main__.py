@@ -17,10 +17,6 @@ ELASTIC_VERBS = ("rebalance", "drain", "split", "migrate", "plan", "jobs")
 
 
 def main(argv: list[str] | None = None) -> int:
-    from vearch_tpu.utils import apply_jax_platform_env
-
-    apply_jax_platform_env()
-
     if argv is None:
         argv = sys.argv[1:]
     if argv and argv[0] in ELASTIC_VERBS:
@@ -90,6 +86,13 @@ def main(argv: list[str] | None = None) -> int:
     stop = threading.Event()
     signal.signal(signal.SIGINT, lambda *a: stop.set())
     signal.signal(signal.SIGTERM, lambda *a: stop.set())
+
+    if args.role in ("standalone", "ps"):
+        # the roles that compile device programs: persist them, before
+        # the first compile, so a restart warms up from disk
+        from vearch_tpu.utils import enable_compilation_cache
+
+        enable_compilation_cache()
 
     if args.role == "standalone":
         from vearch_tpu.cluster.standalone import StandaloneCluster

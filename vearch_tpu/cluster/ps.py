@@ -86,6 +86,7 @@ def _profile_from_timing(timing: dict) -> dict:
             "predicted": timing.get("predicted_dispatches"),
             "predicted_scan_bytes": timing.get("predicted_scan_bytes"),
             "per_dispatch_ms": per_dispatch,
+            "kernels": timing.get("dispatch_kernels", {}),
         },
     }
     if "doc_count" in timing:
@@ -165,9 +166,6 @@ class PSServer:
         hbm_drift_slack_mb: int = 64,
         admission_queue_limit: int = 0,
     ):
-        from vearch_tpu.utils import apply_jax_platform_env
-
-        apply_jax_platform_env()  # before any engine touches jax
         self.data_dir = data_dir
         os.makedirs(data_dir, exist_ok=True)
         self.engines: dict[int, Engine] = {}

@@ -141,11 +141,6 @@ class _FieldBuild:
 
 class Engine:
     def __init__(self, schema: TableSchema, data_dir: str | None = None):
-        from vearch_tpu.utils import enable_compilation_cache
-
-        # opt-in via VEARCH_COMPILE_CACHE: compiled search programs
-        # survive restarts, so warmup after a restart is a disk read
-        enable_compilation_cache()
         self.schema = schema
         self.data_dir = data_dir
         self.table = Table(schema)
@@ -1298,6 +1293,8 @@ class Engine:
         tags = capture.tags
         trace["dispatches"] = tags
         trace["dispatch_count"] = len(tags)
+        if capture.kernels:
+            trace["dispatch_kernels"] = dict(capture.kernels)
         for tag, t0, t1 in capture.events:
             if t1 is not None:
                 key = f"dispatch_{tag}_ms"
@@ -1403,7 +1400,7 @@ class Engine:
         try:
             if mirror is not None and getattr(mirror, "_h8", None) is not None:
                 return perf_model.scan_traffic_bytes(
-                    1, int(mirror._h8.shape[0]), d, "xla_full"
+                    int(mirror._h8.shape[0]), d
                 )
         except Exception:
             pass

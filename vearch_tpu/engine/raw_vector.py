@@ -32,6 +32,7 @@ import numpy as np
 
 from vearch_tpu.ops import perf_model
 from vearch_tpu.ops.distance import host_sqnorms
+from vearch_tpu.tools import lockcheck
 
 
 class RawVectorStore:
@@ -48,6 +49,13 @@ class RawVectorStore:
         self._device: jax.Array | None = None  # [capacity, d] store_dtype
         self._device_sqnorm: jax.Array | None = None  # [capacity] f32
         self._device_rows = 0  # rows already mirrored to device
+        # concurrent first placements race: a second searcher (another
+        # request thread, the shadow-recall sampler) saw `_device` set
+        # and `_device_sqnorm` still None mid-placement and crashed, or
+        # tail-flushed into a half-built buffer and served wrong rows
+        # (found by the four-device chip_smoke phase). One leaf lock
+        # serializes device placement, as Int8Mirror's does.
+        self._flush_lock = lockcheck.make_lock("raw_store_flush")
 
     @property
     def count(self) -> int:
@@ -87,6 +95,10 @@ class RawVectorStore:
         capacity changed; otherwise the tail lands via dynamic_update_slice
         on the existing device array.
         """
+        with self._flush_lock:
+            return self._device_buffer_locked()
+
+    def _device_buffer_locked(self) -> tuple[jax.Array, jax.Array, int]:
         # snapshot n once: a concurrent upsert may advance self._n while we
         # flush; rows past the snapshot flush on the next call
         n = self._n
@@ -127,9 +139,6 @@ class RawVectorStore:
         stays bit-identical to a full rebuild."""
         from vearch_tpu.parallel.mesh import ShardedRowCache
 
-        if self._sh_cache is None:
-            self._sh_cache = ShardedRowCache(align=128, sqnorm_of=0)
-
         def build(cap):
             host = np.zeros((cap, self.dimension), dtype=np.float32)
             host[: self._n] = self._host[: self._n]
@@ -142,8 +151,11 @@ class RawVectorStore:
                 win[:m] = self._host[lo : lo + m]
             return (win.astype(self.store_dtype),)
 
-        (base,), _ = self._sh_cache.get(mesh, self._n, build, append)
-        return base, self._sh_cache.sqnorm, self._n
+        with self._flush_lock:
+            if self._sh_cache is None:
+                self._sh_cache = ShardedRowCache(align=128, sqnorm_of=0)
+            (base,), _ = self._sh_cache.get(mesh, self._n, build, append)
+            return base, self._sh_cache.sqnorm, self._n
 
     # -- persistence ---------------------------------------------------------
 

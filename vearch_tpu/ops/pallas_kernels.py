@@ -11,8 +11,11 @@ bucket block index_map reads it to DMA exactly the probed bucket into
 VMEM (double-buffered across grid steps by the pallas pipeline), and the
 MXU scores it — one pass over exactly the probed data.
 
-Falls back to interpret mode off-TPU (the CPU test mesh), so the same
-code path is exercised everywhere.
+Compiled by Mosaic on the `tpu` backend; interpret mode on the `cpu`
+backend only (the test mesh), so the same code path is exercised there.
+Any other backend is an error: a kernel that silently interprets where
+it was meant to compile hides exactly the faults the compile exists to
+find (tests/test_chip_compile.py compiles it for a described v5e).
 """
 
 from __future__ import annotations
@@ -24,9 +27,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from vearch_tpu.ops.perf_model import register_jit
+
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"pallas TPU kernels run compiled on 'tpu' or interpreted on "
+        f"'cpu'; backend is {backend!r}"
+    )
 
 
 def _probe_dots_kernel(probes_ref, q_ref, bucket_ref, out_ref):
@@ -149,182 +162,9 @@ def ivfpq_probe_search_pallas(
     return top_s, jnp.take_along_axis(flat_i, pos, axis=1)
 
 
-# -- fused block-max int8 full scan (r4 review next-7) -----------------------
-#
-# The XLA full-scan path (ops/ivf.py int8_scan_candidates) materialises
-# the [B, N] f32 score matrix in HBM (4 GB at 1024 x 1M), then re-reads
-# it for the block-max stage-1 and again for the stage-2 gather. This
-# kernel computes scores tile-by-tile in VMEM and writes ONLY the
-# [B, N/512] block maxima — one pass over the int8 rows, no score
-# matrix. Stage 2 (XLA, same jit) re-scores just the chosen blocks at
-# f32 — identical candidate semantics to the XLA block-max path.
-# Gated behind IndexParams scan_kernel="pallas" for hardware A/B
-# (scripts/benchmarks/pallas_ab.py is the microbench hook).
-
-_SCAN_TB = 8      # query rows per tile (pads B up; small batches stay cheap)
-_SCAN_TN = 2048   # db rows per tile (int8 tile = TN*d bytes in VMEM)
-_SCAN_BLOCK = 512  # must match ops/ivf.py BLOCK
-
-
-def _blockmax_kernel(q_ref, rows_ref, scale_ref, vsq_ref, valid_ref,
-                     qsq_ref, bmax_ref, l2: bool):
-    """One (query-tile, row-tile) grid step: score [TB, TN] in VMEM,
-    reduce to per-512-block maxima [TB, TN/512]."""
-    q = q_ref[...]          # [TB, d] bf16
-    rows = rows_ref[...]    # [TN, d] int8
-    dots = jax.lax.dot_general(
-        q, rows.astype(jnp.bfloat16),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [TB, TN]
-    dots = dots * scale_ref[...][None, :]
-    if l2:
-        scores = -(qsq_ref[...][:, None] - 2.0 * dots
-                   + vsq_ref[...][None, :])
-    else:
-        scores = dots
-    scores = jnp.where(valid_ref[...][None, :] != 0, scores,
-                       jnp.float32(-3.4e38))
-    tb = scores.shape[0]
-    nb = scores.shape[1] // _SCAN_BLOCK
-    # bf16 block maxima — same precision contract as the XLA stage 1
-    # (selection-only; stage 2 re-ranks at f32)
-    bmax = jnp.max(
-        scores.reshape(tb, nb, _SCAN_BLOCK).astype(jnp.bfloat16), axis=2
-    ).astype(jnp.float32)
-    bmax_ref[...] = bmax
-
-
-@functools.partial(
-    jax.jit, static_argnames=("r", "l2", "interpret_override")
+# compiled-program tracking (ops/perf_model.py), as for the XLA search
+# entry points in ops/ivf.py: the probe kernel's specialisations count
+# in compiled_program_counts() and its compiles reach the flight recorder
+ivfpq_probe_search_pallas = register_jit(
+    "pallas.ivfpq_probe_search", ivfpq_probe_search_pallas
 )
-def int8_blockmax_scan_pallas(
-    queries: jax.Array,    # [B, d] f32
-    approx8: jax.Array,    # [N_pad, d] int8, N_pad % 512 == 0
-    row_scale: jax.Array,  # [N_pad] f32
-    row_vsq: jax.Array,    # [N_pad] f32
-    valid: jax.Array,      # [N_pad] bool
-    r: int,
-    l2: bool = True,
-    interpret_override: bool | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Fused one-pass block-max int8 scan + top-r candidates.
-
-    Semantics match ops/ivf.py _select_topk's block-max mode: bf16
-    block maxima with 2x+8 over-selection choose candidate blocks, the
-    chosen blocks re-rank at f32. Returns ([B, r] scores, [B, r] ids;
-    -1 for masked)."""
-    b, d = queries.shape
-    n_pad = approx8.shape[0]
-    assert n_pad % _SCAN_BLOCK == 0, n_pad
-    nblk = n_pad // _SCAN_BLOCK
-    tb = _SCAN_TB
-    b_pad = -(-b // tb) * tb
-    qf = queries.astype(jnp.float32)
-    if b_pad != b:
-        qf = jnp.pad(qf, ((0, b_pad - b), (0, 0)))
-    qsq = jnp.sum(qf * qf, axis=1)
-    # lane alignment: Mosaic requires the last block dim to be a
-    # 128-multiple on real TPU; d=100 (glove regime) would fail to
-    # compile. Zero-pad the feature dim for the KERNEL inputs only —
-    # zeros contribute nothing to the dots, and stage 2 gathers from
-    # the original unpadded mirror.
-    d_pad = -(-d // 128) * 128
-    qk = qf
-    a8k = approx8
-    if d_pad != d:
-        qk = jnp.pad(qf, ((0, 0), (0, d_pad - d)))
-        a8k = jnp.pad(approx8, ((0, 0), (0, d_pad - d)))
-    # tn must DIVIDE n_pad or the grid truncates (rows past the last
-    # full tile never scanned, their bmax columns uninitialized — review
-    # r5). Mirror capacity is 512-aligned, so 512 always divides; prefer
-    # the largest power-of-two tile that fits.
-    tn = _SCAN_BLOCK
-    for cand in (_SCAN_TN, _SCAN_TN // 2, _SCAN_TN // 4):
-        if cand <= n_pad and n_pad % cand == 0:
-            tn = cand
-            break
-    interp = _interpret() if interpret_override is None \
-        else interpret_override
-
-    grid = (b_pad // tb, n_pad // tn)
-    bmax = pl.pallas_call(
-        functools.partial(_blockmax_kernel, l2=l2),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, d_pad), lambda i, j: (i, 0)),
-            pl.BlockSpec((tn, d_pad), lambda i, j: (j, 0)),
-            pl.BlockSpec((tn,), lambda i, j: (j,)),
-            pl.BlockSpec((tn,), lambda i, j: (j,)),
-            pl.BlockSpec((tn,), lambda i, j: (j,)),
-            pl.BlockSpec((tb,), lambda i, j: (i,)),
-        ],
-        out_specs=pl.BlockSpec(
-            (tb, tn // _SCAN_BLOCK), lambda i, j: (i, j)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b_pad, nblk), jnp.float32),
-        interpret=interp,
-    )(qk.astype(jnp.bfloat16), a8k, row_scale, row_vsq,
-      valid.astype(jnp.int8), qsq)
-    bmax = bmax[:b]
-
-    # -- stage 2 (XLA): over-select blocks, re-score them at f32.
-    # Chunked over queries: the [chunk, S, d] int8 gather is the peak
-    # HBM consumer (review r5 — at B=1024/r=128/d=128 an unchunked
-    # gather is ~4.8 GB, defeating the kernel's memory win); 32-query
-    # chunks bound it to ~150 MB while total traffic is unchanged. The
-    # chunk loop is a lax.scan, NOT an unrolled Python loop: unrolled,
-    # the program size and compile time grew linearly with batch
-    # (32 chunk bodies at B=1024 — VERDICT weak #7); the scan compiles
-    # ONE chunk body regardless of B. The kernel's sweet spot is
-    # small-to-mid batches — at very large B the XLA path's
-    # materialized score matrix amortizes better; that is exactly what
-    # the pallas_ab.py hardware A/B decides.
-    r_eff = min(r, n_pad)
-    nb_sel = max(32, r_eff // 4)
-    nb_sel = min(2 * nb_sel + 8, nblk)
-    _, top_blocks = jax.lax.top_k(bmax, nb_sel)  # [B, nb_sel]
-    qsq_b = jnp.sum(queries.astype(jnp.float32) ** 2, axis=1)
-    chunk = min(32, b)
-    rr = min(r_eff, nb_sel * _SCAN_BLOCK)
-    # pad B up to a chunk multiple: every row's math is independent
-    # (batched matvec + row-wise top_k), so the padded rows change
-    # nothing for real rows and are sliced off below
-    b2 = -(-b // chunk) * chunk
-    qs2 = queries.astype(jnp.float32)
-    if b2 != b:
-        qs2 = jnp.pad(qs2, ((0, b2 - b), (0, 0)))
-        qsq_b = jnp.pad(qsq_b, (0, b2 - b))
-        top_blocks = jnp.pad(top_blocks, ((0, b2 - b), (0, 0)))
-    offs = jnp.arange(_SCAN_BLOCK, dtype=top_blocks.dtype)
-
-    def _stage2(carry, inp):
-        q_c, qsq_c, blocks_c = inp  # [chunk, d], [chunk], [chunk, nb_sel]
-        idx = (blocks_c[:, :, None] * _SCAN_BLOCK
-               + offs[None, None, :]).reshape(
-                   chunk, nb_sel * _SCAN_BLOCK)
-        vecs = approx8[idx]          # [chunk, S, d] int8
-        dots = jax.lax.dot_general(
-            q_c.astype(jnp.bfloat16), vecs.astype(jnp.bfloat16),
-            (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [chunk, S]
-        dots = dots * row_scale[idx]
-        if l2:
-            scores = -(qsq_c[:, None] - 2.0 * dots + row_vsq[idx])
-        else:
-            scores = dots
-        scores = jnp.where(valid[idx], scores, -jnp.inf)
-        top_s, pos = jax.lax.top_k(scores, rr)
-        return carry, (top_s, jnp.take_along_axis(idx, pos, axis=1))
-
-    nchunks = b2 // chunk
-    _, (top_s, ids) = jax.lax.scan(
-        _stage2, None,
-        (qs2.reshape(nchunks, chunk, d),
-         qsq_b.reshape(nchunks, chunk),
-         top_blocks.reshape(nchunks, chunk, nb_sel)),
-    )
-    top_s = top_s.reshape(b2, rr)[:b]
-    ids = ids.reshape(b2, rr)[:b].astype(jnp.int32)
-    return top_s, jnp.where(jnp.isfinite(top_s), ids, -1)

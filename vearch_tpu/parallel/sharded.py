@@ -18,8 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from vearch_tpu.engine.types import MetricType
 from vearch_tpu.ops import kmeans as km
@@ -46,7 +46,7 @@ def _flat_search_fn(mesh: Mesh, k: int, metric: MetricType):
         mesh=mesh,
         in_specs=(P("data", None), P("data"), P("data"), P("query", None)),
         out_specs=(P("query", None), P("query", None)),
-        check_rep=False,
+        check_vma=False,
     )
     def run(b, sqn, v, q):
         local_k = min(k, b.shape[0])
@@ -115,7 +115,7 @@ def _int8_search_fn(mesh: Mesh, r: int, metric: MetricType,
             P("query", None),
         ),
         out_specs=(P("query", None), P("query", None)),
-        check_rep=False,
+        check_vma=False,
     )
     def run(a8, sc, vsq, v, q):
         local_r = min(r, a8.shape[0])
@@ -167,7 +167,7 @@ def _exact_rerank_fn(mesh: Mesh, k: int, metric: MetricType):
             P("query", None), P("query", None), P("data", None), P("data"),
         ),
         out_specs=(P("query", None), P("query", None)),
-        check_rep=False,
+        check_vma=False,
     )
     def run(q, cids, b, sqn):
         shard = jax.lax.axis_index("data")
@@ -267,7 +267,7 @@ def _ivf_search_fn(
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P("query", None), P("query", None)),
-        check_rep=False,
+        check_vma=False,
     )
     def run(*args):
         if probed:
@@ -397,7 +397,7 @@ def _binary_refine_fn(
             P("data", None), P("data"), P("query", None),
         ),
         out_specs=(P("query", None), P("query", None)),
-        check_rep=False,
+        check_vma=False,
     )
     def run(pl, psc, pvsq, a8, msc, mvsq, v, b, bsqn, q):
         local_n = psc.shape[0]
@@ -474,7 +474,7 @@ def _kmeans_step_fn(mesh: Mesh, chunk: int):
         mesh=mesh,
         in_specs=(P("data", None), P("data"), P(None, None), P(None, None)),
         out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )
     def step(xs, vs, c, rs):
         local_chunk = min(chunk, max(256, xs.shape[0]))
