@@ -36,6 +36,7 @@ from vearch_tpu.cluster.metrics import (
 )
 from vearch_tpu.cluster.raft import RaftNode
 from vearch_tpu.obs import accounting
+from vearch_tpu.ops import ivf as ivf_ops
 from vearch_tpu.ops import perf_model
 from vearch_tpu.cluster.rpc import (
     ERR_REQUEST_KILLED,
@@ -43,7 +44,7 @@ from vearch_tpu.cluster.rpc import (
     RpcError,
 )
 from vearch_tpu.tools import lockcheck
-from vearch_tpu.utils import log
+from vearch_tpu.utils import log, mono_us
 
 _log = log.get("ps")
 
@@ -296,12 +297,14 @@ class PSServer:
             max_entries=search_cache_entries)
         self._search_flight = SingleFlight()
 
-        from vearch_tpu.cluster.tracing import NULL_SPAN, SlowLog, Tracer
+        from vearch_tpu.cluster.tracing import GcSpans, SlowLog, Tracer
 
         # spans join the router's trace via the _trace_ctx envelope
         # (reference: PS extracts span context from rpcx metadata,
         # ps/handler_document.go:123-126)
         self.tracer = Tracer("ps", collector_endpoint=trace_collector)
+        # proc.gc: collections belong to the process, not to a request
+        self._gc_spans = GcSpans(self.tracer)
         # slow/killed request ring at GET /debug/slowlog; threshold via
         # /ps/engine/config {"slow_log_ms": ...}
         self.slowlog = SlowLog()
@@ -974,6 +977,8 @@ class PSServer:
             self._recover_partitions()
         self.device_sampler.start()
         self._quality.start()
+        self._gc_spans.install()
+        ivf_ops.set_phase_observer(self._observe_phase)
         if self.master_addr:
             threading.Thread(target=self._heartbeat_loop, daemon=True,
                              name="ps-heartbeat").start()
@@ -983,6 +988,14 @@ class PSServer:
                          name="ps-raft-tick").start()
         threading.Thread(target=self._slow_killer_loop, daemon=True,
                          name="ps-slow-killer").start()
+
+    def _observe_phase(self, name: str, t0: float, t1: float,
+                       tags: dict | None) -> None:
+        """A window the engine noted outside any profiled request
+        (ops/ivf.py note_phase): a process-level span."""
+        self.tracer.record(name, ctx=self.tracer.process_ctx(),
+                           t0_ns=int(t0 * 1e9), t1_ns=int(t1 * 1e9),
+                           tags=tags)
 
     def stop(self, flush: bool = True) -> None:
         self._stop.set()
@@ -998,8 +1011,9 @@ class PSServer:
         for eng in self.engines.values():
             eng.close()
         self.server.stop()
-        if self.tracer.exporter is not None:
-            self.tracer.exporter.close()  # ship the last buffered spans
+        self._gc_spans.remove()
+        ivf_ops.clear_phase_observer(self._observe_phase)
+        self.tracer.close()  # ship the last buffered spans
 
     @property
     def addr(self) -> str:
@@ -1486,7 +1500,12 @@ class PSServer:
                     if node is None:
                         continue
                     if node.applied > self._flushed.get(pid, 0):
-                        self.flush_partition(pid)
+                        # a process-level span, sampled or not: a
+                        # checkpoint competes with every request
+                        with self.tracer.span(
+                                "ps.flush", ctx=self.tracer.process_ctx(),
+                                tags={"partition": pid}):
+                            self.flush_partition(pid)
                 except Exception as e:
                     # a silently failing flush would stop checkpointing
                     # AND WAL truncation — always loud
@@ -1798,15 +1817,27 @@ class PSServer:
         flat `*_ms` breakdown (same contract as the search path)."""
         from vearch_tpu.cluster.tracing import NULL_SPAN
 
+        self._replay_phase_spans(span, timing, pid)
+        if span is NULL_SPAN:
+            return
+        for phase, ms in timing.items():
+            span.set_tag(phase, ms)
+
+    def _replay_phase_spans(self, span, timing: dict, pid: int) -> None:
+        """Take the `_phase_spans` rows off a timing dict and, under a
+        sampled span, replay them as its children with their real
+        windows. A row is `[name, start_us, dur_us]`, with a dict of
+        tags as an optional fourth."""
+        from vearch_tpu.cluster.tracing import NULL_SPAN
+
         pspans = timing.pop("_phase_spans", None) or []
         if span is NULL_SPAN:
             return
         sctx = span.ctx()
-        for name, start_us, dur_us in pspans:
-            self.tracer.record(name, ctx=sctx, start_us=start_us,
-                               dur_us=dur_us, tags={"partition": pid})
-        for phase, ms in timing.items():
-            span.set_tag(phase, ms)
+        for name, start_us, dur_us, *tags in pspans:
+            self.tracer.record(
+                name, ctx=sctx, start_us=start_us, dur_us=dur_us,
+                tags={"partition": pid, **(tags[0] if tags else {})})
 
     def _h_delete(self, body: dict, _parts) -> dict:
         return self._observed_write(body, self._h_delete_inner, _parts)
@@ -1963,12 +1994,31 @@ class PSServer:
             )
 
     def _h_search(self, body: dict, _parts) -> dict:
+        from vearch_tpu.cluster.tracing import NULL_SPAN
+
+        tctx = body.get("_trace_ctx")
+        if not tctx:
+            return self._search(body, NULL_SPAN)
+        # the span opens at the handler's entry, so that the gate wait
+        # and the handler's own work before and after the engine
+        # (ps.pre, ps.post) lie inside it
+        with self.tracer.span("ps.search", ctx=tctx, serve_root=True,
+                              tags={"node": self.node_id}) as span:
+            return self._search(body, span)
+
+    def _search(self, body: dict, span) -> dict:
         import uuid
 
         import numpy as np
 
+        from vearch_tpu.cluster.tracing import NULL_SPAN
         from vearch_tpu.engine.engine import RequestContext, RequestKilled
-
+        # ps.pre / ps.post: the handler thread's own work around the
+        # engine call, the gate wait taken out (so ps.pre has two
+        # pieces); leaves, whose wall minus CPU time is the time this
+        # thread had work and was not running
+        pre = span.child("ps.pre")
+        post = NULL_SPAN
         eng = self._engine(body["partition_id"])
         self._check_read_consistency(body)
         vectors = {
@@ -1993,6 +2043,8 @@ class PSServer:
             and self._search_ewma.get(pid, 0.0) > self.slow_route_ms
         )
         gate = self._slow_gate if slow else self._search_gate
+        span.set_tag("partition", pid)
+        span.set_tag("slow_channel", slow)
         if slow:
             with self._stats_lock:
                 self.slow_routed += 1
@@ -2009,6 +2061,8 @@ class PSServer:
                 f"(limit {self._admission.queue_limit})",
                 retry_after=self._retry_after_s(),
             )
+        pre.finish()
+        gate_span = span.child("ps.gate_wait", {"partition": pid})
         t_gate = time.monotonic()
         with self._stats_lock:
             self._op_waiting["search"] += 1
@@ -2018,6 +2072,7 @@ class PSServer:
             with self._stats_lock:
                 self._op_waiting["search"] -= 1
             self._admission.leave()
+            gate_span.finish()
         if not acquired:
             raise RpcError(
                 429,
@@ -2025,6 +2080,7 @@ class PSServer:
                 % ("slow-search" if slow else "search"),
                 retry_after=self._retry_after_s(),
             )
+        pre = span.child("ps.pre")
         with self._stats_lock:
             self._op_inflight["search"] += 1
         gate_wait_ms = round((time.monotonic() - t_gate) * 1e3, 3)
@@ -2038,8 +2094,6 @@ class PSServer:
             body.get("deadline_ms") or self.request_deadline_ms or 0
         )
         t_start = time.monotonic()
-        # wall anchor for span epochs; all measurement stays monotonic
-        wall0 = time.time() - t_start  # lint: allow[wall-clock] span epoch anchor, correlates with collector time
         ctx = RequestContext(
             rid,
             deadline=(t_start + deadline_ms / 1e3) if deadline_ms else None,
@@ -2053,15 +2107,6 @@ class PSServer:
                                      # a fan-out without killing the
                                      # sibling that shares the rid
                                      "attempt": body.get("_hedge_attempt")}
-        from vearch_tpu.cluster.tracing import NULL_SPAN
-
-        tctx = body.get("_trace_ctx")
-        span = (
-            self.tracer.span("ps.search", ctx=tctx,
-                             tags={"partition": pid, "node": self.node_id,
-                                   "slow_channel": slow})
-            if tctx else NULL_SPAN
-        )
         want_trace = bool(body.get("trace") or body.get("profile"))
         # slowlog/deadline observability needs the phase breakdown even
         # when the client didn't ask for one — force the engine trace on
@@ -2081,76 +2126,64 @@ class PSServer:
         # batch scheduler carries the binding across its thread hop)
         _space_token = accounting.set_space(space_key)
         try:
-            with span:
-                if self.debug_search_delay_ms:
-                    # injected straggler (tests/bench): sleep in small
-                    # chunks so a hedged loser's kill aborts it fast
-                    end = t_start + float(self.debug_search_delay_ms) / 1e3
-                    while True:
-                        ctx.check()
-                        rem = end - time.monotonic()
-                        if rem <= 0:
-                            break
-                        # lint: allow[serving-blocking] env-gated test-only delay, sliced 5ms so ctx.check() keeps it killable
-                        time.sleep(min(0.005, rem))
-                # apply version captured BEFORE the search runs: a
-                # write landing mid-search makes the resulting cache
-                # entry *older*-labeled, so it can never serve a state
-                # the writer was already acknowledged for
-                rnode = self.raft_nodes.get(pid)
-                applied = (int(rnode.applied) if rnode is not None
-                           else int(eng.data_version))
-                out, cache_status, timing = self._cached_search(
-                    eng, pid, applied, body, vectors, ctx, trace
-                )
-                # every response carries the partition's apply version
-                # — the router's entry-validation signal
-                out["apply_version"] = applied
-                # ... and the partition-map epoch, so a router holding a
-                # stale map learns of a split cutover from any response
-                out["map_version"] = self._map_version(pid)
-                span.set_tag("cache", cache_status)
-                if cache_status in ("hit", "coalesced"):
-                    # served from memo: billed to the hitting space at
-                    # zero device cost (no engine work ran for it)
-                    self._accountant.charge("cache_hits", 1,
-                                            space=space_key)
-                if timing is not None:
-                    timing["gate_wait_ms"] = gate_wait_ms
-                    # engine phase windows -> real child spans under
-                    # ps.search (gate wait included), so /debug/traces
-                    # shows where the partition's time went
-                    pspans = timing.pop("_phase_spans", None) or []
-                    if span is not NULL_SPAN:
-                        sctx = span.ctx()
-                        self.tracer.record(
-                            "ps.gate_wait", ctx=sctx,
-                            start_us=int((wall0 + t_gate) * 1e6),
-                            dur_us=int(gate_wait_ms * 1e3),
-                            tags={"partition": pid},
-                        )
-                        for name, start_us, dur_us in pspans:
-                            self.tracer.record(
-                                name, ctx=sctx, start_us=start_us,
-                                dur_us=dur_us, tags={"partition": pid},
-                            )
-                    for phase, ms in timing.items():
-                        span.set_tag(phase, ms)
-                if body.get("profile"):
-                    prof = _profile_from_timing(timing or {})
-                    prof["cache"] = cache_status
-                    if timing is None and cache_status in (
-                            "hit", "coalesced"):
-                        # no engine work happened for THIS response;
-                        # the zero-dispatch claim is explicit, not an
-                        # absence the reader must infer
-                        prof["dispatches"]["path"] = "cache_hit"
-                    out["profile"] = prof
-                if want_trace and timing is not None:
-                    # _cached_search detaches timing from the shared
-                    # payload; re-attach only when the client asked
-                    out["timing"] = timing
-                return out
+            if self.debug_search_delay_ms:
+                # injected straggler (tests/bench): sleep in small
+                # chunks so a hedged loser's kill aborts it fast
+                end = t_start + float(self.debug_search_delay_ms) / 1e3
+                while True:
+                    ctx.check()
+                    rem = end - time.monotonic()
+                    if rem <= 0:
+                        break
+                    # lint: allow[serving-blocking] env-gated test-only delay, sliced 5ms so ctx.check() keeps it killable
+                    time.sleep(min(0.005, rem))
+            # apply version captured BEFORE the search runs: a
+            # write landing mid-search makes the resulting cache
+            # entry *older*-labeled, so it can never serve a state
+            # the writer was already acknowledged for
+            rnode = self.raft_nodes.get(pid)
+            applied = (int(rnode.applied) if rnode is not None
+                       else int(eng.data_version))
+            pre.finish()
+            out, cache_status, timing = self._cached_search(
+                eng, pid, applied, body, vectors, ctx, trace
+            )
+            post = span.child("ps.post")
+            # every response carries the partition's apply version
+            # — the router's entry-validation signal
+            out["apply_version"] = applied
+            # ... and the partition-map epoch, so a router holding a
+            # stale map learns of a split cutover from any response
+            out["map_version"] = self._map_version(pid)
+            span.set_tag("cache", cache_status)
+            if cache_status in ("hit", "coalesced"):
+                # served from memo: billed to the hitting space at
+                # zero device cost (no engine work ran for it)
+                self._accountant.charge("cache_hits", 1,
+                                        space=space_key)
+            if timing is not None:
+                timing["gate_wait_ms"] = gate_wait_ms
+                # engine phase windows -> real child spans under
+                # ps.search, so /debug/traces shows where the
+                # partition's time went
+                self._replay_phase_spans(span, timing, pid)
+                for phase, ms in timing.items():
+                    span.set_tag(phase, ms)
+            if body.get("profile"):
+                prof = _profile_from_timing(timing or {})
+                prof["cache"] = cache_status
+                if timing is None and cache_status in (
+                        "hit", "coalesced"):
+                    # no engine work happened for THIS response;
+                    # the zero-dispatch claim is explicit, not an
+                    # absence the reader must infer
+                    prof["dispatches"]["path"] = "cache_hit"
+                out["profile"] = prof
+            if want_trace and timing is not None:
+                # _cached_search detaches timing from the shared
+                # payload; re-attach only when the client asked
+                out["timing"] = timing
+            return out
         except RequestKilled as e:
             reason = ctx.reason_code or "operator"
             self._killed_total.inc(reason, space_lbl)
@@ -2159,7 +2192,7 @@ class PSServer:
             if span is NULL_SPAN:
                 self.tracer.record(
                     "ps.search",
-                    start_us=int((wall0 + t_start) * 1e6),
+                    start_us=mono_us(t_start),
                     dur_us=int((time.monotonic() - t_start) * 1e6),
                     tags={"partition": pid, "request_id": rid,
                           "kill_reason": reason},
@@ -2207,6 +2240,7 @@ class PSServer:
                     "dispatches": t.get("dispatches"),
                     "trace_id": span.trace_id or None,
                 })
+            post.finish()
 
     def _cached_search(self, eng, pid, applied, body, vectors, ctx,
                        trace):
@@ -2689,8 +2723,6 @@ class PSServer:
 
     def _run_split(self, pid: int, job: dict) -> None:
         t0 = time.monotonic()
-        # wall anchor for span epochs; measurement stays monotonic
-        wall0 = time.time() - t0  # lint: allow[wall-clock] span epoch anchor, correlates with collector time
         state = {"phase": "copy", "t": t0}
 
         def enter_phase(name: str) -> None:
@@ -2698,7 +2730,7 @@ class PSServer:
             prev, t_prev = state["phase"], state["t"]
             self.tracer.record(
                 f"split.{prev}",
-                start_us=int((wall0 + t_prev) * 1e6),
+                start_us=mono_us(t_prev),
                 dur_us=int((now - t_prev) * 1e6),
                 tags={"partition": pid},
             )

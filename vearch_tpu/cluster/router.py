@@ -301,8 +301,7 @@ class RouterServer:
         if self.grpc is not None:
             self.grpc.stop()
         self.server.stop()
-        if self.tracer.exporter is not None:
-            self.tracer.exporter.close()  # ship the last buffered spans
+        self.tracer.close()  # ship the last buffered spans
         self._pool.shutdown(wait=False)
 
     # -- watch-driven cache invalidation (reference: master_cache.go:414
@@ -1481,13 +1480,18 @@ class RouterServer:
 
         explicit_trace = bool(body.get("trace", False))
         want_profile = bool(body.get("profile", False))
+        # a profiled search always gets its span tree, as a profiled
+        # upsert does: the engine measures its phases for it anyway, and
+        # the tree is what puts them on the device trace's clock.
+        # `trace: true` alone keeps deciding the reply's `params`.
         root = (
             self.tracer.span(
                 "router.search",
                 tags={"db": skey[0], "space": skey[1], "k": k,
                       "batch": int(next(iter(vectors.values())).shape[0])},
+                serve_root=True,
             )
-            if self.tracer.should_sample(explicit_trace)
+            if self.tracer.should_sample(explicit_trace or want_profile)
             else NULL_SPAN
         )
         with root:
@@ -1676,6 +1680,7 @@ class RouterServer:
         ]
         results = [f.result() for f in futures]
         partials = [r for _, r in results]
+        merge_span = root.child("router.merge")  # merge_ms's window
         t_merge = _time.monotonic()
         if sort_specs:
             merged = self._merge_search_sorted(
@@ -1703,7 +1708,9 @@ class RouterServer:
             }
         else:
             out = {"documents": merged}
-        return out, results, round((_time.monotonic() - t_merge) * 1e3, 3)
+        merge_ms = round((_time.monotonic() - t_merge) * 1e3, 3)
+        merge_span.finish()
+        return out, results, merge_ms
 
     def _merge_search(
         self, partials: list[dict], k: int

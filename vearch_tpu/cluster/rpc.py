@@ -27,6 +27,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from vearch_tpu.cluster import tracing
 from vearch_tpu.cluster.metrics import Registry, register_process_gauges
 from vearch_tpu.utils import log
 
@@ -179,6 +180,24 @@ def _decode(content_type: str, raw: bytes) -> Any:
     return body
 
 
+# What a body that may be sampled holds, as json.dumps writes it with
+# and without spaces: a propagated context (PS), or `trace` / `profile`
+# asked for (router).
+_TRACE_MARKS = (b'"_trace_ctx"', b'"trace": true', b'"profile": true',
+                b'"trace":true', b'"profile":true')
+
+
+def _asks_trace(content_type: str, raw: bytes) -> bool:
+    """Whether the undecoded body may ask for a span tree: a byte search
+    of its JSON part, so that a request that does not ask stamps no
+    clock for `rpc.decode` / `rpc.encode`. A false positive (the words
+    inside a document) costs four clock reads and records nothing."""
+    lo, hi = 0, len(raw)
+    if len(raw) >= 4 and content_type.startswith((BIN_CT, BIN_CT_V1)):
+        lo, hi = 4, 4 + _U32.unpack_from(raw, 0)[0]  # the frame's header
+    return any(raw.find(mark, lo, hi) >= 0 for mark in _TRACE_MARKS)
+
+
 class RpcError(Exception):
     def __init__(self, code: int, msg: str,
                  retry_after: float | None = None):
@@ -230,6 +249,32 @@ def _sample_profile(seconds: float, interval: float = 0.01) -> str:
     for stack, cnt in stack_counts.most_common(10):
         lines.append(f"{cnt / max(samples, 1) * 100:6.1f}%  {stack}")
     return "\n".join(lines)
+
+
+def _record_serve(slot, t0_ns: int, t1_ns: int, decode, encode,
+                  n_in: int, n_out: int, code: int) -> None:
+    """The server's own spans around a sampled handler, recorded after
+    the reply went out: `rpc.serve` from before the body was read to
+    after the reply was written, parent of the handler's root span, and
+    its leaves `rpc.decode` (read + _decode) and `rpc.encode` (_encode +
+    write), each a `(t0_ns, t1_ns, cpu_ns)` of the handler thread, or
+    None where the request did not get that far."""
+    root = slot.root
+    tracer = root.tracer
+    tracer.record(
+        "rpc.serve", ctx={"trace_id": root.trace_id,
+                          "parent": slot.parent_id},
+        t0_ns=t0_ns, t1_ns=t1_ns, span_id=slot.span_id,
+        tags={"bytes_in": n_in, "bytes_out": n_out},
+        status="ok" if code == 0 else f"error: {code}",
+    )
+    under = {"trace_id": root.trace_id, "parent": slot.span_id}
+    if decode is not None:
+        tracer.record("rpc.decode", ctx=under, t0_ns=decode[0],
+                      t1_ns=decode[1], cpu_ns=decode[2])
+    if encode is not None:
+        tracer.record("rpc.encode", ctx=under, t0_ns=encode[0],
+                      t1_ns=encode[1], cpu_ns=encode[2])
 
 
 class JsonRpcServer:
@@ -396,10 +441,15 @@ class JsonRpcServer:
                     self.end_headers()
                     self.wfile.write(data)
                     return
-                t0 = time.monotonic()
+                t0_ns = time.monotonic_ns()
                 code = 0
                 prefix = self.path.split("?")[0]
                 _request_ctx.auth = self.headers.get("Authorization")
+                # rpc.serve and its leaves: stamped only for a body
+                # that may be sampled, recorded only if the handler's
+                # root span took the slot (tracing.ServeSlot)
+                slot = decode = encode = None
+                n_in = n_out = 0
                 try:
                     # drain the request body BEFORE anything that can
                     # raise (auth): with keep-alive clients an unread
@@ -407,14 +457,27 @@ class JsonRpcServer:
                     # request on the pooled connection
                     length = int(self.headers.get("Content-Length") or 0)
                     raw = self.rfile.read(length) if length else b""
+                    ctype = self.headers.get("Content-Type") or JSON_CT
+                    tracer = getattr(outer, "tracer", None)
+                    if tracer is not None and (
+                            tracer.sample_rate > 0
+                            or _asks_trace(ctype, raw)):
+                        slot = tracing.offer_serve_slot()
+                        n_in = len(raw)
+                        # the wall window of rpc.decode opens at t0_ns,
+                        # before the read; its CPU window only here, so
+                        # the read (a copy out of the socket) counts as
+                        # time not running
+                        cpu0 = time.thread_time_ns()
                     if outer.authenticator is not None and not any(
                         prefix == p or prefix.startswith(p + "/")
                         for p in outer.auth_exempt
                     ):
                         outer.authenticator(self.headers, method, prefix)
-                    body = _decode(
-                        self.headers.get("Content-Type") or JSON_CT, raw
-                    )
+                    body = _decode(ctype, raw)
+                    if slot is not None:
+                        cpu = time.thread_time_ns() - cpu0
+                        decode = (t0_ns, time.monotonic_ns(), cpu)
                     if "?" in self.path:
                         # URL query params ride into dict bodies under
                         # "_query" (reference: ?detail=true etc.);
@@ -442,7 +505,14 @@ class JsonRpcServer:
                         self._reply(404, {"code": 404, "msg": f"no route {method} {self.path}"})
                         return
                     result = handler(body, parts)
-                    self._reply(200, {"code": 0, "data": result})
+                    if slot is None or slot.root is None:
+                        self._reply(200, {"code": 0, "data": result})
+                    else:
+                        enc0_ns = time.monotonic_ns()
+                        cpu0 = time.thread_time_ns()
+                        n_out = self._reply(200, {"code": 0, "data": result})
+                        cpu = time.thread_time_ns() - cpu0
+                        encode = (enc0_ns, time.monotonic_ns(), cpu)
                 except RpcError as e:
                     code = e.code
                     payload = {"code": e.code, "msg": e.msg}
@@ -461,7 +531,8 @@ class JsonRpcServer:
                     )
                 finally:
                     _request_ctx.auth = None
-                    dt = time.monotonic() - t0
+                    t1_ns = time.monotonic_ns()
+                    dt = (t1_ns - t0_ns) / 1e9
                     # access log at debug (reference: request logs are
                     # debug-gated; IsDebugEnabled avoids the format cost)
                     if log.is_debug_enabled():
@@ -469,14 +540,20 @@ class JsonRpcServer:
                                    code, dt * 1e3)
                     outer._m_requests.inc(method, prefix, str(code))
                     outer._m_latency.observe(dt, method, prefix)
+                    if slot is not None:
+                        tracing.withdraw_serve_slot()
+                        if slot.root is not None:
+                            _record_serve(slot, t0_ns, t1_ns, decode,
+                                          encode, n_in, n_out, code)
 
-            def _reply(self, status: int, obj: dict):
+            def _reply(self, status: int, obj: dict) -> int:
                 ct, data = _encode(obj)
                 self.send_response(status)
                 self.send_header("Content-Type", ct)
                 self.send_header("Content-Length", str(len(data)))
                 self.end_headers()
                 self.wfile.write(data)
+                return len(data)
 
             def do_GET(self):
                 self._serve("GET")

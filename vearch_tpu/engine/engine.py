@@ -1224,6 +1224,9 @@ class Engine:
                     self.pad_waste_bytes += _perf.padding_waste_bytes(
                         b_rows, bb, int(queries.shape[1])
                     )
+                if capture is not None:
+                    capture.rows = b_rows
+                    capture.bucket_rows = int(q_run.shape[0])
                 store = self.vector_stores[name]
                 use_index = index.trained and not req.brute_force
                 if use_index:
@@ -1295,7 +1298,7 @@ class Engine:
         trace["dispatch_count"] = len(tags)
         if capture.kernels:
             trace["dispatch_kernels"] = dict(capture.kernels)
-        for tag, t0, t1 in capture.events:
+        for tag, t0, t1, *_ in capture.events:
             if t1 is not None:
                 key = f"dispatch_{tag}_ms"
                 trace[key] = round(
@@ -1321,9 +1324,19 @@ class Engine:
             for name, t0, t1 in phases
         ]
         spans.extend(
-            [f"kernel.{tag}", mono_us(t0), int((t1 - t0) * 1e6)]
-            for tag, t0, t1 in capture.events
+            [f"kernel.{tag}", mono_us(t0), int((t1 - t0) * 1e6),
+             {"rows": rows, "bucket_rows": bucket_rows,
+              # launch_us: the jitted call returned (program enqueued,
+              # query uploaded); the rest of the window waits for the
+              # device. Only the sites that stamp it carry it.
+              **({"launch_us": int((t_launch - t0) * 1e6)}
+                 if t_launch is not None else {})}]
+            for tag, t0, t1, t_launch, rows, bucket_rows in capture.events
             if t1 is not None
+        )
+        spans.extend(
+            [name, mono_us(t0), int((t1 - t0) * 1e6), tags or {}]
+            for name, t0, t1, tags in capture.phases
         )
         spans.extend(
             [f"mesh.{name}", mono_us(t0), int((t1 - t0) * 1e6)]

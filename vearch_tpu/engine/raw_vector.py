@@ -25,11 +25,13 @@ raw_vector.h:29 StoreParams).
 from __future__ import annotations
 
 import os
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from vearch_tpu.ops import ivf as ivf_ops
 from vearch_tpu.ops import perf_model
 from vearch_tpu.ops.distance import host_sqnorms
 from vearch_tpu.tools import lockcheck
@@ -104,15 +106,20 @@ class RawVectorStore:
         n = self._n
         cap = self._host.shape[0]
         if self._device is None or self._device.shape[0] != cap:
+            t0 = time.monotonic()
             self._device = jnp.asarray(self._host, dtype=self.store_dtype)
             self._device_sqnorm = jnp.asarray(
                 host_sqnorms(np.asarray(self._device))
             )
             # .nbytes is metadata — no host sync
-            perf_model.note_h2d_bytes(
-                int(self._device.nbytes) + int(self._device_sqnorm.nbytes)
-            )
+            nbytes = int(self._device.nbytes) + int(self._device_sqnorm.nbytes)
+            perf_model.note_h2d_bytes(nbytes)
             self._device_rows = n
+            # the whole store goes up again (first placement, or the
+            # host array grew past its capacity after a write): seconds
+            # at a million rows, paid by whichever search comes next
+            ivf_ops.note_phase("engine.replace_raw", t0, time.monotonic(),
+                               {"bytes": nbytes})
         elif self._device_rows < n:
             tail = jnp.asarray(
                 self._host[self._device_rows : n], dtype=self.store_dtype

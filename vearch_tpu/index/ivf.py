@@ -744,6 +744,7 @@ class IVFPQIndex(_IVFBase):
                     scan_metric=metric, rerank_metric=self.metric,
                     topk_mode=topk_mode, storage=self.mirror_storage,
                 )
+                ivf_ops.capture_launched()
                 scores, ids = jax.device_get((scores, ids))
                 return self._pad_to_k(scores, ids, k)
             else:
@@ -757,6 +758,7 @@ class IVFPQIndex(_IVFBase):
                     jnp.asarray(q), approx8, scale, vsq, valid,
                     max(r, k), metric, topk_mode,
                 )
+                ivf_ops.capture_launched()
         else:
             if self._dirty or self._bucket_resid8 is None:
                 self._publish()
@@ -809,6 +811,7 @@ class IVFPQIndex(_IVFBase):
                     probes=None if host_probes is None
                     else jnp.asarray(host_probes),
                 )
+            ivf_ops.capture_launched()
         if not self._exact_rerank_enabled(params):
             # SCANN reordering=false: pure quantized scores, no raw-store
             # gather (candidates come out of the scan best-first)

@@ -82,7 +82,7 @@ _capture_tls = threading.local()
 
 class DispatchCapture:
     __slots__ = ("events", "kernels", "mesh_phases", "tier_phases",
-                 "stage_phases")
+                 "stage_phases", "phases", "rows", "bucket_rows")
 
     def __init__(self) -> None:
         # tag -> kernel implementation that served it, for the tags
@@ -90,10 +90,15 @@ class DispatchCapture:
         # probe scan: "pallas" | "xla") — the profile reports it so a
         # reader can tell which program a dispatch tag really launched
         self.kernels: dict[str, str] = {}
-        # [tag, start_monotonic_s, end_monotonic_s | None] — consumers
+        # [tag, start, end | None, launched | None, rows, bucket_rows],
+        # stamps in monotonic seconds — consumers
         # (engine._record_dispatch_trace) anchor to the epoch via
         # utils.mono_us when emitting spans
         self.events: list[list] = []
+        # what the engine is about to hand the index: real query rows,
+        # and the rows of the shape bucket they were padded to (what the
+        # program runs); stamped on every dispatch noted after
+        self.rows = self.bucket_rows = 0
         # (name, start_monotonic_s, end_monotonic_s) host-side windows
         # of the mesh serving path (shard placement, mask upload, ...)
         # — replayed by the engine as mesh.{name} phase spans
@@ -107,14 +112,25 @@ class DispatchCapture:
         # the fused refine dispatch, the disk stage-2 gather+rerank) —
         # replayed as stage.{name} phase spans
         self.stage_phases: list[tuple[str, float, float]] = []
+        # (full span name, start, end, tags | None): windows noted with
+        # note_phase, replayed under their own names
+        self.phases: list[tuple[str, float, float, dict | None]] = []
 
     def note(self, tag: str, kernel: str | None = None) -> None:
         now = time.monotonic()
         if self.events and self.events[-1][2] is None:
             self.events[-1][2] = now
-        self.events.append([tag, now, None])
+        self.events.append([tag, now, None, None, self.rows,
+                            self.bucket_rows])
         if kernel is not None:
             self.kernels[tag] = kernel
+
+    def launched(self) -> None:
+        """The jitted call of the open dispatch has returned: its
+        program is enqueued and its query uploaded. What is left of the
+        window, up to jax.device_get, is waiting for the device."""
+        if self.events and self.events[-1][3] is None:
+            self.events[-1][3] = time.monotonic()
 
     def mark(self) -> None:
         """Close the open dispatch window (call when device work for the
@@ -139,6 +155,12 @@ def capture_mark() -> None:
         cap.mark()
 
 
+def capture_launched() -> None:
+    cap = getattr(_capture_tls, "capture", None)
+    if cap is not None:
+        cap.launched()
+
+
 def end_capture() -> DispatchCapture | None:
     cap = getattr(_capture_tls, "capture", None)
     _capture_tls.capture = None
@@ -156,6 +178,39 @@ def note_dispatch(tag: str, kernel: str | None = None) -> None:
     cap = getattr(_capture_tls, "capture", None)
     if cap is not None:
         cap.note(tag, kernel)
+
+
+# Optional phase observer (the PS installs one): takes the windows
+# note_phase sees when no request's capture is there to carry them, so
+# that rare process-level work (the raw store's re-placement on a path
+# nobody profiles) still leaves a span. Same single-slot contract as
+# the dispatch observer.
+_phase_observer = None
+
+
+def set_phase_observer(fn) -> None:
+    global _phase_observer
+    _phase_observer = fn
+
+
+def clear_phase_observer(fn) -> None:
+    """Remove `fn` if it is the installed observer (several servers of
+    one process each clear only their own)."""
+    global _phase_observer
+    if _phase_observer == fn:
+        _phase_observer = None
+
+
+def note_phase(name: str, t0: float, t1: float,
+               tags: dict | None = None) -> None:
+    """Record a rare host-side window under its full span name: on the
+    current request's capture when there is one (the request that paid
+    for it; replayed under ps.search), else to the phase observer."""
+    cap = getattr(_capture_tls, "capture", None)
+    if cap is not None:
+        cap.phases.append((name, t0, t1, tags))
+    elif _phase_observer is not None:
+        _phase_observer(name, t0, t1, tags)
 
 
 def note_mesh_phase(name: str, t0: float, t1: float) -> None:
@@ -395,17 +450,23 @@ def int8_scan_candidates(
     shape here keeps the single fused matmul and only restructures the
     selection.
     """
-    dots8 = jax.lax.dot_general(
-        queries.astype(jnp.bfloat16), approx8.astype(jnp.bfloat16),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [B, N]
-    dots = dots8 * row_scale[None, :]
-    if metric is MetricType.L2:
-        scores = -(sqnorms(queries)[:, None] - 2.0 * dots + row_vsq[None, :])
-    else:
-        scores = dots
-    scores = jnp.where(valid[None, :], scores, NEG_INF)
+    # named scopes (here, in _select_topk and in exact_rerank) put the
+    # stage into every operation's op_name, which is what the profiler's
+    # viewer groups by; they cost nothing at run time and are no part of
+    # the compilation cache's key
+    with jax.named_scope("score"):
+        dots8 = jax.lax.dot_general(
+            queries.astype(jnp.bfloat16), approx8.astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [B, N]
+        dots = dots8 * row_scale[None, :]
+        if metric is MetricType.L2:
+            scores = -(sqnorms(queries)[:, None] - 2.0 * dots
+                       + row_vsq[None, :])
+        else:
+            scores = dots
+        scores = jnp.where(valid[None, :], scores, NEG_INF)
     return _select_topk(scores, r, topk_mode)
 
 
@@ -429,18 +490,22 @@ def _select_topk(
     else:
         # 2x + 8 over-selection absorbs bf16 rounding of the block maxima
         nb = min(2 * nb + 8, nblk)
-        s3f = scores.reshape(b, nblk, BLOCK)
-        bmax = jnp.max(
-            s3f.astype(jnp.bfloat16), axis=2
-        ).astype(jnp.float32)  # [B, nblk]
-        _, top_blocks = jax.lax.top_k(bmax, nb)  # [B, nb]
-        # gather the chosen blocks at FULL precision for the final rank
-        gathered = jnp.take_along_axis(s3f, top_blocks[:, :, None], axis=1)
-        flat = gathered.reshape(b, nb * BLOCK)
-        top_s, pos = jax.lax.top_k(flat, min(r, nb * BLOCK))
-        ids = top_blocks[jnp.arange(b)[:, None], pos // BLOCK] * BLOCK \
-            + pos % BLOCK
-        ids = ids.astype(jnp.int32)
+        with jax.named_scope("block_max"):
+            s3f = scores.reshape(b, nblk, BLOCK)
+            bmax = jnp.max(
+                s3f.astype(jnp.bfloat16), axis=2
+            ).astype(jnp.float32)  # [B, nblk]
+        with jax.named_scope("select"):
+            _, top_blocks = jax.lax.top_k(bmax, nb)  # [B, nb]
+            # gather the chosen blocks at FULL precision for the final
+            # rank
+            gathered = jnp.take_along_axis(
+                s3f, top_blocks[:, :, None], axis=1)
+            flat = gathered.reshape(b, nb * BLOCK)
+            top_s, pos = jax.lax.top_k(flat, min(r, nb * BLOCK))
+            ids = top_blocks[jnp.arange(b)[:, None], pos // BLOCK] * BLOCK \
+                + pos % BLOCK
+            ids = ids.astype(jnp.int32)
     # candidates that are really masked slots (filtered/deleted/padding)
     # carry -inf scores — mark their ids -1 so downstream rerank cannot
     # resurrect them with genuine similarity scores (bf16 stage scores
@@ -601,26 +666,27 @@ def exact_rerank(
     One row gather + batched matvec; recovers exact ordering (and exact
     user-facing scores) on top of ADC approximations.
     """
-    safe = jnp.maximum(cand_ids, 0)
-    vecs = base[safe]  # [B, r, d]
-    vsq = base_sqnorm[safe]  # [B, r]
-    dots = jax.lax.dot_general(
-        queries, vecs, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-        precision=dot_precision(queries, vecs),
-    )  # [B, r]
-    if metric is MetricType.L2:
-        scores = -(sqnorms(queries)[:, None] - 2.0 * dots + vsq)
-    elif metric is MetricType.COSINE:
-        qn = jnp.sqrt(jnp.maximum(sqnorms(queries), 1e-30))[:, None]
-        vn = jnp.sqrt(jnp.maximum(vsq, 1e-30))
-        scores = dots / (qn * vn)
-    else:
-        scores = dots
-    scores = jnp.where(cand_ids >= 0, scores, NEG_INF)
-    k = min(k, scores.shape[1])
-    top_s, pos = jax.lax.top_k(scores, k)
-    return top_s, jnp.take_along_axis(cand_ids, pos, axis=1)
+    with jax.named_scope("rerank"):
+        safe = jnp.maximum(cand_ids, 0)
+        vecs = base[safe]  # [B, r, d]
+        vsq = base_sqnorm[safe]  # [B, r]
+        dots = jax.lax.dot_general(
+            queries, vecs, (((1,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+            precision=dot_precision(queries, vecs),
+        )  # [B, r]
+        if metric is MetricType.L2:
+            scores = -(sqnorms(queries)[:, None] - 2.0 * dots + vsq)
+        elif metric is MetricType.COSINE:
+            qn = jnp.sqrt(jnp.maximum(sqnorms(queries), 1e-30))[:, None]
+            vn = jnp.sqrt(jnp.maximum(vsq, 1e-30))
+            scores = dots / (qn * vn)
+        else:
+            scores = dots
+        scores = jnp.where(cand_ids >= 0, scores, NEG_INF)
+        k = min(k, scores.shape[1])
+        top_s, pos = jax.lax.top_k(scores, k)
+        return top_s, jnp.take_along_axis(cand_ids, pos, axis=1)
 
 
 @functools.partial(

@@ -38,12 +38,15 @@ _METRIC_RE = re.compile(
 # doc backticks are too generic to demand a registration behind each.
 _TAG_RE = re.compile(r"\.set_tag\(\s*[\"']([a-z_]+)[\"']")
 
-# span factories — tracer span/record calls with a (possibly
-# f-string) name literal — plus the engine's phase rows appended to
-# `phases`/`spans` lists, which the PS replays as retroactive spans.
+# span factories — tracer span/record calls and a span's child() with
+# a (possibly f-string) name literal — plus the engine's phase rows
+# appended to `phases`/`spans` lists or noted with note_phase, which
+# the PS replays as retroactive spans.
 _SPAN_RES = [
     re.compile(r"\.span\(\s*f?[\"']([a-z_.{}]+)[\"']", re.S),
     re.compile(r"\.record\(\s*f?[\"']([a-z_.{}]+)[\"']", re.S),
+    re.compile(r"\.child\(\s*f?[\"']([a-z_.{}]+)[\"']", re.S),
+    re.compile(r"note_phase\(\s*f?[\"']([a-z_.{}]+)[\"']", re.S),
     re.compile(r"phases\.append\(\(\s*f?[\"']([a-z_.{}]+)[\"']", re.S),
     re.compile(r"spans\.append\(\[\s*f?[\"']([a-z_.{}]+)[\"']", re.S),
     re.compile(r"spans\.extend\(\s*\[\s*f?[\"']([a-z_.{}]+)[\"']", re.S),

@@ -72,6 +72,10 @@ def reset() -> None:
         _violations.clear()
         _edges.clear()
     _forced = None
+    # the calling thread's held stack too: a lock a test left held (the
+    # foreign-release fixture: another thread releases it) would stand
+    # as "held" in front of every lock this thread takes afterwards
+    _tls.held = []
 
 
 def violations() -> list[dict]:

@@ -4,16 +4,28 @@ import time
 # Span epochs are derived from monotonic measurements plus this
 # process-constant anchor: durations must survive wall-clock steps
 # (lint VL203), and a later NTP step merely shifts where spans sit on
-# the collector's absolute timeline. Shared by every module whose
-# timestamps cross function boundaries before span emission (engine
-# phases, ivf dispatch capture, microbatch queue waits).
-MONO_EPOCH_OFFSET = time.time() - time.monotonic()  # lint: allow[wall-clock] span epoch anchor, captured once at import
+# the collector's absolute timeline. THE one conversion from the
+# monotonic clock to the epoch in the span path: spans keep
+# time.monotonic_ns() stamps and become epoch microseconds only when
+# they are read (cluster/tracing.py), and the engine's phase rows
+# (`[name, start_us, dur_us]`) go out and come back through it.
+MONO_EPOCH_OFFSET_NS = time.time_ns() - time.monotonic_ns()  # lint: allow[wall-clock] span epoch anchor, captured once at import
+
+
+def mono_ns_to_epoch_us(t_ns: int) -> int:
+    """time.monotonic_ns() stamp -> wall-anchored epoch microseconds."""
+    return (t_ns + MONO_EPOCH_OFFSET_NS) // 1000
+
+
+def epoch_us_to_mono_ns(start_us: int) -> int:
+    """Inverse of mono_ns_to_epoch_us, to microsecond resolution."""
+    return start_us * 1000 - MONO_EPOCH_OFFSET_NS
 
 
 def mono_us(t_monotonic: float) -> int:
     """Monotonic seconds -> wall-anchored epoch microseconds, the
     `start_us` convention of the tracing layer."""
-    return int((MONO_EPOCH_OFFSET + t_monotonic) * 1e6)
+    return mono_ns_to_epoch_us(int(t_monotonic * 1e9))
 
 
 #: where the persistent compilation cache lives when the environment
