@@ -44,13 +44,13 @@ REHEARSAL_RC = 4
 
 DB, SPACE = "smoke", "items"
 D, M, K, BATCH = 128, 32, 10, 64
-#: exact-rerank depth of the gated requests. At 1M rows the block-max
-#: candidate selection (ops/ivf.py _select_topk) keeps only
-#: 2*max(32, r/4)+8 blocks of 512 rows, and under random docid order
-#: each block holds about one useful row: `rerank: 128` is an effective
-#: depth of ~72 and reaches recall@10 0.92 on the chip (0.91 on the CPU;
-#: exact top-k at the same depth gives 0.98). 256 keeps 136 blocks. The
-#: depth-128 reading is printed beside it, gated at the upstream 0.80.
+#: exact-rerank depth of the gated requests. Until PR 26 the candidate
+#: selection (ops/ivf.py _select_topk) kept 2*max(32, r/4)+8 blocks of
+#: 512 rows, an effective depth of ~72 at `rerank: 128`: recall@10 0.92
+#: on the chip at 1M rows (PR 22). It now returns the exact top-r by
+#: int8 score (0.98 at depth 128 on the CPU; not measured on the chip).
+#: The depth-128 reading is printed beside the gated one, itself gated
+#: at the upstream 0.80.
 RERANK, RERANK_SHALLOW = 256, 128
 PRICE_MOD, PRICE_BELOW = 50, 30
 INGEST_BATCH = 5000

@@ -245,6 +245,54 @@ def test_merge_search_mixed_columnar_and_row_partials():
     assert [r["_id"] for r in merged3[0]] == ["x"]
 
 
+def _merge_columnar_by_query(partials, k):
+    """The per-query merge `_merge_search` ran until PR 26 (a
+    concatenate, a stable argsort and a take for every query row), kept
+    here as the reference of its order."""
+    reverse = partials[0]["metric"] != "L2"
+    out = []
+    offs = [np.cumsum([0] + [len(ks) for ks in p["keys"]]) for p in partials]
+    for qi in range(len(partials[0]["keys"])):
+        keys = [key for p in partials for key in p["keys"][qi]]
+        scores = np.concatenate([
+            np.asarray(p["scores"])[o[qi]:o[qi + 1]]
+            for p, o in zip(partials, offs)])
+        order = np.argsort(-scores if reverse else scores,
+                           kind="stable")[:k]
+        out.append([{"_id": keys[i], "_score": s}
+                    for i, s in zip(order.tolist(), scores[order].tolist())])
+    return out
+
+
+@pytest.mark.parametrize("metric", ["L2", "InnerProduct"])
+@pytest.mark.parametrize("n_parts", [1, 3])
+@pytest.mark.parametrize("ragged", [False, True])
+def test_merge_search_columnar_is_one_sort_with_the_old_order(
+        metric, n_parts, ragged):
+    """The columnar merge sorts the whole reply at once. Same hits, same
+    scores, same order as the per-query merge, ties included (scores
+    drawn from 7 values: partition order, then the partition's own),
+    with queries that got fewer hits than k, or none."""
+    rng = np.random.default_rng(n_parts + 10 * ragged)
+    nq, k = 64, 10
+    partials = []
+    for part in range(n_parts):
+        lens = (rng.integers(0, k + 1, nq) if ragged
+                else np.full(nq, k)).tolist()
+        partials.append({
+            "metric": metric, "columnar": True,
+            "keys": [[f"p{part}q{qi}h{j}" for j in range(n)]
+                     for qi, n in enumerate(lens)],
+            "scores": rng.integers(0, 7, sum(lens)).astype(np.float32),
+        })
+    router = object.__new__(RouterServer)
+    merged = RouterServer._merge_search(router, partials, k)
+    assert merged == _merge_columnar_by_query(partials, k)
+    assert all(type(hit["_score"]) is float for row in merged for hit in row)
+    assert RouterServer._merge_search(router, partials, 3) == \
+        _merge_columnar_by_query(partials, 3)
+
+
 # -- pre_expand_pids round-trip ----------------------------------------------
 
 def test_space_pre_expand_pids_roundtrip():

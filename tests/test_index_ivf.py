@@ -161,7 +161,7 @@ def test_int8_scan_blockmax_matches_exact():
     from vearch_tpu.ops.ivf import int8_scan_candidates
 
     rng = np.random.default_rng(7)
-    n, d = 512 * 64, 32  # 64 blocks, enough for nb*4 with nb=32
+    n, d = 512 * 64, 32  # 256 blocks of 128 rows, 32 of them gathered
     base = rng.integers(-100, 100, (n, d)).astype(np.int8)
     scale = np.ones(n, np.float32)
     vsq = np.sum((base.astype(np.float32)) ** 2, axis=1)
@@ -175,8 +175,9 @@ def test_int8_scan_blockmax_matches_exact():
     es, ei, bs, bi = map(np.asarray, (es, ei, bs, bi))
     # top-1 self-match must survive block selection exactly
     np.testing.assert_array_equal(ei[:, 0], bi[:, 0])
-    # strong overlap in the candidate pool (blockmax is allowed to drop
-    # a shadowed tail candidate, not the head)
+    # the two-stage selection is exact: the same scores, and the same
+    # ids wherever integer scores do not tie
+    np.testing.assert_array_equal(es, bs)
     for row in range(8):
         overlap = len(set(ei[row, :10].tolist()) & set(bi[row, :10].tolist()))
         assert overlap >= 9, (row, overlap)
@@ -218,6 +219,110 @@ def test_blockmax_never_resurrects_filtered_docs():
         jnp.asarray(np.ones(1024, np.float32)), jnp.asarray(vsq[:1024]),
         jnp.asarray(np.ones(1024, bool)), 128, MetricType.L2, "blockmax")
     assert np.asarray(s).shape[0] == 4
+
+
+def _scan_args(rng, n, d, b, selective):
+    """int8 rows whose own copies are the queries (self-match first),
+    per-row scales, and a mask that passes every row or one in ten."""
+    import jax.numpy as jnp
+
+    base = rng.integers(-100, 100, (n, d)).astype(np.int8)
+    scale = rng.uniform(0.5, 1.5, n).astype(np.float32)
+    vsq = np.sum((base.astype(np.float32) * scale[:, None]) ** 2, axis=1)
+    valid = (rng.random(n) < 0.1) if selective else np.ones(n, bool)
+    q = base[rng.choice(n, b, replace=False)].astype(np.float32)
+    return tuple(jnp.asarray(a) for a in (q, base, scale, vsq, valid))
+
+
+# 2,100 blocks of 128 rows: "auto" takes the two-stage selection from
+# 4 * max(r, 128) = 1,024 blocks, and the top 256 of a row lie in more
+# blocks than a capped selection keeps (136 blocks of 512 until PR 26);
+# +40 rows leave a ragged last block, which "auto" and "blockmax" both
+# answer with the plain top-k
+@pytest.mark.parametrize("selective", [False, True])
+@pytest.mark.parametrize("n", [128 * 2100, 128 * 2100 + 40])
+@pytest.mark.parametrize("metric",
+                         [MetricType.L2, MetricType.INNER_PRODUCT])
+@pytest.mark.parametrize("b", [8, 64])
+def test_scan_candidates_are_the_exact_top_r(b, metric, n, selective):
+    """The two-stage selection returns `lax.top_k` over the full row:
+    the same scores in the same order, the same ids wherever scores do
+    not tie, no id twice, masked slots as -1."""
+    from vearch_tpu.ops.ivf import int8_scan_candidates
+
+    r = 256
+    args = _scan_args(np.random.default_rng(b + n), n, 16, b, selective)
+    es, ei = map(np.asarray, int8_scan_candidates(*args, r, metric, "exact"))
+    for mode in ("auto", "blockmax"):
+        s, i = map(np.asarray,
+                   int8_scan_candidates(*args, r, metric, mode))
+        np.testing.assert_array_equal(s, es)
+        # rows of equal score may come back in another order
+        tied = (s == np.roll(s, 1, 1)) | (s == np.roll(s, -1, 1))
+        assert np.all((i == ei) | tied), mode
+        assert np.all((i == -1) == ~np.isfinite(s)), mode
+        assert np.all(np.asarray(args[4])[i[i >= 0]]), "a masked row came back"
+        for row in range(b):
+            real = i[row][i[row] >= 0]
+            assert len(set(real.tolist())) == len(real), "an id twice"
+
+
+@pytest.mark.parametrize("b", [5, 8, 64])
+def test_select_topk_on_tied_scores(b):
+    """Scores drawn from 50 values tie everywhere: the selection still
+    returns the top-r multiset of every row and ids that hold exactly
+    those scores. b=5 is no multiple of the 8-row tile."""
+    import jax
+    import jax.numpy as jnp
+
+    from vearch_tpu.ops.ivf import BLOCK, NEG_INF, _select_topk
+
+    rng = np.random.default_rng(b)
+    n, r = BLOCK * 2100, 256
+    scores = rng.integers(0, 50, (b, n)).astype(np.float32)
+    scores[:, rng.choice(n, n // 3, replace=False)] = NEG_INF
+    scores[0] = NEG_INF  # a row with fewer live slots than r
+    scores[0, 120:200] = rng.integers(0, 50, 80)
+    es, _ = jax.lax.top_k(jnp.asarray(scores), r)
+    s, i = map(np.asarray, _select_topk(jnp.asarray(scores), r, "auto"))
+    np.testing.assert_array_equal(s, np.asarray(es))
+    rows = np.arange(b)[:, None]
+    live = i >= 0
+    np.testing.assert_array_equal(
+        scores[rows, np.maximum(i, 0)][live], s[live])
+    assert np.all(~np.isfinite(s[~live])) and live[0].sum() == 80
+    for row in range(b):
+        assert len(set(i[row][live[row]].tolist())) == live[row].sum()
+
+
+@pytest.mark.parametrize("scan", ["int4", "binary"])
+def test_other_full_scans_select_their_exact_top_r(scan):
+    """int4 and the 1-bit stage-0 scan hand their own [B, N] scores to
+    the same selection: forced two-stage equals their exact top-k."""
+    import jax.numpy as jnp
+
+    from vearch_tpu.index.int8_mirror import quantize_rows_int4
+    from vearch_tpu.ops.binary_scan import (
+        binary_scan_candidates, pack_sign_rows)
+    from vearch_tpu.ops.ivf import int4_scan_candidates
+
+    rng = np.random.default_rng(11)
+    n, d, b, r = 128 * 96, 32, 8, 48
+    rows = rng.standard_normal((n, d)).astype(np.float32)
+    q = jnp.asarray(rows[rng.choice(n, b, replace=False)])
+    valid = jnp.asarray(rng.random(n) < 0.5)
+    if scan == "int4":
+        packed, scale, vsq = quantize_rows_int4(rows)
+        fn = int4_scan_candidates
+    else:
+        packed, scale, vsq = pack_sign_rows(rows)
+        fn = binary_scan_candidates
+    args = (q, jnp.asarray(packed), jnp.asarray(scale), jnp.asarray(vsq),
+            valid)
+    es, ei = map(np.asarray, fn(*args, r, MetricType.L2, "exact"))
+    s, i = map(np.asarray, fn(*args, r, MetricType.L2, "blockmax"))
+    np.testing.assert_array_equal(s, es)
+    assert np.mean(i == ei) > 0.99  # all but rows of equal score
 
 
 class TestHnswCoarseQuantizer:

@@ -75,6 +75,21 @@ def test_mesh_ivfpq_bit_identical(rng, storage):
     assert np.array_equal(ss, ms)
 
 
+@pytest.mark.parametrize("storage", ["int8", "int4"])
+def test_mesh_two_stage_selection_matches_exact(rng, storage):
+    """Every shard hands its own [B, N/shards] scores to the selection
+    the single-device scan uses: forced two-stage ("blockmax") on the
+    mesh returns what the single-device twin returns, and both what the
+    plain top-k ("exact") returns."""
+    single, mesh, _ = _ivfpq_pair(rng, storage=storage)
+    q = rng.standard_normal((8, D)).astype(np.float32)
+    es, ei = single.search(q, 10, None, {"topk_mode": "exact"})
+    for idx in (single, mesh):
+        bs, bi = idx.search(q, 10, None, {"topk_mode": "blockmax"})
+        assert np.array_equal(bi, ei)
+        assert np.array_equal(bs, es)
+
+
 def test_mesh_ivfpq_bit_identical_through_absorb(rng):
     """Incremental tail-appends land the same device state as a full
     place: results stay bit-identical across repeated absorb rounds."""

@@ -674,7 +674,7 @@ def test_shed_request_does_zero_device_work(tmp_path):
 def test_full_scan_materializes_score_matrix():
     """At the headline serving shape (1M x 128, b=1024) the XLA scan
     materializes a 4 GB [B, N] f32 score matrix (the chip's compiler
-    reports two such buffers as temp — tests/test_chip_compile.py) and
+    reports ONE such buffer as temp — tests/test_chip_compile.py) and
     streams the int8 mirror exactly once."""
     b, n_pad, d = 1024, 1_000_448, 128
     assert perf_model.scan_peak_bytes(b, n_pad) == b * n_pad * 4
@@ -682,10 +682,14 @@ def test_full_scan_materializes_score_matrix():
 
 
 def test_blockmax_selection_matches_kernel_constants():
-    # mirrors ops/ivf.py _select_topk over-selection: nb_sel blocks of
-    # 512 rows, and never more blocks than exist
-    assert perf_model.blockmax_selected_blocks(128, 1_000_448) == 72
-    assert perf_model.blockmax_selected_blocks(128, 2048) == 4
+    # mirrors ops/ivf.py _select_topk: r blocks of BLOCK (128) scores,
+    # never more blocks than exist, so stage 2 sorts r * 128 scores a
+    # query: 32,768 at the benchmark's rerank 256
+    assert perf_model.BLOCK == ivf_ops.BLOCK == 128
+    assert perf_model.blockmax_selected_blocks(128, 1_000_448) == 128
+    assert perf_model.blockmax_selected_blocks(256, 1_000_448) \
+        * perf_model.BLOCK == 32_768
+    assert perf_model.blockmax_selected_blocks(128, 2048) == 16
 
 
 # -- gate 4: HBM footprint model ---------------------------------------------

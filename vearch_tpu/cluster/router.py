@@ -1733,37 +1733,39 @@ class RouterServer:
             ]
         if n_columnar == len(partials):
             # fields-free fast path: merge on raw key/score arrays and
-            # build ONLY the final top-k dicts for the client response
+            # build ONLY the final top-k dicts for the client response.
+            # ONE sort for the whole reply. A numpy call a query row
+            # (concatenate, argsort, take) lets go of the interpreter
+            # lock each time, and with other requests on the host path
+            # every one of those is a hand-over to another thread: some
+            # 250 of them in a 64-row reply, 10 ms of the router's own
+            # 17 ms a request once the chip stopped being the wait
+            # (PERF.md section 6, PR 26)
             import numpy as np
 
             nq = len(partials[0]["keys"])
-            # scores arrive as one flat buffer per partition; per-query
-            # slices are recovered from the key-list lengths and stay
-            # numpy until only the final top-k becomes Python objects
-            sliced = []
-            for p in partials:
+            # scores arrive as one flat buffer per partition, query by
+            # query; the key lists give each query's share of it
+            flat = np.concatenate([
                 # lint: allow[host-sync] wraps the wire-decoded score buffer (already host memory), no device involved
-                flat = np.asarray(p["scores"])
-                offs = np.cumsum([0] + [len(ks) for ks in p["keys"]])
-                sliced.append([
-                    flat[offs[i]:offs[i + 1]] for i in range(nq)
-                ])
-            out = []
-            for qi in range(nq):
-                keys: list[str] = []
-                for p in partials:
-                    keys.extend(p["keys"][qi])
-                scores = np.concatenate([sc[qi] for sc in sliced])
-                # stable on the NEGATED array for descending order:
-                # reversing an ascending stable sort would invert tie
-                # order vs the legacy dict-row merge
-                order = np.argsort(-scores if reverse else scores,
-                                   kind="stable")[:k]
-                top = scores[order].tolist()
-                out.append([
-                    {"_id": keys[i], "_score": s}
-                    for i, s in zip(order.tolist(), top)
-                ])
+                np.asarray(p["scores"]).reshape(-1) for p in partials])
+            keys = [key for p in partials for ks in p["keys"] for key in ks]
+            query = np.concatenate([
+                np.repeat(np.arange(nq), [len(ks) for ks in p["keys"]])
+                for p in partials])
+            # stable, on the NEGATED scores for descending order: ties
+            # keep partition order, then the partition's own order, as
+            # the legacy dict-row merge does (reversing an ascending
+            # sort would invert them)
+            order = np.lexsort((-flat if reverse else flat, query))
+            ends = np.cumsum(np.bincount(query, minlength=nq)).tolist()
+            top, order = flat[order].tolist(), order.tolist()
+            out, lo = [], 0
+            for hi in ends:
+                stop = min(hi, lo + k)
+                out.append([{"_id": keys[i], "_score": s}
+                            for i, s in zip(order[lo:stop], top[lo:stop])])
+                lo = hi
             return out
         nq = len(partials[0]["results"])
         out = []

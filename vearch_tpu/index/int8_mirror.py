@@ -118,8 +118,9 @@ class Int8Mirror:
         start = self._n if start is None else start
         need = start + q8.shape[0]
         if self._h8.shape[0] < need:
-            # capacity stays 512-aligned: the block-max top-k reshapes
-            # the score row into [n/512, 512] blocks (ops/ivf.py)
+            # capacity stays 512-aligned: the two-stage top-k takes a
+            # score row in whole blocks of 128 (ops/ivf.py BLOCK), and
+            # a ragged row falls back to one sort of the whole row
             cap = max(need, self._h8.shape[0] * 2, 1024)
             cap = -(-cap // 512) * 512
             g8 = np.zeros((cap, self._row_width), dtype=self._row_dtype)
@@ -157,10 +158,11 @@ class Int8Mirror:
         """Device views row-sharded over the mesh "data" axis — one
         logical partition spanning all chips (the capacity regime: rows
         beyond a single chip's HBM). Rows are padded so every shard is
-        512-aligned (block-max top-k contract). Growth within the cached
-        capacity tail-appends per shard (one H2D per touched device of
-        only the new rows); a full re-place happens only on capacity
-        change — realtime absorb on a mesh partition stays incremental.
+        512-aligned (whole blocks for the two-stage top-k). Growth
+        within the cached capacity tail-appends per shard (one H2D per
+        touched device of only the new rows); a full re-place happens
+        only on capacity change — realtime absorb on a mesh partition
+        stays incremental.
         """
         if self._sh_cache is None:
             from vearch_tpu.parallel.mesh import ShardedRowCache

@@ -403,7 +403,7 @@ def ivfpq_candidates(
     return best_s, jnp.where(jnp.isfinite(best_s), best_i, -1)
 
 
-BLOCK = 512  # score-row block for the two-stage top-k (lane-aligned)
+BLOCK = 128  # block of the two-stage top-k: the lanes of one score tile
 
 
 @functools.partial(jax.jit, static_argnames=("r", "metric", "topk_mode"))
@@ -419,36 +419,11 @@ def int8_scan_candidates(
 ) -> tuple[jax.Array, jax.Array]:
     """Compressed full scan: one [B, d] x [d, N] int8 matmul + top-r.
 
-    The default IVFPQ scan path: one big MXU matmul beats the per-query
-    probe scan >10x at SIFT1M scale while reading 4x less HBM than the
-    bf16 raw buffer.
-
-    Top-r selection is two-stage "block-max" by default (topk_mode
-    "auto"/"blockmax"; "exact" forces plain lax.top_k): a full
-    lax.top_k over [B, 1M] f32 is a giant multi-pass sort (measured
-    482ms of a 511ms scan at B=1024 on v5e — 94% of the kernel). Stage
-    1 reduces each 512-wide block to its max (single pass over bf16
-    scores) and picks candidate blocks per query; stage 2 sorts only
-    the gathered blocks. Measured: 96ms vs 482ms at [1024, 1M], 5x.
-    Candidates are approximate in the same sense as ADC itself (a doc
-    shadowed by stronger block-maxes can drop out); the exact rerank
-    stage restores ordering.
-
-    PRECISION (r2 bench regression, recall 0.98 -> 0.70 on v5e): L2
-    scores at SIFT-like magnitudes are ~1e3 with neighbor gaps of a few
-    units; bf16's 8-bit mantissa rounds them to ±4, which is fine for
-    *choosing blocks* but catastrophic for ranking candidates (XLA CPU
-    constant-folds the bf16 round-trip away, so the loss only shows on
-    real TPU). Stage 1 therefore stays bf16 (bandwidth-bound pass over
-    the whole matrix) but over-selects 2x+8 blocks as rounding margin,
-    and stage 2 gathers the chosen blocks from the f32 score matrix so
-    final candidate ranking is exact.
-
-    NOTE(perf): a chunked (scan-over-blocks) top-k was tried in r1 and
-    measured WORSE (543ms -> 1227ms): many small matmul steps are
-    dispatch-bound, and chunk padding copied the 4GB score matrix. The
-    shape here keeps the single fused matmul and only restructures the
-    selection.
+    The default IVFPQ scan path: one big MXU matmul over the int8
+    mirror, which reads 4x less HBM than the bf16 raw buffer. The
+    [B, N] f32 score matrix is written once and `_select_topk` picks
+    the r candidates from it without copying it; the exact rerank
+    stage restores the user-facing order and scores.
     """
     # named scopes (here, in _select_topk and in exact_rerank) put the
     # stage into every operation's op_name, which is what the profiler's
@@ -473,43 +448,70 @@ def int8_scan_candidates(
 def _select_topk(
     scores: jax.Array, r: int, topk_mode: str
 ) -> tuple[jax.Array, jax.Array]:
-    """Shared block-max / exact top-r selection over a [B, N] score
-    matrix (see int8_scan_candidates docstring for the design note)."""
+    """Top-r of every row of a [B, N] f32 score matrix, shared by every
+    full scan (int8, int4, binary, the mesh programs): (scores, ids),
+    ids of masked slots -1.
+
+    topk_mode "exact" is one `lax.top_k` over the row: a multi-pass
+    sort of the whole matrix, the right thing only for small N. "auto"
+    takes it below 4 * max(r, 128) blocks (65,536 rows at r <= 128) and
+    for an N that is no multiple of BLOCK; "blockmax" forces the
+    two-stage selection wherever N is such a multiple:
+
+    1. `block_max`: the maximum of every BLOCK-wide block of a row, in
+       f32, and the r blocks with the largest maxima.
+    2. `select`: those r blocks gathered, and `lax.top_k` over their
+       r * BLOCK scores (32,768 at rerank 256).
+
+    The result is the exact top-r of the row (rows of equal score
+    aside): a score among the r largest has a block maximum no smaller
+    than itself, so fewer than r blocks can rank before its block.
+
+    LAYOUT. On the TPU the score fusion writes [B, N] f32 in tiles of 8
+    rows x 128 lanes, physically [B/8, N/128, 8, 128]. Both stages read
+    it through exactly that 4-d view, so the transpose below is a
+    bitcast and the matrix is never copied: the maxima are a lane
+    reduction, and a gathered block is one 128-lane row of one tile.
+    Any other blocked view ([B, N/BLOCK, BLOCK], block-major, bf16) is
+    a physical relayout of the whole matrix: two of them were 42 % of
+    this program's device time at B=64, N=1M (PERF.md section 6, PR 26).
+    A B that is no multiple of 8 (no bucket the engine dispatches)
+    takes the same code with 1-row tiles and pays for its relayout.
+    """
     b, n_pad = scores.shape
     r = min(r, n_pad)
-    nb = max(32, r // 4)
     nblk = n_pad // BLOCK
     use_block = (
         n_pad % BLOCK == 0
         and nblk >= 1
         and (topk_mode == "blockmax"
-             or (topk_mode == "auto" and nblk >= nb * 4))
+             or (topk_mode == "auto" and nblk >= 4 * max(r, 128)))
     )
     if not use_block:
         top_s, ids = jax.lax.top_k(scores, r)
     else:
-        # 2x + 8 over-selection absorbs bf16 rounding of the block maxima
-        nb = min(2 * nb + 8, nblk)
+        nb = min(r, nblk)
+        sub = 8 if b % 8 == 0 else 1
+        tiles = scores.reshape(b // sub, sub, nblk, BLOCK).transpose(
+            0, 2, 1, 3)  # [B/sub, nblk, sub, BLOCK]
         with jax.named_scope("block_max"):
-            s3f = scores.reshape(b, nblk, BLOCK)
-            bmax = jnp.max(
-                s3f.astype(jnp.bfloat16), axis=2
-            ).astype(jnp.float32)  # [B, nblk]
+            bmax = jnp.max(tiles, axis=3).transpose(0, 2, 1).reshape(
+                b, nblk)
         with jax.named_scope("select"):
             _, top_blocks = jax.lax.top_k(bmax, nb)  # [B, nb]
-            # gather the chosen blocks at FULL precision for the final
-            # rank
-            gathered = jnp.take_along_axis(
-                s3f, top_blocks[:, :, None], axis=1)
-            flat = gathered.reshape(b, nb * BLOCK)
-            top_s, pos = jax.lax.top_k(flat, min(r, nb * BLOCK))
-            ids = top_blocks[jnp.arange(b)[:, None], pos // BLOCK] * BLOCK \
-                + pos % BLOCK
+            row = jnp.arange(b)[:, None]
+            gathered = tiles[row // sub, top_blocks, row % sub, :]
+            top_s, pos = jax.lax.top_k(gathered.reshape(b, nb * BLOCK), r)
+            # block of each winner by compare-and-sum over the nb chosen
+            # blocks: an element gather of B*r block ids costs the chip
+            # ten times this loop (0.17 ms against 0.01 at B=64)
+            chosen = (pos // BLOCK)[:, :, None] == jnp.arange(nb)
+            ids = jnp.sum(jnp.where(chosen, top_blocks[:, None, :], 0),
+                          axis=2) * BLOCK + pos % BLOCK
             ids = ids.astype(jnp.int32)
     # candidates that are really masked slots (filtered/deleted/padding)
     # carry -inf scores — mark their ids -1 so downstream rerank cannot
-    # resurrect them with genuine similarity scores (bf16 stage scores
-    # are selection-only; the rerank stage recomputes exact scores)
+    # resurrect them with genuine similarity scores
     return top_s, jnp.where(jnp.isfinite(top_s), ids, -1)
 
 

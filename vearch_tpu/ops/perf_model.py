@@ -18,7 +18,7 @@ Four layers:
    searches add ZERO new compiled programs (no silent retrace).
 3. bytes-materialized model — peak intermediate HBM bytes of the
    full-scan path, mirroring the real kernel constants (ops/ivf.py
-   BLOCK): the [B, N] f32 score matrix the XLA scan materializes.
+   BLOCK): the ONE [B, N] f32 score matrix the XLA scan materializes.
 4. HBM-footprint model — resident device bytes per index type
    (index.device_footprint_bytes() feeds these helpers), the
    rows-per-chip capacity planner.
@@ -34,8 +34,8 @@ import threading
 import time
 from typing import Any, Callable
 
-# must match ops/ivf.py BLOCK
-BLOCK = 512
+# must match ops/ivf.py BLOCK (tests/test_perf_gates.py holds them equal)
+BLOCK = 128
 
 F32 = 4
 
@@ -417,19 +417,20 @@ def tier_h2d_bytes(misses: int, cap: int, d: int) -> int:
 
 
 def blockmax_selected_blocks(r: int, n_pad: int) -> int:
-    """Candidate blocks stage 2 re-scores — mirrors the 2x+8
-    over-selection in ops/ivf.py _select_topk."""
-    nblk = max(n_pad // BLOCK, 1)
-    nb = max(32, min(r, n_pad) // 4)
-    return min(2 * nb + 8, nblk)
+    """Blocks of BLOCK scores whose r * BLOCK entries stage 2 of
+    ops/ivf.py _select_topk sorts, per query: r of them (the top-r of a
+    row lies in the r blocks of largest maxima), never more than exist."""
+    return min(min(r, n_pad), max(n_pad // BLOCK, 1))
 
 
 def scan_peak_bytes(b: int, n_pad: int) -> int:
     """Peak intermediate HBM bytes the full scan materializes per
-    search: the [B, N] f32 score matrix (block-max selection then
-    re-reads it). PEAK resident, not total traffic. The chip's compiler
-    reports twice this as temp for the B=1024 program at N~1M (8.2 GB:
-    two score-sized buffers; tests/test_chip_compile.py prints it)."""
+    search: the [B, N] f32 score matrix, once (the selection reads it
+    in the tiles the score fusion writes and copies nothing). PEAK
+    resident, not total traffic. The chip's compiler reports 1.12x this
+    as temp for the B=1024 program at N~1M (4.6 GB: the matrix plus the
+    selection's small buffers; tests/test_chip_compile.py prints it and
+    fails on a second score-sized buffer)."""
     return b * n_pad * F32
 
 
@@ -483,11 +484,11 @@ def refine_depths(k: int, n: int) -> tuple[int, int]:
 
     Stage 0's sign estimator is selection-grade only, so its survivor
     set must be generous: r0 = 32x the int8 default's 10x-k rule,
-    floored at 512 (one block-max block) — still ~1e-3 of a 1M-row
-    partition. Stage 1 then funnels to the proven int8 rerank depth
-    r1 = max(10k, 128). Both clamp to the row count; both are
-    runtime-tunable per request / via /ps/engine/config ("r0"/"r1"
-    index params) with these as the documented fallback."""
+    floored at 512 — still ~1e-3 of a 1M-row partition. Stage 1 then
+    funnels to the proven int8 rerank depth r1 = max(10k, 128). Both
+    clamp to the row count; both are runtime-tunable per request / via
+    /ps/engine/config ("r0"/"r1" index params) with these as the
+    documented fallback."""
     n = max(int(n), 1)
     r1 = min(max(10 * int(k), 128), n)
     r0 = min(max(32 * r1 // 10, 512), n)
