@@ -387,7 +387,25 @@ class Smoke:
         hits_m, prof_m, obs_m = self.timed(
             "batch64_mesh", "sharded_fused_scan_rerank", queries)
         rec_m = recall_at_k(doc_ids(hits_m), want)
-        emit("request", **obs_m, recall_at_10=rec_m, mesh=prof_m.get("mesh"))
+        # the program as the device trace will name it (XLA names a
+        # module after the jitted function), and how long each of the
+        # six dispatches took to launch (kernel.* span tag `launch_us`)
+        from vearch_tpu.cluster import tracing
+        from vearch_tpu.ops import perf_model
+
+        modules = sorted({
+            f"jit_{fn.__name__}" for label, fn in
+            perf_model._JIT_REGISTRY.items()
+            if label.startswith("sharded.ivf_fused[") and fn._cache_size()})
+        launch_us = [s.tags.get("launch_us") for s in tracing.snapshot()
+                     if s.name == "kernel.sharded_fused_scan_rerank"]
+        emit("request", **obs_m, recall_at_10=rec_m, mesh=prof_m.get("mesh"),
+             program=modules, launch_us=launch_us)
+        check(modules == ["jit_sharded_fused_scan_rerank"],
+              f"the mesh program is on the trace as {modules}")
+        check(len(launch_us) == 6 and all(u is not None and u > 0
+                                          for u in launch_us),
+              f"mesh dispatches without launch_us: {launch_us}")
 
         # Where the mirror and the rerank store live, BEFORE the
         # single-device comparison places its own full copy on device 0:

@@ -29,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 from vearch_tpu.engine.raw_vector import RawVectorStore
 from vearch_tpu.engine.types import IndexParams, MetricType
 from vearch_tpu.index.int8_mirror import Int8Mirror
+from vearch_tpu.index import ivf as ivf_index
 from vearch_tpu.index.ivf import IVFPQIndex
 from vearch_tpu.ops import ivf as ivf_ops
 from vearch_tpu.ops import kmeans as km
@@ -219,9 +220,11 @@ def test_xla_probe_scan_and_rerank_compile(one_chip, widths):
                                   "assign_all_rows", "train_pq",
                                   "encode_pq"])
 def test_build_steps_compile(one_chip, widths, step):
-    """What the 1M build dispatches: coarse k-means over the training
-    sample, assignment of the sample and of every row at absorb, PQ
-    codebook training on the sample's residuals, PQ encode of all rows."""
+    """What a build dispatches, whatever its row count: coarse k-means
+    over the training sample, assignment of the sample and of every row
+    at absorb, PQ codebook training on the sample's residuals, PQ encode
+    of all rows (the rows in pieces of the sample's size)."""
+    assert ivf_index.BULK_ROWS == widths["sample"]
     S = _shapes(one_chip)
     sample, nlist, iters = widths["sample"], widths["nlist"], widths["iters"]
     cents = S((nlist, D), jnp.float32)
@@ -231,13 +234,15 @@ def test_build_steps_compile(one_chip, widths, step):
             S((sample, D), jnp.float32), k=nlist, iters=iters),
         "assign_sample": lambda: km.assign_clusters.lower(
             S((sample, D), jnp.float32), cents),
+        # absorb sends the rows up in pieces of BULK_ROWS, the sample's
+        # shape: the build compiles no program of the partition's size
         "assign_all_rows": lambda: km.assign_clusters.lower(
-            S((ROWS, D), jnp.float32), cents),
+            S((ivf_index.BULK_ROWS, D), jnp.float32), cents),
         "train_pq": lambda: jax.jit(functools.partial(
             pq_ops.train_pq, m=widths["m"], ksub=widths["ksub"],
             iters=iters)).lower(S((sample, D), jnp.float32)),
         "encode_pq": lambda: pq_ops.encode_pq.lower(
-            S((ROWS, D), jnp.float32), books),
+            S((ivf_index.BULK_ROWS, D), jnp.float32), books),
     }[step]()
     _report(step, lowered.compile())
 
@@ -284,3 +289,58 @@ def test_mesh_fused_program_compiles_for_four_chips(topo, one_chip, widths):
                              widths["n_store"]))
     assert 0.20 < per_device / single < 0.30, (per_device, single)
     assert "all-gather" in compiled.as_text()
+
+
+# benchmark/configs/deep10m-mesh4-ivfpq.json: 96-d rows over the 4 chips
+# of one host, at the committed row count and at the 10M target
+DEEP_D = 96
+
+
+@pytest.mark.parametrize("rows,b", [(4_000_000, 64), (4_000_000, 256),
+                                    (10_000_000, 64)])
+def test_deep_mesh_program_compiles_for_four_chips(topo, widths, rows, b):
+    """The serving program of `deep10m-mesh4-ivfpq` at the shard sizes
+    its placement caches pick: XLA module `jit_sharded_fused_scan_rerank`
+    (what the benchmark finds it by on the device trace), both
+    collectives, and per chip ONE instruction that writes a
+    [B, N/4] f32 score matrix, as the one-chip program since PR 26.
+
+    What this also shows, at 96 dimensions only: the chip keeps an
+    [N/4, 96] f32 array column-major (`{0,1:T(8,128)}`: no padding of 96
+    to 128 lanes), the rerank's row gather wants it row-major, and the
+    compiler copies the whole raw shard (`copy` of `args[4]`) in every
+    dispatch. At B=64 that relaid shard, not the score matrix, is the
+    program's temp. PERF.md section 7 queues it; a program that stops
+    copying passes this test unchanged."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "query"))
+    n_mirror = ShardedRowCache(align=512).capacity(mesh, rows)
+    n_store = ShardedRowCache(align=128).capacity(mesh, rows)
+
+    def S(shape, dt, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dt, sharding=NamedSharding(mesh, P(*spec)))
+
+    fn = sharded._ivf_search_fn(mesh, RERANK, widths["fetch_k"], L2, L2,
+                                "auto", "int8", 0)
+    compiled = fn.lower(
+        S((n_mirror, DEEP_D), jnp.int8, "data", None),
+        S((n_mirror,), jnp.float32, "data"),
+        S((n_mirror,), jnp.float32, "data"),
+        S((n_mirror,), jnp.bool_, "data"),
+        S((n_store, DEEP_D), jnp.float32, "data", None),
+        S((n_store,), jnp.float32, "data"),
+        S((b, DEEP_D), jnp.float32, "query", None)).compile()
+    _, temp = _report(f"sharded_fused_scan_rerank[4 chips, {rows} x "
+                      f"{DEEP_D}, B={b}]", compiled)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_sharded_fused_scan_rerank")
+    assert "all-gather" in text and "all-reduce" in text
+    for scope in ("score", "block_max", "select", "merge", "rerank", "pmax"):
+        assert f"shard_map/{scope}/" in text, scope
+    local_n = n_mirror // 4
+    written = [name for name in _score_sized(compiled, b, local_n)
+               if not name.startswith("copy")]
+    assert len(written) == 1, written
+    matrix = perf_model.scan_peak_bytes(b, local_n)
+    raw_relaid = (n_store // 4) * 128 * 4  # 96 columns in 128 lanes
+    assert matrix <= temp < 1.25 * max(matrix, raw_relaid), (temp, matrix)

@@ -130,6 +130,8 @@ def test_four_chip_option_runs_only_the_mesh_phase(tmp_path):
     mesh, res, single = earlier[4:7]
     assert mesh["kind"] == "batch64_mesh"
     assert mesh["dispatches"] == ["sharded_fused_scan_rerank"]
+    assert mesh["program"] == ["jit_sharded_fused_scan_rerank"]
+    assert len(mesh["launch_us"]) == 6 and min(mesh["launch_us"]) > 0
     assert single["kind"] == "batch64_single_device"
     assert single["dispatches"] == ["fused_scan_rerank"]
     assert min(mesh["recall_at_10"], single["recall_at_10"]) >= 0.95

@@ -478,3 +478,35 @@ def test_padded_probe_slots_never_duplicate_results():
         assert len(real) == len(set(real.tolist())), row
         # only cell 2's docids can appear
         assert all(16 <= i < 24 for i in real), row
+
+
+@pytest.mark.parametrize("rows", [2500, 3000, 700])
+def test_bulk_absorb_in_pieces_builds_the_same_index(rng, monkeypatch, rows):
+    """An index build's absorb sends its rows to the device in pieces of
+    `BULK_ROWS` (one compiled shape whatever the partition's size, the
+    last piece zero-padded): codes, assignments and the int8 mirror are
+    what one call over all rows gives. 2500 rows = two pieces and a
+    padded third, 3000 = three whole pieces, 700 = under one piece."""
+    from vearch_tpu.engine.raw_vector import RawVectorStore
+    from vearch_tpu.index import ivf
+    from vearch_tpu.index.ivf import IVFPQIndex
+
+    data = rng.standard_normal((rows, 32)).astype(np.float32)
+
+    def build(bulk_rows):
+        monkeypatch.setattr(ivf, "BULK_ROWS", bulk_rows)
+        store = RawVectorStore(32)
+        store.add(data)
+        idx = IVFPQIndex(IndexParams("IVFPQ", MetricType.L2, {
+            "ncentroids": 16, "nsubvector": 8, "train_iters": 4}), store)
+        idx.train(data[:600])
+        idx.absorb(rows)
+        return idx
+
+    whole, pieces = build(1 << 20), build(1000)
+    np.testing.assert_array_equal(pieces._codes[:rows], whole._codes[:rows])
+    np.testing.assert_array_equal(pieces._assign_host[:rows],
+                                  whole._assign_host[:rows])
+    for a, b in zip(pieces._mirror.flush(), whole._mirror.flush()):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert pieces._members == whole._members

@@ -419,6 +419,46 @@ def test_mesh_trace_reports_phases_and_placement(mesh_engine):
     emb = trace["mesh"]["fields"]["emb"]
     assert emb["data_shards"] == 8
     assert emb["per_device_bytes"] > 0
+    # the place phase says what went up meanwhile (here: the query
+    # batch), the dispatch when its jitted call returned
+    place = next(s for s in trace["_phase_spans"] if s[0] == "mesh.place")
+    assert place[3]["bytes"] >= 8 * D * 4
+    kernel = next(s for s in trace["_phase_spans"]
+                  if s[0] == "kernel.sharded_fused_scan_rerank")
+    assert kernel[3]["rows"] == kernel[3]["bucket_rows"] == 8
+    assert 0 < kernel[3]["launch_us"] <= kernel[2]
+
+
+@pytest.mark.parametrize("params,tag,label,module", [
+    ({"scan_mode": "full"}, "sharded_fused_scan_rerank",
+     ("sharded.ivf_fused[", ",p0]"), "jit_sharded_fused_scan_rerank"),
+    ({"scan_mode": "probe", "nprobe": 8}, "sharded_probe_scan_rerank",
+     ("sharded.ivf_fused[", ",p8]"), "jit_sharded_probe_scan_rerank"),
+    ({"scan_mode": "full", "fused_rerank": False}, "sharded_scan",
+     ("sharded.int8[", "]"), "jit_run"),
+])
+def test_mesh_serving_program_is_named_on_the_device_trace(
+        mesh_engine, params, tag, label, module):
+    """XLA names a module after the jitted function, and the benchmark
+    finds a dispatch on the device trace by that name
+    (benchmark/kernels/sharded_fused_scan_rerank.py): the fused mesh
+    program carries its dispatch tag, with its stages as named scopes;
+    every other shard_map program of parallel/sharded.py is `jit_run`.
+    Every mesh dispatch stamps `launch_us`."""
+    eng, vecs = mesh_engine
+    trace: dict = {}
+    eng.search(SearchRequest(vectors={"emb": vecs[:8]}, k=10,
+                             include_fields=[], index_params=params,
+                             trace=trace))
+    kernels = [s for s in trace["_phase_spans"]
+               if s[0].startswith("kernel.")]
+    assert kernels[0][0] == f"kernel.{tag}"
+    assert all("launch_us" in s[3] for s in kernels), kernels
+    live = {f"jit_{fn.__name__}"
+            for name, fn in perf_model._JIT_REGISTRY.items()
+            if name.startswith(label[0]) and name.endswith(label[1])
+            and fn._cache_size()}
+    assert live == {module}, live
 
 
 # -- incremental placement (tail-append, never full re-place) ----------------

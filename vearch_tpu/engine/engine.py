@@ -1339,8 +1339,8 @@ class Engine:
             for name, t0, t1, tags in capture.phases
         )
         spans.extend(
-            [f"mesh.{name}", mono_us(t0), int((t1 - t0) * 1e6)]
-            for name, t0, t1 in capture.mesh_phases
+            [f"mesh.{name}", mono_us(t0), int((t1 - t0) * 1e6), tags or {}]
+            for name, t0, t1, tags in capture.mesh_phases
         )
         spans.extend(
             [f"tier.{name}", mono_us(t0), int((t1 - t0) * 1e6)]

@@ -36,8 +36,37 @@ from vearch_tpu.index.int8_mirror import Int8Mirror
 from vearch_tpu.index.registry import register_index
 from vearch_tpu.ops import ivf as ivf_ops
 from vearch_tpu.ops import kmeans as km
+from vearch_tpu.ops import perf_model
 from vearch_tpu.ops import pq as pq_ops
 from vearch_tpu.ops.distance import sqnorms, to_device_mask
+
+
+#: rows of one piece of a bulk absorb: the default training sample's
+#: size, so that the build assigns its rows with the program that
+#: assigned the sample
+BULK_ROWS = 262_144
+
+
+def _bulk_rows(fn, rows: np.ndarray) -> np.ndarray:
+    """A row-wise device function over host rows. More than BULK_ROWS
+    (an index build's absorb) go up in pieces of exactly BULK_ROWS, the
+    last one zero-padded: one compiled shape however many rows the
+    partition has. Compiled for its own row count, a 4M x 96 build
+    spent 107 s of its 139 s of `assign` in the compiler (PERF.md
+    section 6, PR 27). Every row's result is its own, so the pieces
+    give what one call gives."""
+    n = rows.shape[0]
+    if n <= BULK_ROWS:
+        return np.asarray(fn(jnp.asarray(rows)))
+    out = []
+    for lo in range(0, n, BULK_ROWS):
+        piece = rows[lo:lo + BULK_ROWS]
+        real = piece.shape[0]
+        if real < BULK_ROWS:
+            piece = np.concatenate([piece, np.zeros(
+                (BULK_ROWS - real,) + piece.shape[1:], piece.dtype)])
+        out.append(np.asarray(fn(jnp.asarray(piece)))[:real])
+    return np.concatenate(out)
 
 
 class _IVFBase(VectorIndex):
@@ -176,9 +205,8 @@ class _IVFBase(VectorIndex):
         if self._coarse_graph is not None:
             _s, ids = self._coarse_graph.search(rows, 1, ef=96)
             return ids[:, 0].astype(np.int64)
-        return np.asarray(
-            km.assign_clusters(jnp.asarray(rows), self.centroids)
-        )
+        return _bulk_rows(
+            lambda x: km.assign_clusters(x, self.centroids), rows)
 
     def _host_probes(self, q: np.ndarray, nprobe: int) -> np.ndarray | None:
         """[B, nprobe] probe cells from the host graph, or None for the
@@ -540,9 +568,8 @@ class IVFPQIndex(_IVFBase):
 
     def _encode_rows(self, resid: np.ndarray, rows: np.ndarray) -> np.ndarray:
         """Encoder hook (same override seam as `_fit_codebooks`)."""
-        return np.asarray(
-            pq_ops.encode_pq(jnp.asarray(resid), self.codebooks)
-        )
+        return _bulk_rows(
+            lambda x: pq_ops.encode_pq(x, self.codebooks), resid)
 
     def _absorb_rows(
         self, rows: np.ndarray, assign: np.ndarray, start_docid: int
@@ -913,6 +940,16 @@ class IVFPQIndex(_IVFBase):
         )
 
         t_place0 = _time.monotonic()
+        h2d0 = perf_model.h2d_bytes_total()
+
+        def note_place() -> None:
+            # `bytes`: what the process uploaded during the phase: the
+            # query batch on every request; a re-placement or a
+            # tail-append of mirror, raw store or mask shows as more
+            ivf_ops.note_mesh_phase(
+                "place", t_place0, _time.monotonic(),
+                {"bytes": perf_model.h2d_bytes_total() - h2d0})
+
         mesh = self._serving_mesh(params)
         a8, scale, vsq = self._mirror.flush_sharded(mesh)
         n = self.indexed_count
@@ -934,7 +971,7 @@ class IVFPQIndex(_IVFBase):
         rerank = self._exact_rerank_enabled(params)
         if fused and rerank:
             base, base_sqn, _ = self.store.device_buffer_sharded(mesh)
-            ivf_ops.note_mesh_phase("place", t_place0, _time.monotonic())
+            note_place()
             ivf_ops.note_dispatch(
                 "sharded_probe_scan_rerank" if probe_nprobe > 0
                 else "sharded_fused_scan_rerank"
@@ -947,14 +984,16 @@ class IVFPQIndex(_IVFBase):
                 topk_mode=topk_mode, storage=self.mirror_storage,
                 nprobe=nprobe,
             )
+            ivf_ops.capture_launched()
             scores, ids = jax.device_get((scores, ids))
             return self._pad_to_k(scores[:b], ids[:b], k)
-        ivf_ops.note_mesh_phase("place", t_place0, _time.monotonic())
+        note_place()
         ivf_ops.note_dispatch("sharded_scan")
         cand_s, cand_i = sharded_int8_search(
             mesh, a8, scale, vsq, valid_sh, qd, max(r, k), metric,
             topk_mode, storage=self.mirror_storage,
         )
+        ivf_ops.capture_launched()
         if not rerank:
             scores, ids = jax.device_get((cand_s, cand_i))
             return self._pad_to_k(scores[:b, :k], ids[:b, :k], k)
@@ -964,6 +1003,7 @@ class IVFPQIndex(_IVFBase):
             mesh, qd.astype(base.dtype), cand_i, base, base_sqn,
             min(k, int(cand_i.shape[1])), self.metric,
         )
+        ivf_ops.capture_launched()
         scores, ids = jax.device_get((scores, ids))
         return self._pad_to_k(scores[:b], ids[:b], k)
 
@@ -994,8 +1034,6 @@ class IVFPQIndex(_IVFBase):
         rides whole on every chip (ops/perf_model.per_device_bytes)."""
         if not self._mesh_enabled(None):
             return self.device_footprint_bytes()
-        from vearch_tpu.ops import perf_model
-
         mesh = self._serving_mesh(None)
         n_shards = int(mesh.shape["data"])
         sharded = self._mirror.device_bytes() + \

@@ -99,10 +99,11 @@ class DispatchCapture:
         # and the rows of the shape bucket they were padded to (what the
         # program runs); stamped on every dispatch noted after
         self.rows = self.bucket_rows = 0
-        # (name, start_monotonic_s, end_monotonic_s) host-side windows
-        # of the mesh serving path (shard placement, mask upload, ...)
-        # — replayed by the engine as mesh.{name} phase spans
-        self.mesh_phases: list[tuple[str, float, float]] = []
+        # (name, start_monotonic_s, end_monotonic_s, tags | None)
+        # host-side windows of the mesh serving path (shard placement,
+        # mask upload, ...) — replayed by the engine as mesh.{name}
+        # phase spans
+        self.mesh_phases: list[tuple[str, float, float, dict | None]] = []
         # (name, start_monotonic_s, end_monotonic_s) host-side windows
         # of the tiered-storage path (demand fetch, prefetch schedule,
         # pin-set change) — replayed as tier.{name} phase spans
@@ -213,13 +214,15 @@ def note_phase(name: str, t0: float, t1: float,
         _phase_observer(name, t0, t1, tags)
 
 
-def note_mesh_phase(name: str, t0: float, t1: float) -> None:
+def note_mesh_phase(name: str, t0: float, t1: float,
+                    tags: dict | None = None) -> None:
     """Record a host-side window of the mesh serving path (per-shard
     placement, mask upload) on the current request's capture — shows up
-    as a mesh.{name} phase span next to the kernel.* dispatch spans."""
+    as a mesh.{name} phase span next to the kernel.* dispatch spans,
+    with `tags` (the place phase: `bytes` uploaded) as the span's."""
     cap = getattr(_capture_tls, "capture", None)
     if cap is not None:
-        cap.mesh_phases.append((name, t0, t1))
+        cap.mesh_phases.append((name, t0, t1, tags))
 
 
 def note_tier_phase(name: str, t0: float, t1: float) -> None:

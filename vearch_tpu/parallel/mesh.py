@@ -88,8 +88,6 @@ def shard_rows(mesh: Mesh, x, pad_value=0):
     """Place a host [N, ...] array row-sharded over the "data" axis,
     padding N up to a multiple of the axis size. Returns (device_array,
     orig_n)."""
-    import jax.numpy as jnp
-
     n_shards = mesh.shape["data"]
     n = x.shape[0]
     rem = (-n) % n_shards
@@ -99,14 +97,16 @@ def shard_rows(mesh: Mesh, x, pad_value=0):
     sharding = NamedSharding(mesh, P("data", *([None] * (x.ndim - 1))))
     # .nbytes is metadata on both numpy and jax arrays — no host sync
     perf_model.note_h2d_bytes(int(getattr(x, "nbytes", 0)))
-    return jax.device_put(jnp.asarray(x), sharding), n
+    # the host array goes to the devices shard by shard: staged through
+    # jnp.asarray the WHOLE array sat on device 0 first (0.82 GB peak
+    # there for a 1M x 96 partition whose shards are 0.12 GB; a corpus
+    # one chip cannot hold would not have been placed at all)
+    return jax.device_put(x, sharding), n
 
 
 def shard_queries(mesh: Mesh, q):
     """Place a host [B, d] query batch sharded over the "query" axis
     (replicated over "data")."""
-    import jax.numpy as jnp
-
     n_shards = mesh.shape["query"]
     b = q.shape[0]
     rem = (-b) % n_shards
@@ -116,15 +116,13 @@ def shard_queries(mesh: Mesh, q):
         )
     sharding = NamedSharding(mesh, P("query", None))
     perf_model.note_h2d_bytes(int(getattr(q, "nbytes", 0)))
-    return jax.device_put(jnp.asarray(q), sharding), b
+    return jax.device_put(q, sharding), b
 
 
 def replicate(mesh: Mesh, x):
-    import jax.numpy as jnp
-
     spec = P(*([None] * np.ndim(x)))
     perf_model.note_h2d_bytes(int(getattr(x, "nbytes", 0)))
-    return jax.device_put(jnp.asarray(x), NamedSharding(mesh, spec))
+    return jax.device_put(x, NamedSharding(mesh, spec))
 
 
 @functools.lru_cache(maxsize=16)

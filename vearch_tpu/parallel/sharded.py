@@ -261,15 +261,7 @@ def _ivf_search_fn(
     else:
         in_specs = mirror_specs + rerank_specs
 
-    @jax.jit
-    @functools.partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=(P("query", None), P("query", None)),
-        check_vma=False,
-    )
-    def run(*args):
+    def program(*args):
         if probed:
             cents, assign, a8, sc, vsq, v, b, bsqn, q = args
         else:
@@ -287,57 +279,76 @@ def _ivf_search_fn(
                 (q.shape[0], cents.shape[0]), dtype=bool
             ).at[jnp.arange(q.shape[0])[:, None], probes].set(True)
             ok = ok & cell[:, jnp.maximum(assign, 0)]
-        rows = a8.astype(jnp.bfloat16) if storage == "int8" \
-            else unpack_int4(a8)
-        dots = jax.lax.dot_general(
-            q.astype(jnp.bfloat16), rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sc[None, :]
-        if scan_metric is MetricType.L2:
-            scores = -(sqnorms(q)[:, None] - 2.0 * dots + vsq[None, :])
-        else:
-            scores = dots
-        scores = jnp.where(ok, scores, NEG_INF)
+        # the stages carry the named scopes of ops/ivf.py
+        # int8_scan_rerank (block_max and select come with
+        # _select_topk), plus the two only a mesh has: merge and pmax
+        with jax.named_scope("score"):
+            rows = a8.astype(jnp.bfloat16) if storage == "int8" \
+                else unpack_int4(a8)
+            dots = jax.lax.dot_general(
+                q.astype(jnp.bfloat16), rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sc[None, :]
+            if scan_metric is MetricType.L2:
+                scores = -(sqnorms(q)[:, None] - 2.0 * dots + vsq[None, :])
+            else:
+                scores = dots
+            scores = jnp.where(ok, scores, NEG_INF)
         top_s, top_i = _select_topk(scores, min(r, local_n), topk_mode)
         shard = jax.lax.axis_index("data")
-        gids = jnp.where(top_i >= 0, top_i + shard * local_n, -1)
-        all_s = jax.lax.all_gather(top_s, "data", axis=1, tiled=True)
-        all_i = jax.lax.all_gather(gids, "data", axis=1, tiled=True)
-        rr = min(r, all_s.shape[1])
-        cand_s, pos = jax.lax.top_k(all_s, rr)
-        cand_i = jnp.take_along_axis(all_i, pos, axis=1)
+        with jax.named_scope("merge"):
+            gids = jnp.where(top_i >= 0, top_i + shard * local_n, -1)
+            all_s = jax.lax.all_gather(top_s, "data", axis=1, tiled=True)
+            all_i = jax.lax.all_gather(gids, "data", axis=1, tiled=True)
+            rr = min(r, all_s.shape[1])
+            cand_s, pos = jax.lax.top_k(all_s, rr)
+            cand_i = jnp.take_along_axis(all_i, pos, axis=1)
         # exact rerank against the shard's raw slab: candidates this
         # shard does not own score -inf and the pmax merge recovers the
         # owner's exact score everywhere (same ownership math as
         # _exact_rerank_fn, with the BASE slab size — the mirror and the
         # raw buffer are padded to different alignments)
-        local_nb = b.shape[0]
-        local = cand_i - shard * local_nb
-        mine = (cand_i >= 0) & (local >= 0) & (local < local_nb)
-        safe = jnp.clip(local, 0, local_nb - 1)
-        vecs = b[safe]  # [B, rr, d]
-        bvsq = bsqn[safe]
-        qf = q.astype(b.dtype)
-        rdots = jax.lax.dot_general(
-            qf, vecs, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-            precision=dot_precision(qf, vecs),
-        )
-        if rerank_metric is MetricType.L2:
-            rscores = -(sqnorms(qf)[:, None] - 2.0 * rdots + bvsq)
-        elif rerank_metric is MetricType.COSINE:
-            qn = jnp.sqrt(jnp.maximum(sqnorms(qf), 1e-30))[:, None]
-            vn = jnp.sqrt(jnp.maximum(bvsq, 1e-30))
-            rscores = rdots / (qn * vn)
-        else:
-            rscores = rdots
-        rscores = jnp.where(mine, rscores, NEG_INF)
-        rscores = jax.lax.pmax(rscores, "data")
-        kk = min(k, rscores.shape[1])
-        out_s, out_pos = jax.lax.top_k(rscores, kk)
-        out_i = jnp.take_along_axis(cand_i, out_pos, axis=1)
-        return out_s, jnp.where(jnp.isfinite(out_s), out_i, -1)
+        with jax.named_scope("rerank"):
+            local_nb = b.shape[0]
+            local = cand_i - shard * local_nb
+            mine = (cand_i >= 0) & (local >= 0) & (local < local_nb)
+            safe = jnp.clip(local, 0, local_nb - 1)
+            vecs = b[safe]  # [B, rr, d]
+            bvsq = bsqn[safe]
+            qf = q.astype(b.dtype)
+            rdots = jax.lax.dot_general(
+                qf, vecs, (((1,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+                precision=dot_precision(qf, vecs),
+            )
+            if rerank_metric is MetricType.L2:
+                rscores = -(sqnorms(qf)[:, None] - 2.0 * rdots + bvsq)
+            elif rerank_metric is MetricType.COSINE:
+                qn = jnp.sqrt(jnp.maximum(sqnorms(qf), 1e-30))[:, None]
+                vn = jnp.sqrt(jnp.maximum(bvsq, 1e-30))
+                rscores = rdots / (qn * vn)
+            else:
+                rscores = rdots
+            rscores = jnp.where(mine, rscores, NEG_INF)
+        with jax.named_scope("pmax"):
+            rscores = jax.lax.pmax(rscores, "data")
+        with jax.named_scope("rerank"):
+            kk = min(k, rscores.shape[1])
+            out_s, out_pos = jax.lax.top_k(rscores, kk)
+            out_i = jnp.take_along_axis(cand_i, out_pos, axis=1)
+            return out_s, jnp.where(jnp.isfinite(out_s), out_i, -1)
 
+    # jit names the XLA module after the function: the serving program
+    # is `jit_sharded_fused_scan_rerank` on the device trace (probe
+    # regime `jit_sharded_probe_scan_rerank`), its dispatch tag, where
+    # every other shard_map program of this file is `jit_run`
+    program.__name__ = program.__qualname__ = (
+        "sharded_probe_scan_rerank" if probed
+        else "sharded_fused_scan_rerank")
+    run = jax.jit(shard_map(
+        program, mesh=mesh, in_specs=in_specs,
+        out_specs=(P("query", None), P("query", None)), check_vma=False,
+    ))
     return register_jit(
         f"sharded.ivf_fused[{_mesh_tag(mesh)},r{r},k{k},"
         f"{scan_metric.name},{rerank_metric.name},{topk_mode},{storage},"
