@@ -121,6 +121,12 @@ def recorded(tmp_path_factory):
                     {"operator": "=", "field": "color", "value": "red"}]},
                 fields=["color", "price"],
             ))
+            # ids and scores only: the Python SDK asks the array form
+            # of the reply (cluster/hitarrays.py); the Go/Java/Rust
+            # SDKs do not, and keep the rows of "search"
+            op("search_ids", lambda: cl.search(
+                "db", "sp", [{"field": "emb", "feature": vecs[1].tolist()}],
+                limit=3, fields=[]))
             op("query", lambda: cl.query("db", "sp",
                                          document_ids=["d1", "d2"]))
             op("delete", lambda: cl.delete("db", "sp",

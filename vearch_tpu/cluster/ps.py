@@ -27,7 +27,7 @@ from typing import Any
 
 from vearch_tpu.engine.engine import Engine, SearchRequest
 from vearch_tpu.engine.types import DataType, TableSchema
-from vearch_tpu.cluster import rpc
+from vearch_tpu.cluster import hitarrays, rpc
 from vearch_tpu.cluster.entities import Partition
 from vearch_tpu.cluster.metrics import (
     SIZE_BUCKETS,
@@ -450,6 +450,7 @@ class PSServer:
             "requests shed (429) by admission control before any "
             "device work, per op and tenant space",
             ("op", "space"))
+        self._reply_forms = hitarrays.reply_form_counter(m)
         # render from 1st scrape; no tenant has been admitted yet
         self._shed_total.inc(  # lint: allow[space-attr] zero-fill render
             "search", accounting.OTHER_LABEL, by=0.0)
@@ -2149,6 +2150,7 @@ class PSServer:
                 eng, pid, applied, body, vectors, ctx, trace
             )
             post = span.child("ps.post")
+            self._reply_forms.inc(hitarrays.form_of(out))
             # every response carries the partition's apply version
             # — the router's entry-validation signal
             out["apply_version"] = applied
@@ -2311,6 +2313,7 @@ class PSServer:
                    trace: dict | None = None) -> dict:
         columnar = bool(
             body.get("columnar_wire") and body.get("include_fields") == []
+            and not body.get("sort")  # sort values ride the rows form
         )
         # raw_results skips the microbatcher, so only take the columnar
         # engine shape when the batch is big enough that per-item
@@ -2368,28 +2371,22 @@ class PSServer:
         if columnar:
             from vearch_tpu.engine.types import ColumnarSearchResults
 
-            # fields-free searches ride columnar: keys as string lists,
-            # scores as ONE ndarray over the binary tensor codec —
-            # per-item JSON dicts for b=1024*k results were a measured
-            # chunk of the e2e batch latency
+            # fields-free searches answer arrays (cluster/hitarrays.py):
+            # the keys as one UTF-8 blob with their lengths, the hits of
+            # each query row, the scores as ONE buffer over the binary
+            # tensor codec — no Python object a hit, and a frame whose
+            # JSON header holds a dozen scalars
             if isinstance(results, ColumnarSearchResults):
-                out = {
-                    "metric": metric,
-                    "columnar": True,
-                    "keys": results.keys,
-                    "scores": np.asarray(results.scores, dtype=np.float32),  # lint: allow[host-sync] terminal result materialization for the wire codec
-                }
+                packed = hitarrays.pack(
+                    results.flat_keys, results.counts, results.scores)
             else:
-                # engine fell back to the item shape (e.g. sort rode in)
-                out = {
-                    "metric": metric,
-                    "columnar": True,
-                    "keys": [[it.key for it in r.items] for r in results],
-                    "scores": np.asarray(  # lint: allow[host-sync] terminal result materialization for the wire codec
-                        [it.score for r in results for it in r.items],
-                        dtype=np.float32,
-                    ),
-                }
+                # the engine kept the item shape (rows below the
+                # scheduler's bound ride its co-batched dispatch)
+                packed = hitarrays.pack(
+                    [it.key for r in results for it in r.items],
+                    [len(r.items) for r in results],
+                    [it.score for r in results for it in r.items])
+            out = {"metric": metric, **packed}
         else:
             out = {
                 "metric": metric,

@@ -17,7 +17,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any
 
-from vearch_tpu.cluster import rpc
+from vearch_tpu.cluster import hitarrays, rpc
 from vearch_tpu.cluster.entities import Server, Space
 from vearch_tpu.cluster.rpc import ERR_REQUEST_KILLED, JsonRpcServer, RpcError
 from vearch_tpu.obs import accounting
@@ -258,6 +258,7 @@ class RouterServer:
             "replica answers discarded for a stale apply_version and "
             "re-fetched from the leader (read-your-writes guard)", ())
         self._m_replica_refetch.inc(by=0.0)
+        self._m_reply_forms = hitarrays.reply_form_counter(m)
 
         def _route_series():
             with self._route_lock:
@@ -1387,6 +1388,7 @@ class RouterServer:
             out = self._retry_moved(
                 (body["db_name"], body["space_name"]),
                 lambda: self._search_impl(body))
+            self._m_reply_forms.inc(hitarrays.form_of(out))
             return out
         except RpcError as e:
             # a killed request (deadline/slow/operator) is terminal —
@@ -1682,91 +1684,70 @@ class RouterServer:
         partials = [r for _, r in results]
         merge_span = root.child("router.merge")  # merge_ms's window
         t_merge = _time.monotonic()
-        if sort_specs:
-            merged = self._merge_search_sorted(
-                partials, sort_specs, k, start, size)
+        # a caller that asks `columnar` for a fields-free, unsorted
+        # search is answered arrays, merged as arrays
+        # (cluster/hitarrays.py; the SDK builds the rows, so its return
+        # type is unchanged); every other search is answered rows
+        want_arrays = bool(body.get("columnar") and body.get("fields") == []
+                           and not sort_specs)
+        merged = (self._merge_arrays(partials, k, start, size)
+                  if want_arrays else None)
+        if merged is not None:
+            out = merged
+        elif sort_specs:
+            out = {"documents": self._merge_search_sorted(
+                partials, sort_specs, k, start, size)}
         else:
-            merged = self._merge_search(partials, k)
-            # window slice within top-k (no-op without paging:
-            # start=0, size=k)
-            merged = [rows[start:start + size] for rows in merged]
-        if body.get("columnar") and body.get("fields") == []:
-            # opt-in columnar response: the client gets key lists +
-            # ONE flat f32 score buffer over the binary codec
-            # instead of b*k JSON dicts (the SDK reshapes, so its
-            # return type is unchanged)
-            import numpy as np
-
-            out = {
-                "columnar": True,
-                "keys": [[r["_id"] for r in rows] for rows in merged],
-                # lint: allow[host-sync] packs merged host floats for the columnar wire codec, no device involved
-                "scores": np.asarray(
-                    [r["_score"] for rows in merged for r in rows],
-                    dtype=np.float32,
-                ),
-            }
-        else:
-            out = {"documents": merged}
+            # window slice within top-k (no-op without paging: start=0,
+            # size=k)
+            rows = [r[start:start + size]
+                    for r in self._merge_search(partials, k)]
+            # a version-skewed partition answered rows
+            out = hitarrays.from_rows(rows) if want_arrays \
+                else {"documents": rows}
         merge_ms = round((_time.monotonic() - t_merge) * 1e3, 3)
         merge_span.finish()
         return out, results, merge_ms
 
+    @staticmethod
+    def _merge_arrays(partials: list[dict], k: int, start: int,
+                      size: int) -> dict | None:
+        """Top-k merge across partitions on the replies' arrays, or None
+        when a partition answered rows. Scores are metric-oriented: L2
+        ascending, IP/cosine descending."""
+        if not partials or not all(p.get("columnar") for p in partials):
+            return None
+        return hitarrays.merge(
+            # a partition server from before the array form answers
+            # key lists beside its flat scores
+            [p if hitarrays.is_arrays(p)
+             else hitarrays.from_key_lists(p["keys"], p["scores"])
+             for p in partials],
+            k, start, size, reverse=partials[0]["metric"] != "L2")
+
     def _merge_search(
         self, partials: list[dict], k: int
     ) -> list[list[dict]]:
-        """Top-k merge across partitions (reference: client.go:779 sorted
-        merge). Scores are metric-oriented: L2 ascending, IP/cosine
-        descending."""
+        """Top-k merge across partitions as rows of `{"_id", "_score"}`
+        (reference: client.go:779 sorted merge)."""
+        merged = self._merge_arrays(partials, k, 0, k)
+        if merged is not None:
+            return hitarrays.to_rows(merged)
+        return self._merge_rows(partials, k)
+
+    def _merge_rows(
+        self, partials: list[dict], k: int
+    ) -> list[list[dict]]:
+        """The row merge: what partials with fields carry, and what a
+        version-skewed mix (one PS answering arrays, another rows) is
+        normalised down to."""
         if not partials:
             return []
-        metric = partials[0]["metric"]
-        reverse = metric != "L2"
-        n_columnar = sum(1 for p in partials if p.get("columnar"))
-        if 0 < n_columnar < len(partials):
-            # version-skewed mix (one PS answered columnar, another
-            # rows): normalize columnar partials down to row form so
-            # the merge below sees one shape
-            partials = [
-                self._rows_from_columnar(p) if p.get("columnar") else p
-                for p in partials
-            ]
-        if n_columnar == len(partials):
-            # fields-free fast path: merge on raw key/score arrays and
-            # build ONLY the final top-k dicts for the client response.
-            # ONE sort for the whole reply. A numpy call a query row
-            # (concatenate, argsort, take) lets go of the interpreter
-            # lock each time, and with other requests on the host path
-            # every one of those is a hand-over to another thread: some
-            # 250 of them in a 64-row reply, 10 ms of the router's own
-            # 17 ms a request once the chip stopped being the wait
-            # (PERF.md section 6, PR 26)
-            import numpy as np
-
-            nq = len(partials[0]["keys"])
-            # scores arrive as one flat buffer per partition, query by
-            # query; the key lists give each query's share of it
-            flat = np.concatenate([
-                # lint: allow[host-sync] wraps the wire-decoded score buffer (already host memory), no device involved
-                np.asarray(p["scores"]).reshape(-1) for p in partials])
-            keys = [key for p in partials for ks in p["keys"] for key in ks]
-            query = np.concatenate([
-                np.repeat(np.arange(nq), [len(ks) for ks in p["keys"]])
-                for p in partials])
-            # stable, on the NEGATED scores for descending order: ties
-            # keep partition order, then the partition's own order, as
-            # the legacy dict-row merge does (reversing an ascending
-            # sort would invert them)
-            order = np.lexsort((-flat if reverse else flat, query))
-            ends = np.cumsum(np.bincount(query, minlength=nq)).tolist()
-            top, order = flat[order].tolist(), order.tolist()
-            out, lo = [], 0
-            for hi in ends:
-                stop = min(hi, lo + k)
-                out.append([{"_id": keys[i], "_score": s}
-                            for i, s in zip(order[lo:stop], top[lo:stop])])
-                lo = hi
-            return out
+        reverse = partials[0]["metric"] != "L2"
+        partials = [
+            self._rows_from_columnar(p) if p.get("columnar") else p
+            for p in partials
+        ]
         nq = len(partials[0]["results"])
         out = []
         for qi in range(nq):
@@ -1839,22 +1820,14 @@ class RouterServer:
 
     @staticmethod
     def _rows_from_columnar(p: dict) -> dict:
-        """Expand a columnar search partial ({keys, scores} arrays) to
-        the row form ({results: [[{_id,_score}]]}) the slow merge path
-        consumes."""
-        import numpy as np
-
-        # lint: allow[host-sync] wraps the wire-decoded score buffer (already host memory), no device involved
-        flat = np.asarray(p["scores"])
-        offs = np.cumsum([0] + [len(ks) for ks in p["keys"]])
-        results = [
-            [{"_id": kk, "_score": ss}
-             for kk, ss in zip(ks, flat[offs[i]:offs[i + 1]].tolist())]
-            for i, ks in enumerate(p["keys"])
-        ]
+        """Expand a columnar search partial (arrays, or the older key
+        lists beside flat scores) to the row form ({results:
+        [[{_id,_score}]]}) the row merges consume."""
+        if not hitarrays.is_arrays(p):
+            p = {**p, **hitarrays.from_key_lists(p["keys"], p["scores"])}
         out = {k_: v for k_, v in p.items()
-               if k_ not in ("columnar", "keys", "scores")}
-        out["results"] = results
+               if k_ not in ("columnar", "keys", *hitarrays.ARRAYS)}
+        out["results"] = hitarrays.to_rows(p)
         return out
 
     def _h_query(self, body: dict, _parts) -> dict:

@@ -9,15 +9,22 @@ buffers appended after a JSON skeleton (`_encode`/`_decode`), so a
 of ~1.4 MB of parsed-float JSON — the reference's custom rpcx codec
 serves the same purpose for its vector payloads.
 
-Wire format (Content-Type: application/x-vearch-tensors):
+Wire format (Content-Type: application/x-vtensors2):
     [u32 header_len][header json][tensor 0 bytes][tensor 1 bytes]...
-header = {"body": <json, ndarray leaves replaced by {"__tensor__": i}>,
+header = {"body": <json, ndarray leaves replaced by null>,
+          "paths": [<key path of tensor i in body>, ...],
           "tensors": [{"dtype", "shape"}, ...]}
+Arrays at `<name>` or `<name>.<name>` of a dict body are taken out
+without a walk of the rest (`_extract_shallow`); a fields-free search
+reply is four such arrays and a dozen scalars (cluster/hitarrays.py).
+(v1, application/x-vearch-tensors, marked tensors in the body with
+{"__tensor__": i}; it is still decoded.)
 """
 
 from __future__ import annotations
 
 import json
+import math
 import struct
 import threading
 import time
@@ -110,20 +117,41 @@ def _probably_has_tensor(body: Any) -> bool:
     return False
 
 
-def _encode(body: Any) -> tuple[str, bytes]:
-    """JSON when tensor-free; binary framing otherwise. The tensor-free
-    case is detected by letting json.dumps fail on the first ndarray —
-    pure-JSON bodies (the vast majority of control traffic and most
-    responses) serialize at C speed with no Python tree walk."""
-    if not _probably_has_tensor(body):
-        try:
-            return JSON_CT, json.dumps(body).encode()
-        except TypeError:
-            pass  # a deeply nested tensor the probe missed
+def _extract_shallow(body: dict) -> tuple[dict, list, list]:
+    """`_extract_tensors` for the arrays that sit at `<name>` or
+    `<name>.<name>` of a dict body (a reply's `data.scores`, a search's
+    `vectors.<field>`), in the order the full walk finds them; every
+    other value stays where it is, unvisited. An array any deeper is
+    left in the skeleton, where `json.dumps` refuses it."""
     tensors: list[np.ndarray] = []
     paths: list[list] = []
-    skeleton = _extract_tensors(body, tensors, paths, ())
-    arrays = [np.ascontiguousarray(t) for t in tensors]
+    skeleton = {}
+    for k, v in body.items():
+        if isinstance(v, np.ndarray):
+            tensors.append(v)
+            paths.append([str(k)])
+            v = None
+        elif isinstance(v, dict) and any(
+                isinstance(x, np.ndarray) for x in v.values()):
+            inner = {}
+            for k2, v2 in v.items():
+                if isinstance(v2, np.ndarray):
+                    tensors.append(v2)
+                    paths.append([str(k), str(k2)])
+                    v2 = None
+                inner[k2] = v2
+            v = inner
+        skeleton[k] = v
+    return skeleton, tensors, paths
+
+
+def _frame(skeleton: Any, tensors: list, paths: list) -> bytes:
+    # a tensor that is contiguous already is neither copied nor turned
+    # to bytes: the join reads its buffer (a 64 x 768 query batch was
+    # copied twice; a small reply's frame costs its calls into numpy).
+    # 0-d goes through ascontiguousarray, which frames it as [1]
+    arrays = [t if t.ndim and t.flags.c_contiguous
+              else np.ascontiguousarray(t) for t in tensors]
     header = json.dumps({
         "body": skeleton,
         "paths": paths,
@@ -132,8 +160,34 @@ def _encode(body: Any) -> tuple[str, bytes]:
         ],
     }).encode()
     parts = [_U32.pack(len(header)), header]
-    parts.extend(a.tobytes() for a in arrays)
-    return BIN_CT, b"".join(parts)
+    parts.extend(a.data for a in arrays)
+    return b"".join(parts)
+
+
+def _encode(body: Any) -> tuple[str, bytes]:
+    """JSON when tensor-free; binary framing otherwise. The tensor-free
+    case is detected by letting json.dumps fail on the first ndarray —
+    pure-JSON bodies (the vast majority of control traffic and most
+    responses) serialize at C speed with no Python tree walk. A body
+    whose arrays all sit at shallow paths is framed the same way: the
+    shallow pass takes them out and json.dumps writes the rest at C
+    speed, where the full walk visited every node of a reply in Python
+    to find one array at `data.scores`. The frame is byte for byte the
+    full walk's."""
+    if not _probably_has_tensor(body):
+        try:
+            return JSON_CT, json.dumps(body).encode()
+        except TypeError:
+            pass  # a deeply nested tensor the probe missed
+    if isinstance(body, dict):
+        try:
+            return BIN_CT, _frame(*_extract_shallow(body))
+        except TypeError:
+            pass  # an array below the shallow paths: the full walk
+    tensors: list[np.ndarray] = []
+    paths: list[list] = []
+    skeleton = _extract_tensors(body, tensors, paths, ())
+    return BIN_CT, _frame(skeleton, tensors, paths)
 
 
 def _restore_markers_v1(obj: Any, tensors: list[np.ndarray]) -> Any:
@@ -159,13 +213,12 @@ def _decode(content_type: str, raw: bytes) -> Any:
     tensors = []
     for meta in header["tensors"]:
         dt = np.dtype(meta["dtype"])
-        n = int(np.prod(meta["shape"], dtype=np.int64)) if meta["shape"] \
-            else 1
-        nbytes = n * dt.itemsize
-        arr = np.frombuffer(raw, dtype=dt, count=n, offset=off).reshape(
-            meta["shape"]
-        )
-        off += nbytes
+        shape = meta["shape"]
+        n = math.prod(shape)  # 1 for a scalar's empty shape
+        arr = np.frombuffer(raw, dtype=dt, count=n, offset=off)
+        if len(shape) != 1:
+            arr = arr.reshape(shape)
+        off += n * dt.itemsize
         tensors.append(arr)
     body = header["body"]
     if "paths" not in header:
@@ -252,13 +305,15 @@ def _sample_profile(seconds: float, interval: float = 0.01) -> str:
 
 
 def _record_serve(slot, t0_ns: int, t1_ns: int, decode, encode,
-                  n_in: int, n_out: int, code: int) -> None:
+                  n_in: int, n_out: int, code: int, form) -> None:
     """The server's own spans around a sampled handler, recorded after
     the reply went out: `rpc.serve` from before the body was read to
     after the reply was written, parent of the handler's root span, and
     its leaves `rpc.decode` (read + _decode) and `rpc.encode` (_encode +
     write), each a `(t0_ns, t1_ns, cpu_ns)` of the handler thread, or
-    None where the request did not get that far."""
+    None where the request did not get that far. `rpc.encode` carries
+    the reply's wire `form` (`arrays`: a tensor frame; `rows`: plain
+    JSON) and its `bytes`."""
     root = slot.root
     tracer = root.tracer
     tracer.record(
@@ -274,7 +329,8 @@ def _record_serve(slot, t0_ns: int, t1_ns: int, decode, encode,
                       t1_ns=decode[1], cpu_ns=decode[2])
     if encode is not None:
         tracer.record("rpc.encode", ctx=under, t0_ns=encode[0],
-                      t1_ns=encode[1], cpu_ns=encode[2])
+                      t1_ns=encode[1], cpu_ns=encode[2],
+                      tags={"form": form, "bytes": n_out})
 
 
 class JsonRpcServer:
@@ -448,7 +504,7 @@ class JsonRpcServer:
                 # rpc.serve and its leaves: stamped only for a body
                 # that may be sampled, recorded only if the handler's
                 # root span took the slot (tracing.ServeSlot)
-                slot = decode = encode = None
+                slot = decode = encode = form = None
                 n_in = n_out = 0
                 try:
                     # drain the request body BEFORE anything that can
@@ -510,9 +566,13 @@ class JsonRpcServer:
                     else:
                         enc0_ns = time.monotonic_ns()
                         cpu0 = time.thread_time_ns()
-                        n_out = self._reply(200, {"code": 0, "data": result})
+                        n_out, ct = self._reply(
+                            200, {"code": 0, "data": result})
                         cpu = time.thread_time_ns() - cpu0
                         encode = (enc0_ns, time.monotonic_ns(), cpu)
+                        # the reply's wire form: a tensor frame carries
+                        # its bulk as arrays, plain JSON as rows
+                        form = "arrays" if ct == BIN_CT else "rows"
                 except RpcError as e:
                     code = e.code
                     payload = {"code": e.code, "msg": e.msg}
@@ -544,16 +604,16 @@ class JsonRpcServer:
                         tracing.withdraw_serve_slot()
                         if slot.root is not None:
                             _record_serve(slot, t0_ns, t1_ns, decode,
-                                          encode, n_in, n_out, code)
+                                          encode, n_in, n_out, code, form)
 
-            def _reply(self, status: int, obj: dict) -> int:
+            def _reply(self, status: int, obj: dict) -> tuple[int, str]:
                 ct, data = _encode(obj)
                 self.send_response(status)
                 self.send_header("Content-Type", ct)
                 self.send_header("Content-Length", str(len(data)))
                 self.end_headers()
                 self.wfile.write(data)
-                return len(data)
+                return len(data), ct
 
             def do_GET(self):
                 self._serve("GET")

@@ -75,9 +75,9 @@ class SearchRequest:
     # returned ordered by them (reference: SortFields on the request,
     # doc_query.go:1543; sortorder value compare)
     sort: list[dict] | None = None
-    # fields-free fast path: return ColumnarSearchResults (key lists +
-    # one flat score buffer) instead of per-item objects — the serving
-    # shape of the columnar wire; skips the microbatcher
+    # fields-free fast path: return ColumnarSearchResults (flat keys,
+    # hits per query, one flat score buffer) instead of per-item objects
+    # — the serving shape of the columnar wire; skips the microbatcher
     raw_results: bool = False
     # when not None, the engine records per-phase wall times into it
     # (reference: per-request trace:true timing breakdown,
@@ -1540,13 +1540,9 @@ class Engine:
             # one numpy buffer end to end
             from vearch_tpu.engine.types import ColumnarSearchResults
 
-            counts = ok.sum(axis=1).tolist()
-            out_keys, pos = [], 0
-            for c in counts:
-                out_keys.append(keys[pos:pos + c])
-                pos += c
             return ColumnarSearchResults(
-                keys=out_keys,
+                flat_keys=keys,
+                counts=ok.sum(axis=1),
                 scores=np.ascontiguousarray(metric_scores[ok],
                                             dtype=np.float32),
             )

@@ -198,12 +198,22 @@ class SearchResult:
 
 @dataclass
 class ColumnarSearchResults:
-    """Fields-free search results in columnar form: per-query key lists
-    plus ONE flat score buffer (per-query lengths are the key-list
-    lengths). Returned by Engine.search for `raw_results` requests —
-    building b*k SearchResultItem objects measured ~50 ms of host time
-    at b=1024, which a TPU-speed kernel cannot hide; the PS columnar
-    wire path consumes this shape directly."""
+    """Fields-free search results in columnar form: every hit's key in
+    one flat list (query by query), the hits of each query row, and ONE
+    flat score buffer. Returned by Engine.search for `raw_results`
+    requests — building b*k SearchResultItem objects measured ~50 ms of
+    host time at b=1024, which a TPU-speed kernel cannot hide; the PS
+    packs this shape into the reply's arrays (cluster/hitarrays.py)."""
 
-    keys: list[list[str]]
-    scores: Any  # np.ndarray [sum(len(keys_i))] f32
+    flat_keys: list[str]
+    counts: Any  # np.ndarray [queries] int
+    scores: Any  # np.ndarray [len(flat_keys)] f32
+
+    @property
+    def keys(self) -> list[list[str]]:
+        """Per-query key lists (the shadow sampler's and tests' view)."""
+        out, lo = [], 0
+        for n in self.counts.tolist():
+            out.append(self.flat_keys[lo:lo + n])
+            lo += n
+        return out

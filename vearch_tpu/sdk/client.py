@@ -11,7 +11,7 @@ from typing import Any
 
 import numpy as np
 
-from vearch_tpu.cluster import rpc
+from vearch_tpu.cluster import hitarrays, rpc
 
 
 class VearchClient:
@@ -148,6 +148,10 @@ class VearchClient:
         model's documented prediction, and router merge cost (schema in
         docs/OBSERVABILITY.md).
 
+        ``columnar`` is accepted and no longer read: a search with
+        ``fields=[]`` and no ``sort`` always asks the router for the
+        array form of the reply and builds the hit lists here.
+
         ``cache=False`` bypasses the router and partition result
         caches for this request — correctness-sensitive callers and
         cold benchmarks always hit the engines; the profile reports
@@ -189,25 +193,24 @@ class VearchClient:
             body["cache"] = False
         if profile:
             body["profile"] = True
-            return self._doc_call("POST", "/document/search", body)
-        if columnar and fields == []:
-            # fields-free throughput mode: scores ride as ONE binary f32
-            # buffer instead of b*k JSON dicts; reshaped here so the
-            # return type is identical
+        if fields == [] and not sort:
+            # ids and scores only: the router answers four arrays over
+            # the binary codec (cluster/hitarrays.py) instead of b*k
+            # JSON dicts, with or without `profile`, so that a traced
+            # request rides the wire form a timed one does; the rows are
+            # built here, and the return type is the same
             body["columnar"] = True
-            out = self._doc_call("POST", "/document/search", body)
-            if out.get("columnar"):
-                flat = np.asarray(out["scores"]).tolist()
-                res, pos = [], 0
-                for ks in out["keys"]:
-                    res.append([
-                        {"_id": k, "_score": flat[pos + i]}
-                        for i, k in enumerate(ks)
-                    ])
-                    pos += len(ks)
-                return res
-            return out["documents"]
-        return self._doc_call("POST", "/document/search", body)["documents"]
+        out = self._doc_call("POST", "/document/search", body)
+        if out.get("columnar"):
+            # a router from before the array form answers key lists
+            # beside the flat scores; one older still, `documents`
+            if not hitarrays.is_arrays(out):
+                out.update(hitarrays.from_key_lists(
+                    out.pop("keys"), out["scores"]))
+            out["documents"] = hitarrays.to_rows(out)
+            for name in ("columnar", *hitarrays.ARRAYS):
+                del out[name]
+        return out if profile else out["documents"]
 
     def query(
         self,
