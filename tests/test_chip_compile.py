@@ -166,7 +166,7 @@ def test_fused_scan_rerank_compiles(one_chip, widths, b, r):
     compiled = ivf_ops.int8_scan_rerank.lower(
         *_fused_args(_shapes(one_chip), b, n, widths["n_store"]),
         r, widths["fetch_k"], scan_metric=L2, rerank_metric=L2,
-        topk_mode="auto", storage="int8").compile()
+        storage="int8").compile()
     _, temp = _report(f"int8_scan_rerank[B={b},r={r}]", compiled)
     matrix = perf_model.scan_peak_bytes(b, n)
     big = _score_sized(compiled, b, n)
@@ -273,7 +273,7 @@ def test_mesh_fused_program_compiles_for_four_chips(topo, one_chip, widths):
             shape, dt, sharding=NamedSharding(mesh, P(*spec)))
 
     fn = sharded._ivf_search_fn(mesh, RERANK, widths["fetch_k"], L2, L2,
-                                "auto", "int8", 0)
+                                "int8", 0)
     compiled = fn.lower(
         S((n_mirror, D), jnp.int8, "data", None),
         S((n_mirror,), jnp.float32, "data"),
@@ -321,7 +321,7 @@ def test_deep_mesh_program_compiles_for_four_chips(topo, widths, rows, b):
             shape, dt, sharding=NamedSharding(mesh, P(*spec)))
 
     fn = sharded._ivf_search_fn(mesh, RERANK, widths["fetch_k"], L2, L2,
-                                "auto", "int8", 0)
+                                "int8", 0)
     compiled = fn.lower(
         S((n_mirror, DEEP_D), jnp.int8, "data", None),
         S((n_mirror,), jnp.float32, "data"),

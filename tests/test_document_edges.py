@@ -292,11 +292,11 @@ def test_full_update_still_replaces_everything(client, vecs):
 
 
 def test_columnar_response_matches_json(client, vecs):
-    """Opt-in columnar responses return exactly the JSON path's results
-    (ids AND scores) for fields-free searches."""
+    """A fields-free search, which the SDK asks in the array form,
+    returns exactly the JSON rows' results (ids AND scores)."""
     q = [{"field": "emb", "feature": vecs[:8]}]
-    plain = client.search("db", "sp", q, limit=5, fields=[])
-    col = client.search("db", "sp", q, limit=5, fields=[], columnar=True)
+    plain = client.search("db", "sp", q, limit=5)
+    col = client.search("db", "sp", q, limit=5, fields=[])
     assert len(col) == 8
     for a, b in zip(plain, col):
         assert [r["_id"] for r in a] == [r["_id"] for r in b]

@@ -151,14 +151,51 @@ def test_ivfpq_dump_load_preserves_search(rng, tmp_path):
     assert res[0].items[0].key == "d11"
 
 
-def test_int8_scan_blockmax_matches_exact():
-    """Forced block-max two-stage top-k returns the same top candidates
-    as exact lax.top_k on a well-separated dataset (id reconstruction
-    across blocks is the failure mode to catch)."""
+def _full_scores(scan, queries, rows, scale, vsq, valid, metric):
+    """The [B, N] f32 score matrix a full scan hands `_select_topk`,
+    built from the scan's own pieces: what the selection is compared
+    with `lax.top_k` on."""
+    import jax
     import jax.numpy as jnp
 
-    from vearch_tpu.engine.types import MetricType
-    from vearch_tpu.ops.ivf import int8_scan_candidates
+    from vearch_tpu.ops.binary_scan import _binary_scores
+    from vearch_tpu.ops.distance import sqnorms
+    from vearch_tpu.ops.ivf import NEG_INF, unpack_int4
+
+    @jax.jit
+    def scores():
+        if scan == "binary":
+            return _binary_scores(queries, rows, scale, vsq, valid, metric)
+        vals = rows.astype(jnp.bfloat16) if scan == "int8" \
+            else unpack_int4(rows)
+        dots = jax.lax.dot_general(
+            queries.astype(jnp.bfloat16), vals, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale[None, :]
+        if metric is MetricType.L2:
+            out = -(sqnorms(queries)[:, None] - 2.0 * dots + vsq[None, :])
+        else:
+            out = dots
+        return jnp.where(valid[None, :], out, NEG_INF)
+
+    return scores()
+
+
+def _plain_topk(scores, r):
+    """`lax.top_k` over the row, masked slots as id -1."""
+    import jax
+
+    s, i = map(np.asarray, jax.lax.top_k(scores, r))
+    return s, np.where(np.isfinite(s), i, -1)
+
+
+def test_int8_scan_blockmax_matches_exact():
+    """The block-max two-stage top-k returns the same top candidates as
+    exact lax.top_k on a well-separated dataset (id reconstruction
+    across blocks is the failure mode to catch), at a row count below
+    the one from which `_select_topk` takes it of itself."""
+    import jax.numpy as jnp
+
+    from vearch_tpu.ops.ivf import _blocked_topk
 
     rng = np.random.default_rng(7)
     n, d = 512 * 64, 32  # 256 blocks of 128 rows, 32 of them gathered
@@ -168,11 +205,11 @@ def test_int8_scan_blockmax_matches_exact():
     valid = np.ones(n, bool)
     q = base[rng.choice(n, 8, replace=False)].astype(np.float32)
 
-    args = (jnp.asarray(q), jnp.asarray(base), jnp.asarray(scale),
-            jnp.asarray(vsq), jnp.asarray(valid))
-    es, ei = int8_scan_candidates(*args, 32, MetricType.L2, "exact")
-    bs, bi = int8_scan_candidates(*args, 32, MetricType.L2, "blockmax")
-    es, ei, bs, bi = map(np.asarray, (es, ei, bs, bi))
+    scores = _full_scores("int8", *map(jnp.asarray,
+                                       (q, base, scale, vsq, valid)),
+                          MetricType.L2)
+    es, ei = _plain_topk(scores, 32)
+    bs, bi = map(np.asarray, _blocked_topk(scores, 32))
     # top-1 self-match must survive block selection exactly
     np.testing.assert_array_equal(ei[:, 0], bi[:, 0])
     # the two-stage selection is exact: the same scores, and the same
@@ -189,36 +226,34 @@ def test_blockmax_never_resurrects_filtered_docs():
     r2 finding — exact_rerank masks only id>=0, not validity)."""
     import jax.numpy as jnp
 
-    from vearch_tpu.engine.types import MetricType
-    from vearch_tpu.ops.ivf import int8_scan_candidates
+    from vearch_tpu.ops.ivf import BLOCK, _blocked_topk, int8_scan_candidates
 
     rng = np.random.default_rng(3)
-    n, d = 512 * 64, 16
-    base = rng.integers(-100, 100, (n, d)).astype(np.int8)
-    vsq = np.sum(base.astype(np.float32) ** 2, axis=1)
-    valid = np.zeros(n, bool)
-    allowed = rng.choice(n, 40, replace=False)
-    valid[allowed] = True  # only 40 of 32k docs pass the filter
-    q = rng.standard_normal((4, d)).astype(np.float32)
-
-    for mode in ("exact", "blockmax"):
+    # 512 blocks: the fewest from which r=128 selects in two stages;
+    # 40 rows more leave a ragged block and the plain top-k
+    for n in (512 * BLOCK, 512 * BLOCK + 40):
+        d = 16
+        base = rng.integers(-100, 100, (n, d)).astype(np.int8)
+        vsq = np.sum(base.astype(np.float32) ** 2, axis=1)
+        valid = np.zeros(n, bool)
+        allowed = rng.choice(n, 40, replace=False)
+        valid[allowed] = True  # only 40 of 65k docs pass the filter
+        q = rng.standard_normal((4, d)).astype(np.float32)
         s, i = int8_scan_candidates(
             jnp.asarray(q), jnp.asarray(base),
             jnp.asarray(np.ones(n, np.float32)), jnp.asarray(vsq),
-            jnp.asarray(valid), 128, MetricType.L2, mode)
+            jnp.asarray(valid), 128, MetricType.L2)
         s, i = np.asarray(s), np.asarray(i)
         real = i[i >= 0]
-        assert set(real.tolist()) <= set(allowed.tolist()), mode
+        assert set(real.tolist()) <= set(allowed.tolist()), n
         # every -inf slot is id -1
-        assert np.all(i[~np.isfinite(s)] == -1), mode
+        assert np.all(i[~np.isfinite(s)] == -1), n
 
-    # forced blockmax on a tiny space degrades gracefully, no crash
-    small = base[:1024]
-    s, i = int8_scan_candidates(
-        jnp.asarray(q), jnp.asarray(small),
-        jnp.asarray(np.ones(1024, np.float32)), jnp.asarray(vsq[:1024]),
-        jnp.asarray(np.ones(1024, bool)), 128, MetricType.L2, "blockmax")
-    assert np.asarray(s).shape[0] == 4
+    # the two-stage selection on a tiny space (fewer blocks than r)
+    # degrades gracefully, no crash
+    s, _ = _blocked_topk(
+        jnp.asarray(rng.standard_normal((4, 1024)).astype(np.float32)), 128)
+    assert np.asarray(s).shape == (4, 128)
 
 
 def _scan_args(rng, n, d, b, selective):
@@ -234,37 +269,35 @@ def _scan_args(rng, n, d, b, selective):
     return tuple(jnp.asarray(a) for a in (q, base, scale, vsq, valid))
 
 
-# 2,100 blocks of 128 rows: "auto" takes the two-stage selection from
+# 2,100 blocks of 128 rows: the scan takes the two-stage selection from
 # 4 * max(r, 128) = 1,024 blocks, and the top 256 of a row lie in more
 # blocks than a capped selection keeps (136 blocks of 512 until PR 26);
-# +40 rows leave a ragged last block, which "auto" and "blockmax" both
-# answer with the plain top-k
+# +40 rows leave a ragged last block, which it answers with the plain
+# top-k
 @pytest.mark.parametrize("selective", [False, True])
 @pytest.mark.parametrize("n", [128 * 2100, 128 * 2100 + 40])
 @pytest.mark.parametrize("metric",
                          [MetricType.L2, MetricType.INNER_PRODUCT])
 @pytest.mark.parametrize("b", [8, 64])
 def test_scan_candidates_are_the_exact_top_r(b, metric, n, selective):
-    """The two-stage selection returns `lax.top_k` over the full row:
-    the same scores in the same order, the same ids wherever scores do
-    not tie, no id twice, masked slots as -1."""
+    """The scan returns `lax.top_k` over the full row of its score
+    matrix: the same scores in the same order, the same ids wherever
+    scores do not tie, no id twice, masked slots as -1."""
     from vearch_tpu.ops.ivf import int8_scan_candidates
 
     r = 256
     args = _scan_args(np.random.default_rng(b + n), n, 16, b, selective)
-    es, ei = map(np.asarray, int8_scan_candidates(*args, r, metric, "exact"))
-    for mode in ("auto", "blockmax"):
-        s, i = map(np.asarray,
-                   int8_scan_candidates(*args, r, metric, mode))
-        np.testing.assert_array_equal(s, es)
-        # rows of equal score may come back in another order
-        tied = (s == np.roll(s, 1, 1)) | (s == np.roll(s, -1, 1))
-        assert np.all((i == ei) | tied), mode
-        assert np.all((i == -1) == ~np.isfinite(s)), mode
-        assert np.all(np.asarray(args[4])[i[i >= 0]]), "a masked row came back"
-        for row in range(b):
-            real = i[row][i[row] >= 0]
-            assert len(set(real.tolist())) == len(real), "an id twice"
+    es, ei = _plain_topk(_full_scores("int8", *args, metric), r)
+    s, i = map(np.asarray, int8_scan_candidates(*args, r, metric))
+    np.testing.assert_array_equal(s, es)
+    # rows of equal score may come back in another order
+    tied = (s == np.roll(s, 1, 1)) | (s == np.roll(s, -1, 1))
+    assert np.all((i == ei) | tied)
+    assert np.all((i == -1) == ~np.isfinite(s))
+    assert np.all(np.asarray(args[4])[i[i >= 0]]), "a masked row came back"
+    for row in range(b):
+        real = i[row][i[row] >= 0]
+        assert len(set(real.tolist())) == len(real), "an id twice"
 
 
 @pytest.mark.parametrize("b", [5, 8, 64])
@@ -284,7 +317,7 @@ def test_select_topk_on_tied_scores(b):
     scores[0] = NEG_INF  # a row with fewer live slots than r
     scores[0, 120:200] = rng.integers(0, 50, 80)
     es, _ = jax.lax.top_k(jnp.asarray(scores), r)
-    s, i = map(np.asarray, _select_topk(jnp.asarray(scores), r, "auto"))
+    s, i = map(np.asarray, _select_topk(jnp.asarray(scores), r))
     np.testing.assert_array_equal(s, np.asarray(es))
     rows = np.arange(b)[:, None]
     live = i >= 0
@@ -298,7 +331,8 @@ def test_select_topk_on_tied_scores(b):
 @pytest.mark.parametrize("scan", ["int4", "binary"])
 def test_other_full_scans_select_their_exact_top_r(scan):
     """int4 and the 1-bit stage-0 scan hand their own [B, N] scores to
-    the same selection: forced two-stage equals their exact top-k."""
+    the same selection: at 512 blocks, where r=48 selects in two
+    stages, each returns its exact top-k."""
     import jax.numpy as jnp
 
     from vearch_tpu.index.int8_mirror import quantize_rows_int4
@@ -307,7 +341,7 @@ def test_other_full_scans_select_their_exact_top_r(scan):
     from vearch_tpu.ops.ivf import int4_scan_candidates
 
     rng = np.random.default_rng(11)
-    n, d, b, r = 128 * 96, 32, 8, 48
+    n, d, b, r = 128 * 512, 32, 8, 48
     rows = rng.standard_normal((n, d)).astype(np.float32)
     q = jnp.asarray(rows[rng.choice(n, b, replace=False)])
     valid = jnp.asarray(rng.random(n) < 0.5)
@@ -319,9 +353,10 @@ def test_other_full_scans_select_their_exact_top_r(scan):
         fn = binary_scan_candidates
     args = (q, jnp.asarray(packed), jnp.asarray(scale), jnp.asarray(vsq),
             valid)
-    es, ei = map(np.asarray, fn(*args, r, MetricType.L2, "exact"))
-    s, i = map(np.asarray, fn(*args, r, MetricType.L2, "blockmax"))
-    np.testing.assert_array_equal(s, es)
+    es, ei = _plain_topk(_full_scores(scan, *args, MetricType.L2), r)
+    s, i = map(np.asarray, fn(*args, r, MetricType.L2))
+    # the matrix is another program's: its scores round an ulp apart
+    np.testing.assert_allclose(s, es, rtol=1e-6, atol=1e-5)
     assert np.mean(i == ei) > 0.99  # all but rows of equal score
 
 

@@ -43,20 +43,22 @@ MESH_PARAMS = {
 }
 
 
-def _ivfpq_pair(rng, metric=MetricType.L2, storage="int8"):
+def _ivfpq_pair(rng, metric=MetricType.L2, storage="int8", n=N,
+                mesh_shape=None):
     """Same data, same training → one single-device index, one mesh."""
-    data = rng.standard_normal((N, D)).astype(np.float32)
+    data = rng.standard_normal((n, D)).astype(np.float32)
 
     def build(ms):
         params = IndexParams("IVFPQ", metric, {
             "ncentroids": 16, "nsubvector": 8, "train_iters": 4,
             "mirror_dtype": storage, "mesh_serving": ms,
+            "mesh_shape": mesh_shape,
         })
         store = RawVectorStore(D)
         store.add(data)
         idx = IVFPQIndex(params, store)
         idx.train(data[:2000])
-        idx.absorb(N)
+        idx.absorb(n)
         return idx
 
     return build("off"), build("on"), data
@@ -78,16 +80,23 @@ def test_mesh_ivfpq_bit_identical(rng, storage):
 @pytest.mark.parametrize("storage", ["int8", "int4"])
 def test_mesh_two_stage_selection_matches_exact(rng, storage):
     """Every shard hands its own [B, N/shards] scores to the selection
-    the single-device scan uses: forced two-stage ("blockmax") on the
-    mesh returns what the single-device twin returns, and both what the
-    plain top-k ("exact") returns."""
-    single, mesh, _ = _ivfpq_pair(rng, storage=storage)
+    the single-device scan uses. At 512 blocks a shard, where r=128
+    selects in two stages on both (tests/test_index_ivf.py holds that
+    selection to `lax.top_k` over the row), the mesh returns what the
+    single-device twin returns."""
+    from vearch_tpu.ops.ivf import BLOCK
+
+    shards, r = 2, 128
+    n = shards * 4 * r * BLOCK
+    single, mesh, _ = _ivfpq_pair(rng, storage=storage, n=n,
+                                  mesh_shape=f"{shards}x1")
     q = rng.standard_normal((8, D)).astype(np.float32)
-    es, ei = single.search(q, 10, None, {"topk_mode": "exact"})
-    for idx in (single, mesh):
-        bs, bi = idx.search(q, 10, None, {"topk_mode": "blockmax"})
-        assert np.array_equal(bi, ei)
-        assert np.array_equal(bs, es)
+    es, ei = single.search(q, 10, None, {"rerank": r})
+    bs, bi = mesh.search(q, 10, None, {"rerank": r})
+    assert mesh._mirror._sh_cache.capacity(
+        mesh._serving_mesh(None), n) == n  # no padding: 512 blocks each
+    assert np.array_equal(bi, ei)
+    assert np.array_equal(bs, es)
 
 
 def test_mesh_ivfpq_bit_identical_through_absorb(rng):
@@ -240,12 +249,15 @@ def mesh_engine():
 def test_mesh_paths_launch_documented_dispatches(mesh_engine):
     eng, vecs = mesh_engine
     doc = perf_model.DOCUMENTED_DISPATCHES
+    # no exact rerank wanted (SCANN reordering=false): scan and merge
+    scan_eng, _ = _build("SCANN", {**MESH_PARAMS, "reordering": False},
+                         n=1000)
     cases = {
-        "ivfpq_mesh_fused": {"scan_mode": "full"},
-        "ivfpq_mesh_unfused": {"scan_mode": "full", "fused_rerank": False},
+        "ivfpq_mesh_fused": (eng, {"scan_mode": "full"}),
+        "ivfpq_mesh_scan": (scan_eng, {"scan_mode": "full"}),
     }
-    for path, params in cases.items():
-        ledger = _search(eng, vecs, index_params=params)
+    for path, (engine, params) in cases.items():
+        ledger = _search(engine, vecs, index_params=params)
         assert ledger.tags == doc[path], (
             f"{path}: launched {ledger.tags}, documented {doc[path]}"
         )
@@ -276,8 +288,7 @@ def test_mesh_three_stage_documented_dispatch_and_parity(rng):
 
     def build(ms):
         params = IndexParams("IVFRABITQ", MetricType.L2, {
-            "ncentroids": 16, "train_iters": 4, "topk_mode": "exact",
-            "mesh_serving": ms,
+            "ncentroids": 16, "train_iters": 4, "mesh_serving": ms,
         })
         store = RawVectorStore(D)
         store.add(data)
@@ -429,23 +440,26 @@ def test_mesh_trace_reports_phases_and_placement(mesh_engine):
     assert 0 < kernel[3]["launch_us"] <= kernel[2]
 
 
-@pytest.mark.parametrize("params,tag,label,module", [
-    ({"scan_mode": "full"}, "sharded_fused_scan_rerank",
+@pytest.mark.parametrize("index,params,tag,label,module", [
+    ("IVFPQ", {"scan_mode": "full"}, "sharded_fused_scan_rerank",
      ("sharded.ivf_fused[", ",p0]"), "jit_sharded_fused_scan_rerank"),
-    ({"scan_mode": "probe", "nprobe": 8}, "sharded_probe_scan_rerank",
+    ("IVFPQ", {"scan_mode": "probe", "nprobe": 8},
+     "sharded_probe_scan_rerank",
      ("sharded.ivf_fused[", ",p8]"), "jit_sharded_probe_scan_rerank"),
-    ({"scan_mode": "full", "fused_rerank": False}, "sharded_scan",
+    ("SCANN", {"scan_mode": "full"}, "sharded_scan",
      ("sharded.int8[", "]"), "jit_run"),
 ])
 def test_mesh_serving_program_is_named_on_the_device_trace(
-        mesh_engine, params, tag, label, module):
+        mesh_engine, index, params, tag, label, module):
     """XLA names a module after the jitted function, and the benchmark
     finds a dispatch on the device trace by that name
     (benchmark/kernels/sharded_fused_scan_rerank.py): the fused mesh
     program carries its dispatch tag, with its stages as named scopes;
     every other shard_map program of parallel/sharded.py is `jit_run`.
-    Every mesh dispatch stamps `launch_us`."""
-    eng, vecs = mesh_engine
+    Every mesh dispatch stamps `launch_us`. SCANN with
+    `reordering: false` wants no rerank: scan and merge alone."""
+    eng, vecs = mesh_engine if index == "IVFPQ" else _build(
+        "SCANN", {**MESH_PARAMS, "reordering": False}, n=1000)
     trace: dict = {}
     eng.search(SearchRequest(vectors={"emb": vecs[:8]}, k=10,
                              include_fields=[], index_params=params,
@@ -557,14 +571,11 @@ def test_mesh_serving_config_validation():
         IVFPQIndex(IndexParams("IVFPQ", MetricType.L2, {
             "ncentroids": 4, "nsubvector": 8, "mesh_serving": "sideways",
         }), store)
-    idx = IVFPQIndex(IndexParams("IVFPQ", MetricType.L2, {
-        "ncentroids": 4, "nsubvector": 8, "mesh_serving": True,
-    }), store)
-    assert idx.data_parallel  # boolean alias still accepted
-    idx2 = IVFPQIndex(IndexParams("IVFPQ", MetricType.L2, {
-        "ncentroids": 4, "nsubvector": 8, "data_parallel": False,
-    }), store)
-    assert not idx2.data_parallel
+    for given, read in ((True, "on"), (False, "off"), ("AUTO", "auto")):
+        idx = IVFPQIndex(IndexParams("IVFPQ", MetricType.L2, {
+            "ncentroids": 4, "nsubvector": 8, "mesh_serving": given,
+        }), store)
+        assert idx.mesh_serving == read
 
 
 def test_apply_config_toggles_mesh_serving():

@@ -129,8 +129,8 @@ def test_sharded_int8_search(rng):
     assert (i[:, 0] == np.arange(6)).all()
 
 
-def test_ivfpq_data_parallel_matches_single_device(rng):
-    """Engine-level mesh-spanning IVFPQ partition: data_parallel=True
+def test_ivfpq_mesh_serving_matches_single_device(rng):
+    """Engine-level mesh-spanning IVFPQ partition: mesh_serving "on"
     row-shards the int8 mirror + rerank buffer over all 8 CPU devices;
     results must match the single-device path."""
     from vearch_tpu.engine.engine import Engine, SearchRequest
@@ -141,13 +141,13 @@ def test_ivfpq_data_parallel_matches_single_device(rng):
     n, d = 6000, 32
     base = rng.standard_normal((n, d)).astype(np.float32)
 
-    def make_engine(dp):
+    def make_engine(mesh_serving):
         schema = TableSchema("m", [
             FieldSchema("v", DataType.VECTOR, dimension=d,
                         index=IndexParams("IVFPQ", MetricType.L2, {
                             "ncentroids": 32, "nsubvector": 8,
                             "train_iters": 4, "training_threshold": 2 * n,
-                            "data_parallel": dp,
+                            "mesh_serving": mesh_serving,
                         })),
         ])
         eng = Engine(schema)
@@ -158,8 +158,8 @@ def test_ivfpq_data_parallel_matches_single_device(rng):
         eng.build_index()
         return eng
 
-    e1 = make_engine(False)
-    e8 = make_engine(True)
+    e1 = make_engine("off")
+    e8 = make_engine("on")
     q = base[rng.choice(n, 16, replace=False)]
     req = lambda: SearchRequest(vectors={"v": q}, k=5, include_fields=[],
                                 index_params={"rerank": 64})

@@ -33,7 +33,7 @@ D = 32
 N = 3000
 
 
-def _build(index_type, params, n=N, warmup=None):
+def _build(index_type, params, n=N, warmup=None, data_dir=None):
     params = dict(params)
     if warmup:
         params["warmup_batches"] = warmup
@@ -42,7 +42,7 @@ def _build(index_type, params, n=N, warmup=None):
         FieldSchema("emb", DataType.VECTOR, dimension=D,
                     index=IndexParams(index_type, MetricType.L2, params)),
     ])
-    eng = Engine(schema)
+    eng = Engine(schema, data_dir=data_dir)
     rng = np.random.default_rng(33)
     vecs = rng.standard_normal((n, D), dtype=np.float32)
     eng.upsert([
@@ -85,21 +85,125 @@ def _search(eng, vecs, b=8, index_params=None):
 # -- gate 1: dispatch count per search path ----------------------------------
 
 
-def test_ivfpq_paths_launch_documented_dispatches(ivfpq_engine):
+def test_ivfpq_paths_launch_documented_dispatches(ivfpq_engine, tmp_path):
     eng, vecs = ivfpq_engine
     doc = perf_model.DOCUMENTED_DISPATCHES
+    # the two-step full scan is what a disk store takes: the rerank
+    # gathers its rows on the host
+    disk_eng, _ = _build(
+        "IVFPQ", {**IVFPQ_PARAMS, "store_type": "RocksDB"}, n=1000,
+        data_dir=str(tmp_path))
     cases = {
-        "ivfpq_full_fused": {"scan_mode": "full"},
-        "ivfpq_full_unfused": {"scan_mode": "full", "fused_rerank": False},
-        "ivfpq_probe": {"scan_mode": "probe"},
+        "ivfpq_full_fused": (eng, {"scan_mode": "full"}),
+        "ivfpq_full_unfused": (disk_eng, {"scan_mode": "full"}),
+        "ivfpq_probe": (eng, {"scan_mode": "probe"}),
     }
-    for path, params in cases.items():
-        ledger = _search(eng, vecs, index_params=params)
+    for path, (engine, params) in cases.items():
+        ledger = _search(engine, vecs, index_params=params)
         assert ledger.tags == doc[path], (
             f"{path}: launched {ledger.tags}, documented {doc[path]} — "
             "a new dispatch on a serving path must bump "
             "DOCUMENTED_DISPATCHES in the same PR"
         )
+    disk_eng.close()
+
+
+@pytest.fixture(scope="module")
+def path_indexes(tmp_path_factory):
+    """One corpus under the three things `_serving_path` can observe of
+    an index: a RAM store, a disk store, and SCANN with
+    `reordering: false` (no exact rerank wanted)."""
+    from vearch_tpu.engine.disk_vector import DiskRawVectorStore
+    from vearch_tpu.engine.raw_vector import RawVectorStore
+    from vearch_tpu.index.registry import create_index
+
+    vecs = np.random.default_rng(5).standard_normal(
+        (N, D)).astype(np.float32)
+    shape = {"ncentroids": 16, "nsubvector": 8, "train_iters": 4}
+    stores = {
+        "ram": ("IVFPQ", shape, RawVectorStore(D)),
+        "disk": ("IVFPQ", shape, DiskRawVectorStore(
+            D, str(tmp_path_factory.mktemp("paths") / "store"))),
+        "scann": ("SCANN", {**shape, "reordering": False},
+                  RawVectorStore(D)),
+    }
+    built = {}
+    for name, (kind, params, store) in stores.items():
+        store.add(vecs)
+        idx = create_index(IndexParams(kind, MetricType.L2, params), store)
+        idx.train(vecs)
+        idx.absorb(N)
+        built[name] = idx
+    return built, vecs
+
+
+# conftest.py shows the process 8 devices: "auto" meshes, and the
+# full-scan limit of a mesh counts 8 times (its data axis)
+@pytest.mark.parametrize("index,params,limit,devices,path", [
+    ("ram", {"mesh_serving": "off"}, 16_000_000, 8, "ivfpq_full_fused"),
+    ("ram", {"mesh_serving": "off"}, N - 1, 8, "ivfpq_probe"),
+    ("ram", {"mesh_serving": "off", "scan_mode": "full"}, N - 1, 8,
+     "ivfpq_full_fused"),
+    ("ram", {"mesh_serving": "off", "scan_mode": "probe"}, 16_000_000, 8,
+     "ivfpq_probe"),
+    ("ram", {}, 16_000_000, 1, "ivfpq_full_fused"),
+    ("ram", {}, 16_000_000, 8, "ivfpq_mesh_fused"),
+    ("ram", {"mesh_serving": "on"}, N // 8, 8, "ivfpq_mesh_fused"),
+    ("ram", {"mesh_serving": "on"}, N // 8 - 1, 8, "ivfpq_mesh_probe"),
+    ("ram", {"mesh_serving": "on", "scan_mode": "probe"}, 16_000_000, 8,
+     "ivfpq_mesh_probe"),
+    ("disk", {"mesh_serving": "off"}, 16_000_000, 8, "ivfpq_full_unfused"),
+    ("disk", {}, 16_000_000, 8, "ivfpq_full_unfused"),
+    ("disk", {"mesh_serving": "on"}, N - 1, 8, "ivfpq_probe"),
+    ("scann", {"mesh_serving": "off"}, 16_000_000, 8, "ivfpq_full_unfused"),
+    ("scann", {}, 16_000_000, 8, "ivfpq_mesh_scan"),
+    ("scann", {"scan_mode": "probe"}, 16_000_000, 8, "ivfpq_probe"),
+    ("scann", {"mesh_serving": "off", "rerank": 64}, 16_000_000, 8,
+     "ivfpq_full_fused"),
+])
+def test_serving_path_is_chosen_from_what_the_index_observes(
+        path_indexes, monkeypatch, index, params, limit, devices, path):
+    """`IVFPQIndex._serving_path` names the documented path from the
+    store, the devices visible against `mesh_serving`, the rows against
+    the per-chip limit (or `scan_mode`) and whether a rerank is wanted,
+    and the search launches exactly that path's programs. Where no
+    rerank is wanted the two-step paths stop before theirs."""
+    import jax
+
+    built, vecs = path_indexes
+    idx = built[index]
+    monkeypatch.setattr(idx, "full_scan_limit", limit)
+    visible = jax.devices()[:devices]
+    monkeypatch.setattr(jax, "devices", lambda *a: visible)
+    assert idx._serving_path(params) == path
+    ledger: list = []
+    ivf_ops.set_dispatch_ledger(ledger)
+    try:
+        _, ids = idx.search(vecs[:8], 10, None, params)
+    finally:
+        ivf_ops.set_dispatch_ledger(None)
+    want = perf_model.DOCUMENTED_DISPATCHES[path]
+    if not idx._exact_rerank_enabled(params):
+        want = [tag for tag in want if tag != "rerank"]
+    assert ledger == want
+    assert list(ids[:, 0]) == list(range(8))
+
+
+def test_graft_entry_returns_the_served_program():
+    """The driver's compile check (`__graft_entry__.entry()`) lowers
+    what the one-chip cells serve, under the module name their device
+    trace shows, with the two-stage selection in it: three `top_k`s
+    (block maxima, gathered blocks, rerank)."""
+    import jax
+
+    import __graft_entry__
+
+    fn, args = __graft_entry__.entry()
+    text = jax.jit(fn).lower(*args).as_text()
+    assert text.startswith("module @jit_int8_scan_rerank ")
+    assert "@int8_scan_candidates" in text and "@exact_rerank" in text
+    assert text.count("chlo.top_k") == 3
+    assert len(args) == 7 and args[5].shape == (args[1].shape[0], 128)
 
 
 def test_ivfflat_and_flat_dispatch_counts():
@@ -820,26 +924,6 @@ def test_refine_depth_auto_defaults():
     r0, r1 = perf_model.refine_depths(10, 64)    # r1 clamps too
     assert r1 == 64 and r0 == 64
     assert r0 >= r1
-
-
-# -- roofline ----------------------------------------------------------------
-
-
-def test_roofline_math_and_chip_table():
-    # an unknown or absent device kind is an error, never an assumed chip
-    for kind in (None, "", "cpu", "TPU v9000"):
-        with pytest.raises(ValueError, match="no int8 peak on record"):
-            perf_model.peak_int8_ops(kind)
-    # the kind the described v5e reports (tests/test_chip_compile.py)
-    label, peak = perf_model.peak_int8_ops("TPU v5 lite")
-    assert label == "TPU v5 lite"
-    assert peak == perf_model.INT8_PEAK_OPS["TPU v5e"]
-    assert perf_model.peak_int8_ops("TPU v5 lite chip")[0] == "TPU v5 lite"
-    q = perf_model.roofline_qps(1_000_000, 128, 394.7e12, rerank_r=128)
-    # peak / (2*1e6*128 + 2*128*128) ~= 1.54M QPS
-    assert 1.5e6 < q < 1.6e6
-    # roofline scales down with N
-    assert perf_model.roofline_qps(2_000_000, 128, 394.7e12) < q
 
 
 # -- gate 5: bytes over PCIe (tiered storage, PERF.md Tier 6) ----------------

@@ -85,20 +85,24 @@ def test_profile_multi_partition_router_merge(cluster, rng):
     assert "profile" not in plain
 
 
-def test_profile_dispatches_match_documented_per_ivfpq_path():
+def test_profile_dispatches_match_documented_per_ivfpq_path(tmp_path):
     """Engine-level: every IVFPQ serving path's profiled trace reports
     exactly its documented dispatch sequence, with the perf model's
     reverse lookup naming the path and a byte prediction beside it."""
     eng, vecs = _build("IVFPQ", IVFPQ_PARAMS, warmup=[8])
+    # a disk store takes the two-step full scan
+    disk_eng, _ = _build(
+        "IVFPQ", {**IVFPQ_PARAMS, "store_type": "RocksDB"}, n=1000,
+        data_dir=str(tmp_path))
     doc = perf_model.DOCUMENTED_DISPATCHES
     cases = {
-        "ivfpq_full_fused": {"scan_mode": "full"},
-        "ivfpq_full_unfused": {"scan_mode": "full", "fused_rerank": False},
-        "ivfpq_probe": {"scan_mode": "probe"},
+        "ivfpq_full_fused": (eng, {"scan_mode": "full"}),
+        "ivfpq_full_unfused": (disk_eng, {"scan_mode": "full"}),
+        "ivfpq_probe": (eng, {"scan_mode": "probe"}),
     }
-    for path, params in cases.items():
+    for path, (engine, params) in cases.items():
         trace: dict = {}
-        eng.search(SearchRequest(
+        engine.search(SearchRequest(
             vectors={"emb": vecs[:8]}, k=10, include_fields=[],
             index_params=params, trace=trace))
         assert trace["dispatches"] == doc[path], path

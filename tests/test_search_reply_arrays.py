@@ -345,9 +345,6 @@ def test_sdk_returns_what_the_documents_form_returns(
     assert out == want
     assert all(type(h["_score"]) is float and type(h["_id"]) is str
                for r in out for h in r)
-    # the `columnar` argument is accepted, and changes nothing
-    assert client.search("db", space, q, limit=7, fields=[], columnar=True,
-                         cache=False) == want
 
 
 def test_sdk_pages_and_short_rows_keep_their_counts(cluster, client, vecs):

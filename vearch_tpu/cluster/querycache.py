@@ -15,7 +15,7 @@ These back the multi-tier serving cache (see docs/PERF.md "Tier 4"):
   into one computation at both tiers (one dispatch set, N responses).
 
 Everything here is pure data-structure code — no HTTP, no engine
-imports — so bench.py and unit tests can exercise it standalone.
+imports — so unit tests can exercise it standalone.
 """
 
 from __future__ import annotations

@@ -158,12 +158,9 @@ class IVFRaBitQIndex(IVFPQIndex):
             else self.metric
         )
         r0, r1 = self._stage_depths(k, params)
-        topk_mode = (params or {}).get(
-            "topk_mode", self.params.get("topk_mode", "auto")
-        )
         if self._mesh_enabled(params) and not is_disk_store(self.store):
             return self._search_binary_mesh(
-                q, k, valid_mask, params, metric, r0, r1, topk_mode
+                q, k, valid_mask, params, metric, r0, r1
             )
         t_flush0 = time.monotonic()
         planes, p_scale, p_vsq = self._bits.flush()
@@ -182,7 +179,7 @@ class IVFRaBitQIndex(IVFPQIndex):
             ivf_ops.note_dispatch("binary_refine_scan")
             _, cand_i = binary_ops.binary_refine_candidates(
                 qd, planes, p_scale, p_vsq, approx8, m_scale, m_vsq,
-                valid, r0, r1, metric, topk_mode, self.mirror_storage,
+                valid, r0, r1, metric, self.mirror_storage,
             )
             cand_i.block_until_ready()
             ivf_ops.note_stage_phase("scan", t0, time.monotonic())
@@ -206,7 +203,7 @@ class IVFRaBitQIndex(IVFPQIndex):
             qd, planes, p_scale, p_vsq, approx8, m_scale, m_vsq, valid,
             base, base_sqnorm, r0, r1, k,
             scan_metric=metric, rerank_metric=self.metric,
-            topk_mode=topk_mode, storage=self.mirror_storage,
+            storage=self.mirror_storage,
         )
         scores, ids = jax.device_get((scores, ids))
         ivf_ops.note_stage_phase("refine", t0, time.monotonic())
@@ -216,7 +213,7 @@ class IVFRaBitQIndex(IVFPQIndex):
 
     def _search_binary_mesh(
         self, q: np.ndarray, k: int, valid_mask, params, metric,
-        r0: int, r1: int, topk_mode: str,
+        r0: int, r1: int,
     ) -> tuple[np.ndarray, np.ndarray]:
         """Mesh-spanning three-stage chain: bit planes, int8 mirror,
         and raw base row-sharded in lockstep (identical ShardedRowCache
@@ -241,7 +238,7 @@ class IVFRaBitQIndex(IVFPQIndex):
             mesh, planes, p_scale, p_vsq, a8, m_scale, m_vsq, valid_sh,
             base, base_sqn, qd, r0, r1, min(k, r1),
             scan_metric=metric, rerank_metric=self.metric,
-            topk_mode=topk_mode, storage=self.mirror_storage,
+            storage=self.mirror_storage,
         )
         scores, ids = jax.device_get((scores, ids))
         ivf_ops.note_stage_phase("refine", t0, time.monotonic())

@@ -454,11 +454,10 @@ class IVFPQIndex(_IVFBase):
         # one partition spanning the whole device mesh (capacity regime:
         # rows beyond a single chip's HBM — SURVEY §2.3 "intra-node
         # parallelism", the axis the reference lacks). Config
-        # `mesh_serving: auto|on|off` ("data_parallel" stays as a
-        # boolean back-compat alias); "auto" — the default — engages
+        # `mesh_serving: auto|on|off`; "auto" — the default — engages
         # whenever more than one device is visible.
         self.mesh_serving = self._norm_mesh_serving(
-            params.get("mesh_serving", params.get("data_parallel", "auto"))
+            params.get("mesh_serving", "auto")
         )
         # row -> cluster assignment, docid-ordered (the mesh probe gate
         # reads it row-sharded in lockstep with the int8 mirror)
@@ -496,20 +495,46 @@ class IVFPQIndex(_IVFBase):
         and per-request overrides both take effect without a rebuild."""
         ms = self._norm_mesh_serving(
             (params or {}).get(
-                "mesh_serving",
-                self.params.get(
-                    "mesh_serving", self.params.get("data_parallel", "auto")
-                ),
+                "mesh_serving", self.params.get("mesh_serving", "auto")
             )
         )
         if ms == "auto":
             return len(jax.devices()) > 1
         return ms == "on"
 
-    # back-compat surface (pre-mesh_serving callers/tests)
-    @property
-    def data_parallel(self) -> bool:
-        return self._mesh_enabled(None)
+    def _serving_path(self, params: dict | None) -> str:
+        """The documented path (ops/perf_model.py DOCUMENTED_DISPATCHES
+        key) that serves this search; the one reader of the selectors,
+        request level over index level. A disk store cannot hand a
+        device program its raw rows, sharded or whole, so it neither
+        meshes nor fuses; SCANN reordering=false wants no rerank. The
+        full-scan budget is per chip: a mesh scans its rows in
+        parallel, so its cliff scales with the DATA axis (a
+        query_axis>1 mesh still holds n/data_axis rows a chip)."""
+        from vearch_tpu.index._store_paths import is_disk_store
+
+        disk = is_disk_store(self.store)
+        rerank = self._exact_rerank_enabled(params)
+        mesh = self._mesh_enabled(params) and not disk
+        mode = (params or {}).get("scan_mode", self.scan_mode)
+        if mode == "auto":
+            limit = self.full_scan_limit
+            if mesh:
+                limit *= max(
+                    int(self._serving_mesh(params).shape["data"]), 1
+                )
+            mode = "full" if self.indexed_count <= limit else "probe"
+        if mesh and mode == "full":
+            return "ivfpq_mesh_fused" if rerank else "ivfpq_mesh_scan"
+        if mesh and rerank:
+            # past the cliff a mesh partition keeps its row-sharded
+            # layout and gates the one program to the probed cells
+            return "ivfpq_mesh_probe"
+        if mode != "full":
+            return "ivfpq_probe"
+        if rerank and not disk:
+            return "ivfpq_full_fused"
+        return "ivfpq_full_unfused"
 
     def _device_state_arrays(self) -> tuple:
         return super()._device_state_arrays() + (
@@ -704,88 +729,40 @@ class IVFPQIndex(_IVFBase):
             if self.metric is MetricType.COSINE
             else self.metric
         )
-        mode = (params or {}).get("scan_mode", self.scan_mode)
-        mesh_on = self._mesh_enabled(params)
-        from vearch_tpu.index._store_paths import is_disk_store
-
-        # mesh mode needs the raw buffer sharded across HBM — a disk
-        # store can't provide that; it falls through to the
-        # single-device scan with host-gathered rerank
-        mesh_route = mesh_on and not is_disk_store(self.store)
-        if mode == "auto":
-            # the full-scan budget is per chip: a mesh-spanning
-            # partition scans its rows in parallel, so the cliff to
-            # probe mode scales with the DATA axis of the serving mesh
-            # — a query_axis>1 mesh still holds n/data_axis rows per
-            # chip, so counting all devices would move the cliff to the
-            # wrong row count
-            limit = self.full_scan_limit
-            if mesh_route:
-                limit *= max(
-                    int(self._serving_mesh(params).shape["data"]), 1
-                )
-            mode = "full" if self.indexed_count <= limit else "probe"
-        if mesh_route and mode == "full":
-            return self._search_mesh(q, k, valid_mask, params, metric)
-        if (
-            mesh_route and mode == "probe"
-            and self._exact_rerank_enabled(params)
-            and (params or {}).get(
-                "fused_rerank", self.params.get("fused_rerank", True)
-            )
-        ):
-            # probe regime under the mesh: keep the row-sharded layout
-            # and gate the ONE fused program to the probed coarse cells
-            # — past the full-scan cliff a mesh partition no longer
-            # falls back to a single chip. (reordering=false and the
-            # unfused A/B path keep the single-device bucket layout.)
-            return self._search_mesh(
-                q, k, valid_mask, params, metric,
-                probe_nprobe=max(self._nprobe(params), 1),
-            )
-        if mode == "full":
+        path = self._serving_path(params)
+        if path.startswith("ivfpq_mesh"):
+            return self._search_mesh(q, k, valid_mask, params, metric, path)
+        if path != "ivfpq_probe":
             approx8, scale, vsq = self._mirror.flush()
             n_pad = approx8.shape[0]
             valid = to_device_mask(valid_mask, self.indexed_count, n_pad)
             r = min(self._rerank_depth(k, params), max(self.indexed_count, 1))
-            topk_mode = (params or {}).get(
-                "topk_mode", self.params.get("topk_mode", "auto")
-            )
-            fused = (params or {}).get(
-                "fused_rerank", self.params.get("fused_rerank", True)
-            )
-            if (
-                fused
-                and self._exact_rerank_enabled(params)
-                and not is_disk_store(self.store)
-            ):
+            if path == "ivfpq_full_fused":
                 # default hot path: scan + rerank as ONE device program
                 # (two dispatches paid launch latency twice and
-                # round-tripped nothing for it);
-                # `fused_rerank: false` keeps the two-step path for A/B
+                # round-tripped nothing for it)
                 base, base_sqnorm, _ = self.store.device_buffer()
                 ivf_ops.note_dispatch("fused_scan_rerank")
                 scores, ids = ivf_ops.int8_scan_rerank(
                     jnp.asarray(q), approx8, scale, vsq, valid,
                     base, base_sqnorm, max(r, k), k,
                     scan_metric=metric, rerank_metric=self.metric,
-                    topk_mode=topk_mode, storage=self.mirror_storage,
+                    storage=self.mirror_storage,
                 )
                 ivf_ops.capture_launched()
                 scores, ids = jax.device_get((scores, ids))
                 return self._pad_to_k(scores, ids, k)
-            else:
-                scan = (
-                    ivf_ops.int8_scan_candidates
-                    if self.mirror_storage == "int8"
-                    else ivf_ops.int4_scan_candidates
-                )
-                ivf_ops.note_dispatch("scan")
-                cand_s, cand_i = scan(
-                    jnp.asarray(q), approx8, scale, vsq, valid,
-                    max(r, k), metric, topk_mode,
-                )
-                ivf_ops.capture_launched()
+            scan = (
+                ivf_ops.int8_scan_candidates
+                if self.mirror_storage == "int8"
+                else ivf_ops.int4_scan_candidates
+            )
+            ivf_ops.note_dispatch("scan")
+            cand_s, cand_i = scan(
+                jnp.asarray(q), approx8, scale, vsq, valid,
+                max(r, k), metric,
+            )
+            ivf_ops.capture_launched()
         else:
             if self._dirty or self._bucket_resid8 is None:
                 self._publish()
@@ -914,7 +891,7 @@ class IVFPQIndex(_IVFBase):
 
     def _search_mesh(
         self, q: np.ndarray, k: int, valid_mask, params, metric,
-        probe_nprobe: int = 0,
+        path: str,
     ) -> tuple[np.ndarray, np.ndarray]:
         """Mesh-spanning serving path: the int8 mirror, the raw rerank
         buffer, and the row->cluster assignment are row-sharded over the
@@ -927,14 +904,13 @@ class IVFPQIndex(_IVFBase):
         Placement is incremental: absorb tail-appends only the new rows
         per shard.
 
-        ``probe_nprobe>0`` is the probe REGIME routed here by search():
-        same fused program, gated to the probed cells — distinct
-        dispatch tag so the perf model tells the regimes apart."""
+        ``path`` is `_serving_path`'s: "ivfpq_mesh_probe" is the probe
+        REGIME (same fused program, gated to the probed cells, a
+        dispatch tag of its own); "ivfpq_mesh_scan" wants no rerank."""
         import time as _time
 
         from vearch_tpu.parallel import mesh as mesh_lib
         from vearch_tpu.parallel.sharded import (
-            sharded_exact_rerank,
             sharded_int8_search,
             sharded_ivf_search,
         )
@@ -955,25 +931,20 @@ class IVFPQIndex(_IVFBase):
         n = self.indexed_count
         cap = self._mirror._sh_cache.capacity(mesh, n)
         valid_sh = self._mesh_valid_sharded(mesh, valid_mask, n, cap)
-        nprobe = probe_nprobe or self._mesh_nprobe(params)
+        probe = path == "ivfpq_mesh_probe"
+        nprobe = (max(self._nprobe(params), 1) if probe
+                  else self._mesh_nprobe(params))
         cents = assign_sh = None
         if nprobe > 0:
             cents = mesh_lib.replicate(mesh, np.asarray(self.centroids))
             assign_sh = self._assign_sharded(mesh, n)
         qd, b = mesh_lib.shard_queries(mesh, np.asarray(q, np.float32))
         r = min(self._rerank_depth(k, params), max(n, 1))
-        topk_mode = (params or {}).get(
-            "topk_mode", self.params.get("topk_mode", "auto")
-        )
-        fused = (params or {}).get(
-            "fused_rerank", self.params.get("fused_rerank", True)
-        )
-        rerank = self._exact_rerank_enabled(params)
-        if fused and rerank:
+        if path != "ivfpq_mesh_scan":
             base, base_sqn, _ = self.store.device_buffer_sharded(mesh)
             note_place()
             ivf_ops.note_dispatch(
-                "sharded_probe_scan_rerank" if probe_nprobe > 0
+                "sharded_probe_scan_rerank" if probe
                 else "sharded_fused_scan_rerank"
             )
             scores, ids = sharded_ivf_search(
@@ -981,8 +952,7 @@ class IVFPQIndex(_IVFBase):
                 base, base_sqn, qd, max(r, k),
                 min(k, max(r, k)),
                 scan_metric=metric, rerank_metric=self.metric,
-                topk_mode=topk_mode, storage=self.mirror_storage,
-                nprobe=nprobe,
+                storage=self.mirror_storage, nprobe=nprobe,
             )
             ivf_ops.capture_launched()
             scores, ids = jax.device_get((scores, ids))
@@ -991,21 +961,11 @@ class IVFPQIndex(_IVFBase):
         ivf_ops.note_dispatch("sharded_scan")
         cand_s, cand_i = sharded_int8_search(
             mesh, a8, scale, vsq, valid_sh, qd, max(r, k), metric,
-            topk_mode, storage=self.mirror_storage,
+            storage=self.mirror_storage,
         )
         ivf_ops.capture_launched()
-        if not rerank:
-            scores, ids = jax.device_get((cand_s, cand_i))
-            return self._pad_to_k(scores[:b, :k], ids[:b, :k], k)
-        base, base_sqn, _ = self.store.device_buffer_sharded(mesh)
-        ivf_ops.note_dispatch("sharded_rerank")
-        scores, ids = sharded_exact_rerank(
-            mesh, qd.astype(base.dtype), cand_i, base, base_sqn,
-            min(k, int(cand_i.shape[1])), self.metric,
-        )
-        ivf_ops.capture_launched()
-        scores, ids = jax.device_get((scores, ids))
-        return self._pad_to_k(scores[:b], ids[:b], k)
+        scores, ids = jax.device_get((cand_s, cand_i))
+        return self._pad_to_k(scores[:b, :k], ids[:b, :k], k)
 
     def mesh_info(self) -> dict[str, Any] | None:
         """Mesh data-plane placement summary (surfaced in /ps/stats and

@@ -111,7 +111,7 @@ def _binary_scores(
     return jnp.where(valid[None, :], scores, NEG_INF)
 
 
-@functools.partial(jax.jit, static_argnames=("r", "metric", "topk_mode"))
+@functools.partial(jax.jit, static_argnames=("r", "metric"))
 def binary_scan_candidates(
     queries: jax.Array,    # [B, d] f32
     planes: jax.Array,     # [N_pad, d/8] uint8 packed sign planes
@@ -120,7 +120,6 @@ def binary_scan_candidates(
     valid: jax.Array,      # [N_pad] bool
     r: int,
     metric: MetricType = MetricType.L2,
-    topk_mode: str = "auto",
 ) -> tuple[jax.Array, jax.Array]:
     """Stage-0 binary full scan: one unpack+matmul + fused top-r.
 
@@ -129,7 +128,7 @@ def binary_scan_candidates(
     block-max selection machinery with the int8 scan."""
     scores = _binary_scores(queries, planes, row_scale, row_vsq, valid,
                             metric)
-    return _select_topk(scores, r, topk_mode)
+    return _select_topk(scores, r)
 
 
 def _mirror_rescore(
@@ -164,7 +163,7 @@ def _mirror_rescore(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("r0", "r1", "metric", "topk_mode", "storage")
+    jax.jit, static_argnames=("r0", "r1", "metric", "storage")
 )
 def binary_refine_candidates(
     queries: jax.Array,    # [B, d] f32
@@ -178,7 +177,6 @@ def binary_refine_candidates(
     r0: int,
     r1: int,
     metric: MetricType = MetricType.L2,
-    topk_mode: str = "auto",
     storage: str = "int8",
 ) -> tuple[jax.Array, jax.Array]:
     """Stages 0+1 as ONE program: binary scan -> top r0 -> int8/int4
@@ -187,7 +185,7 @@ def binary_refine_candidates(
     (index/_store_paths.rerank_against_store), the same stage-2 shape
     the int8 disk path already pays."""
     _, cand_i = binary_scan_candidates(
-        queries, planes, row_scale, row_vsq, valid, r0, metric, topk_mode
+        queries, planes, row_scale, row_vsq, valid, r0, metric
     )
     return _mirror_rescore(
         queries, cand_i, approx8, m_scale, m_vsq, r1, metric, storage
@@ -197,7 +195,7 @@ def binary_refine_candidates(
 @functools.partial(
     jax.jit,
     static_argnames=("r0", "r1", "k", "scan_metric", "rerank_metric",
-                     "topk_mode", "storage"),
+                     "storage"),
 )
 def binary_refine_rerank(
     queries: jax.Array,      # [B, d] f32
@@ -215,7 +213,6 @@ def binary_refine_rerank(
     k: int,
     scan_metric: MetricType = MetricType.L2,
     rerank_metric: MetricType = MetricType.L2,
-    topk_mode: str = "auto",
     storage: str = "int8",
 ) -> tuple[jax.Array, jax.Array]:
     """The fused three-stage program: binary scan -> int8/int4 rescore
@@ -227,7 +224,7 @@ def binary_refine_rerank(
 
     _, cand_i = binary_refine_candidates(
         queries, planes, row_scale, row_vsq, approx8, m_scale, m_vsq,
-        valid, r0, r1, scan_metric, topk_mode, storage,
+        valid, r0, r1, scan_metric, storage,
     )
     return exact_rerank(queries.astype(base.dtype), cand_i, base,
                         base_sqnorm, k, rerank_metric)

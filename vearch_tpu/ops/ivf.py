@@ -409,7 +409,7 @@ def ivfpq_candidates(
 BLOCK = 128  # block of the two-stage top-k: the lanes of one score tile
 
 
-@functools.partial(jax.jit, static_argnames=("r", "metric", "topk_mode"))
+@functools.partial(jax.jit, static_argnames=("r", "metric"))
 def int8_scan_candidates(
     queries: jax.Array,    # [B, d] f32
     approx8: jax.Array,    # [N_pad, d] int8 docid-ordered quantized vectors
@@ -418,7 +418,6 @@ def int8_scan_candidates(
     valid: jax.Array,      # [N_pad] bool
     r: int,
     metric: MetricType = MetricType.L2,
-    topk_mode: str = "auto",
 ) -> tuple[jax.Array, jax.Array]:
     """Compressed full scan: one [B, d] x [d, N] int8 matmul + top-r.
 
@@ -445,30 +444,42 @@ def int8_scan_candidates(
         else:
             scores = dots
         scores = jnp.where(valid[None, :], scores, NEG_INF)
-    return _select_topk(scores, r, topk_mode)
+    return _select_topk(scores, r)
 
 
-def _select_topk(
-    scores: jax.Array, r: int, topk_mode: str
-) -> tuple[jax.Array, jax.Array]:
+def _select_topk(scores: jax.Array, r: int) -> tuple[jax.Array, jax.Array]:
     """Top-r of every row of a [B, N] f32 score matrix, shared by every
     full scan (int8, int4, binary, the mesh programs): (scores, ids),
     ids of masked slots -1.
 
-    topk_mode "exact" is one `lax.top_k` over the row: a multi-pass
-    sort of the whole matrix, the right thing only for small N. "auto"
-    takes it below 4 * max(r, 128) blocks (65,536 rows at r <= 128) and
-    for an N that is no multiple of BLOCK; "blockmax" forces the
-    two-stage selection wherever N is such a multiple:
+    One `lax.top_k` over the row is a multi-pass sort of the whole
+    matrix: it serves below 4 * max(r, 128) blocks (65,536 rows at
+    r <= 128) and an N that is no multiple of BLOCK; from there on
+    `_blocked_topk` does (rows of equal score may order otherwise)."""
+    n_pad = scores.shape[1]
+    r = min(r, n_pad)
+    if n_pad % BLOCK == 0 and n_pad // BLOCK >= 4 * max(r, 128):
+        top_s, ids = _blocked_topk(scores, r)
+    else:
+        top_s, ids = jax.lax.top_k(scores, r)
+    # candidates that are really masked slots (filtered/deleted/padding)
+    # carry -inf scores — mark their ids -1 so downstream rerank cannot
+    # resurrect them with genuine similarity scores
+    return top_s, jnp.where(jnp.isfinite(top_s), ids, -1)
+
+
+def _blocked_topk(scores: jax.Array, r: int) -> tuple[jax.Array, jax.Array]:
+    """Two-stage top-r of a [B, N] f32 matrix whose N is a multiple of
+    BLOCK, r <= N:
 
     1. `block_max`: the maximum of every BLOCK-wide block of a row, in
        f32, and the r blocks with the largest maxima.
     2. `select`: those r blocks gathered, and `lax.top_k` over their
        r * BLOCK scores (32,768 at rerank 256).
 
-    The result is the exact top-r of the row (rows of equal score
-    aside): a score among the r largest has a block maximum no smaller
-    than itself, so fewer than r blocks can rank before its block.
+    The result is the exact top-r of the row: a score among the r
+    largest has a block maximum no smaller than itself, so fewer than r
+    blocks can rank before its block.
 
     LAYOUT. On the TPU the score fusion writes [B, N] f32 in tiles of 8
     rows x 128 lanes, physically [B/8, N/128, 8, 128]. Both stages read
@@ -482,40 +493,27 @@ def _select_topk(
     takes the same code with 1-row tiles and pays for its relayout.
     """
     b, n_pad = scores.shape
-    r = min(r, n_pad)
     nblk = n_pad // BLOCK
-    use_block = (
-        n_pad % BLOCK == 0
-        and nblk >= 1
-        and (topk_mode == "blockmax"
-             or (topk_mode == "auto" and nblk >= 4 * max(r, 128)))
-    )
-    if not use_block:
-        top_s, ids = jax.lax.top_k(scores, r)
-    else:
-        nb = min(r, nblk)
-        sub = 8 if b % 8 == 0 else 1
-        tiles = scores.reshape(b // sub, sub, nblk, BLOCK).transpose(
-            0, 2, 1, 3)  # [B/sub, nblk, sub, BLOCK]
-        with jax.named_scope("block_max"):
-            bmax = jnp.max(tiles, axis=3).transpose(0, 2, 1).reshape(
-                b, nblk)
-        with jax.named_scope("select"):
-            _, top_blocks = jax.lax.top_k(bmax, nb)  # [B, nb]
-            row = jnp.arange(b)[:, None]
-            gathered = tiles[row // sub, top_blocks, row % sub, :]
-            top_s, pos = jax.lax.top_k(gathered.reshape(b, nb * BLOCK), r)
-            # block of each winner by compare-and-sum over the nb chosen
-            # blocks: an element gather of B*r block ids costs the chip
-            # ten times this loop (0.17 ms against 0.01 at B=64)
-            chosen = (pos // BLOCK)[:, :, None] == jnp.arange(nb)
-            ids = jnp.sum(jnp.where(chosen, top_blocks[:, None, :], 0),
-                          axis=2) * BLOCK + pos % BLOCK
-            ids = ids.astype(jnp.int32)
-    # candidates that are really masked slots (filtered/deleted/padding)
-    # carry -inf scores — mark their ids -1 so downstream rerank cannot
-    # resurrect them with genuine similarity scores
-    return top_s, jnp.where(jnp.isfinite(top_s), ids, -1)
+    nb = min(r, nblk)
+    sub = 8 if b % 8 == 0 else 1
+    tiles = scores.reshape(b // sub, sub, nblk, BLOCK).transpose(
+        0, 2, 1, 3)  # [B/sub, nblk, sub, BLOCK]
+    with jax.named_scope("block_max"):
+        bmax = jnp.max(tiles, axis=3).transpose(0, 2, 1).reshape(
+            b, nblk)
+    with jax.named_scope("select"):
+        _, top_blocks = jax.lax.top_k(bmax, nb)  # [B, nb]
+        row = jnp.arange(b)[:, None]
+        gathered = tiles[row // sub, top_blocks, row % sub, :]
+        top_s, pos = jax.lax.top_k(gathered.reshape(b, nb * BLOCK), r)
+        # block of each winner by compare-and-sum over the nb chosen
+        # blocks: an element gather of B*r block ids costs the chip
+        # ten times this loop (0.17 ms against 0.01 at B=64)
+        chosen = (pos // BLOCK)[:, :, None] == jnp.arange(nb)
+        ids = jnp.sum(jnp.where(chosen, top_blocks[:, None, :], 0),
+                      axis=2) * BLOCK + pos % BLOCK
+        ids = ids.astype(jnp.int32)
+    return top_s, ids
 
 
 def unpack_int4(packed: jax.Array) -> jax.Array:
@@ -533,7 +531,7 @@ def unpack_int4(packed: jax.Array) -> jax.Array:
     return jnp.concatenate([lo, hi], axis=-1).astype(jnp.bfloat16)
 
 
-@functools.partial(jax.jit, static_argnames=("r", "metric", "topk_mode"))
+@functools.partial(jax.jit, static_argnames=("r", "metric"))
 def int4_scan_candidates(
     queries: jax.Array,    # [B, d] f32
     packed4: jax.Array,    # [N_pad, d/2] uint8 nibble-packed int4 rows
@@ -542,7 +540,6 @@ def int4_scan_candidates(
     valid: jax.Array,      # [N_pad] bool
     r: int,
     metric: MetricType = MetricType.L2,
-    topk_mode: str = "auto",
 ) -> tuple[jax.Array, jax.Array]:
     """int4 compressed full scan: the capacity tier of the int8 mirror.
 
@@ -563,7 +560,7 @@ def int4_scan_candidates(
     else:
         scores = dots
     scores = jnp.where(valid[None, :], scores, NEG_INF)
-    return _select_topk(scores, r, topk_mode)
+    return _select_topk(scores, r)
 
 
 @functools.partial(jax.jit, static_argnames=("r", "metric"))
@@ -696,8 +693,7 @@ def exact_rerank(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("r", "k", "scan_metric", "rerank_metric",
-                     "topk_mode", "storage"),
+    static_argnames=("r", "k", "scan_metric", "rerank_metric", "storage"),
 )
 def int8_scan_rerank(
     queries: jax.Array,      # [B, d] f32
@@ -711,7 +707,6 @@ def int8_scan_rerank(
     k: int,
     scan_metric: MetricType = MetricType.L2,
     rerank_metric: MetricType = MetricType.L2,
-    topk_mode: str = "auto",
     storage: str = "int8",
 ) -> tuple[jax.Array, jax.Array]:
     """Fused compressed scan + exact rerank: ONE device program per
@@ -725,7 +720,7 @@ def int8_scan_rerank(
     scan = (int8_scan_candidates if storage == "int8"
             else int4_scan_candidates)
     _, cand_i = scan(queries, approx8, row_scale, row_vsq, valid,
-                     r, scan_metric, topk_mode)
+                     r, scan_metric)
     return exact_rerank(queries.astype(base.dtype), cand_i, base,
                         base_sqnorm, k, rerank_metric)
 

@@ -97,7 +97,9 @@ class PerfLedger:
 DOCUMENTED_DISPATCHES: dict[str, list[str]] = {
     # IVFPQ full-scan, fused scan+rerank (default hot path): ONE program
     "ivfpq_full_fused": ["fused_scan_rerank"],
-    # IVFPQ full-scan with fused_rerank=false (A/B escape hatch)
+    # IVFPQ full-scan, two-step: a disk store (its rerank gathers the
+    # rows on the host) and SCANN reordering=false, which wants no
+    # rerank and stops after "scan"
     "ivfpq_full_unfused": ["scan", "rerank"],
     # IVFPQ probe mode: bucket scan + exact rerank
     "ivfpq_probe": ["probe_scan", "rerank"],
@@ -112,9 +114,8 @@ DOCUMENTED_DISPATCHES: dict[str, list[str]] = {
     # mesh serving (parallel/sharded.py): probe gate + shard scan +
     # all_gather merge + exact rerank + pmax merge, ONE shard_map program
     "ivfpq_mesh_fused": ["sharded_fused_scan_rerank"],
-    # mesh serving with fused_rerank=false (A/B escape hatch)
-    "ivfpq_mesh_unfused": ["sharded_scan", "sharded_rerank"],
-    # mesh serving with exact rerank disabled: scan+merge only
+    # mesh serving with no exact rerank wanted (SCANN
+    # reordering=false): scan+merge only
     "ivfpq_mesh_scan": ["sharded_scan"],
     # probe regime under the mesh: the fused program gated to the
     # probed coarse cells (nprobe > 0) — past the full-scan cliff a
@@ -517,57 +518,3 @@ def ivf_bucket_footprint_bytes(nlist: int, cap: int, d: int) -> int:
     per-cluster scale + [nlist, cap] vsq + ids (index/ivf.py
     _publish_locked)."""
     return nlist * cap * d + nlist * F32 + 2 * nlist * cap * F32
-
-
-def roofline_qps(
-    n: int, d: int, peak_int8_ops: float, rerank_r: int = 0
-) -> float:
-    """Compute-roofline QPS for the int8 full scan: one [1, d] x [d, N]
-    int8 matmul per query (2 ops per MAC) plus the optional exact-rerank
-    matvec. The denominator bench.py prints so a capture reads "X% of
-    roofline" instead of a bare QPS."""
-    ops_per_query = 2.0 * n * d + 2.0 * rerank_r * d
-    return peak_int8_ops / max(ops_per_query, 1.0)
-
-
-#: per-chip peak int8 MXU throughput (ops/s), keyed by the prefix of
-#: jax's `device_kind`. Source: Google Cloud TPU documentation, system
-#: architecture pages per generation (v5e: 394 int8 TOPS / 197 bf16
-#: TFLOPS per chip). A device kind that is not in the table is an error
-#: (`peak_int8_ops`), never a default.
-INT8_PEAK_OPS: dict[str, float] = {
-    "TPU v4": 275e12,       # bf16 figure; v4 has no int8 doubling
-    "TPU v5 lite": 394.7e12,
-    "TPU v5e": 394.7e12,
-    "TPU v5": 918.8e12,     # v5p
-    "TPU v5p": 918.8e12,
-    "TPU v6 lite": 1836.0e12,  # trillium
-    "TPU v6e": 1836.0e12,
-}
-
-
-def effective_qps(
-    cold_qps: float, hit_rate: float, hit_cost_frac: float = 0.0
-) -> float:
-    """Amdahl-style serving throughput under a result cache: a hit
-    costs ``hit_cost_frac`` of a cold query (0 = free hash lookup),
-    a miss costs a full cold query. bench.py's cache-effectiveness
-    phase reports this next to the measured effective QPS so the
-    model and the measurement can be compared directly."""
-    hit_rate = min(max(hit_rate, 0.0), 1.0)
-    denom = hit_rate * max(hit_cost_frac, 0.0) + (1.0 - hit_rate)
-    return cold_qps / max(denom, 1e-12)
-
-
-def peak_int8_ops(device_kind: str) -> tuple[str, float]:
-    """(label, ops/s) for a device kind; prefix-matches so platform
-    suffixes ("TPU v5 lite chip") still resolve. An unknown or absent
-    kind raises: a roofline against an assumed chip is not a number."""
-    if device_kind:
-        for k in sorted(INT8_PEAK_OPS, key=len, reverse=True):
-            if device_kind.lower().startswith(k.lower()):
-                return k, INT8_PEAK_OPS[k]
-    raise ValueError(
-        f"no int8 peak on record for device kind {device_kind!r}; "
-        f"known: {sorted(INT8_PEAK_OPS)}"
-    )

@@ -89,19 +89,18 @@ def sharded_int8_search(
     queries: jax.Array,    # [B_pad, d] f32 sharded P("query", None)
     r: int,
     metric: MetricType = MetricType.L2,
-    topk_mode: str = "auto",
     storage: str = "int8",
 ) -> tuple[jax.Array, jax.Array]:
     """Sharded compressed scan (the IVFPQ full-scan path across chips).
     `storage` follows the mirror tier: int8 rows or nibble-packed int4."""
-    return _int8_search_fn(mesh, r, metric, topk_mode, storage)(
+    return _int8_search_fn(mesh, r, metric, storage)(
         approx8, row_scale, row_vsq, valid, queries
     )
 
 
 @functools.lru_cache(maxsize=128)
 def _int8_search_fn(mesh: Mesh, r: int, metric: MetricType,
-                    topk_mode: str, storage: str = "int8"):
+                    storage: str = "int8"):
     from vearch_tpu.ops.ivf import int4_scan_candidates, int8_scan_candidates
 
     scan = int8_scan_candidates if storage == "int8" else int4_scan_candidates
@@ -119,7 +118,7 @@ def _int8_search_fn(mesh: Mesh, r: int, metric: MetricType,
     )
     def run(a8, sc, vsq, v, q):
         local_r = min(r, a8.shape[0])
-        scores, ids = scan(q, a8, sc, vsq, v, local_r, metric, topk_mode)
+        scores, ids = scan(q, a8, sc, vsq, v, local_r, metric)
         shard = jax.lax.axis_index("data")
         # masked candidates come back as id=-1; keep them -1 globally
         # (a bare shard offset would turn them into real foreign docids)
@@ -131,75 +130,8 @@ def _int8_search_fn(mesh: Mesh, r: int, metric: MetricType,
         return top_s, jnp.take_along_axis(all_i, pos, axis=1)
 
     return register_jit(
-        f"sharded.int8[{_mesh_tag(mesh)},r{r},{metric.name},"
-        f"{topk_mode},{storage}]", run,
-    )
-
-
-def sharded_exact_rerank(
-    mesh: Mesh,
-    queries: jax.Array,     # [B_pad, d] sharded P("query", None)
-    cand_ids: jax.Array,    # [B_pad, r] i32 global docids, P("query", None)
-    base: jax.Array,        # [N_pad, d] sharded P("data", None)
-    base_sqnorm: jax.Array,  # [N_pad] sharded P("data")
-    k: int,
-    metric: MetricType = MetricType.L2,
-) -> tuple[jax.Array, jax.Array]:
-    """Exact re-scoring against a row-sharded raw buffer: every shard
-    scores the candidates it owns (others -inf), pmax over "data" merges
-    without leaving the device, then one small top-k. The mesh analogue
-    of ops/ivf.py exact_rerank. Every step is per-query-row, so the
-    query batch shards over "query" (positional PartitionSpecs — the
-    program stays mesh-shape agnostic; a 1-wide query axis degenerates
-    to the replicated layout)."""
-    return _exact_rerank_fn(mesh, k, metric)(
-        queries, cand_ids, base, base_sqnorm
-    )
-
-
-@functools.lru_cache(maxsize=128)
-def _exact_rerank_fn(mesh: Mesh, k: int, metric: MetricType):
-    @jax.jit
-    @functools.partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(
-            P("query", None), P("query", None), P("data", None), P("data"),
-        ),
-        out_specs=(P("query", None), P("query", None)),
-        check_vma=False,
-    )
-    def run(q, cids, b, sqn):
-        shard = jax.lax.axis_index("data")
-        local_n = b.shape[0]
-        local = cids - shard * local_n
-        mine = (cids >= 0) & (local >= 0) & (local < local_n)
-        safe = jnp.clip(local, 0, local_n - 1)
-        vecs = b[safe]  # [B, r, d]
-        vsq = sqn[safe]
-        qf = q.astype(b.dtype)
-        dots = jax.lax.dot_general(
-            qf, vecs, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-            precision=dot_precision(qf, vecs),
-        )
-        if metric is MetricType.L2:
-            scores = -(sqnorms(qf)[:, None] - 2.0 * dots + vsq)
-        elif metric is MetricType.COSINE:
-            qn = jnp.sqrt(jnp.maximum(sqnorms(qf), 1e-30))[:, None]
-            vn = jnp.sqrt(jnp.maximum(vsq, 1e-30))
-            scores = dots / (qn * vn)
-        else:
-            scores = dots
-        scores = jnp.where(mine, scores, NEG_INF)
-        scores = jax.lax.pmax(scores, "data")  # replicated merge
-        kk = min(k, scores.shape[1])
-        top_s, pos = jax.lax.top_k(scores, kk)
-        ids = jnp.take_along_axis(cids, pos, axis=1)
-        return top_s, jnp.where(jnp.isfinite(top_s), ids, -1)
-
-    return register_jit(
-        f"sharded.rerank[{_mesh_tag(mesh)},k{k},{metric.name}]", run
+        f"sharded.int8[{_mesh_tag(mesh)},r{r},{metric.name},{storage}]",
+        run,
     )
 
 
@@ -218,7 +150,6 @@ def sharded_ivf_search(
     k: int,
     scan_metric: MetricType = MetricType.L2,
     rerank_metric: MetricType = MetricType.L2,
-    topk_mode: str = "auto",
     storage: str = "int8",
     nprobe: int = 0,
 ) -> tuple[jax.Array, jax.Array]:
@@ -233,7 +164,7 @@ def sharded_ivf_search(
     cells using the REPLICATED coarse quantizer, so probe selection is
     computed redundantly per shard instead of paying a collective."""
     fn = _ivf_search_fn(
-        mesh, r, k, scan_metric, rerank_metric, topk_mode, storage, nprobe
+        mesh, r, k, scan_metric, rerank_metric, storage, nprobe
     )
     if nprobe > 0:
         return fn(centroids, assign, approx8, row_scale, row_vsq, valid,
@@ -244,7 +175,7 @@ def sharded_ivf_search(
 @functools.lru_cache(maxsize=128)
 def _ivf_search_fn(
     mesh: Mesh, r: int, k: int, scan_metric: MetricType,
-    rerank_metric: MetricType, topk_mode: str, storage: str, nprobe: int,
+    rerank_metric: MetricType, storage: str, nprobe: int,
 ):
     from vearch_tpu.ops.ivf import _coarse_probes, _select_topk, unpack_int4
 
@@ -294,7 +225,7 @@ def _ivf_search_fn(
             else:
                 scores = dots
             scores = jnp.where(ok, scores, NEG_INF)
-        top_s, top_i = _select_topk(scores, min(r, local_n), topk_mode)
+        top_s, top_i = _select_topk(scores, min(r, local_n))
         shard = jax.lax.axis_index("data")
         with jax.named_scope("merge"):
             gids = jnp.where(top_i >= 0, top_i + shard * local_n, -1)
@@ -305,9 +236,9 @@ def _ivf_search_fn(
             cand_i = jnp.take_along_axis(all_i, pos, axis=1)
         # exact rerank against the shard's raw slab: candidates this
         # shard does not own score -inf and the pmax merge recovers the
-        # owner's exact score everywhere (same ownership math as
-        # _exact_rerank_fn, with the BASE slab size — the mirror and the
-        # raw buffer are padded to different alignments)
+        # owner's exact score everywhere (ownership by the BASE slab
+        # size — the mirror and the raw buffer are padded to different
+        # alignments)
         with jax.named_scope("rerank"):
             local_nb = b.shape[0]
             local = cand_i - shard * local_nb
@@ -351,8 +282,8 @@ def _ivf_search_fn(
     ))
     return register_jit(
         f"sharded.ivf_fused[{_mesh_tag(mesh)},r{r},k{k},"
-        f"{scan_metric.name},{rerank_metric.name},{topk_mode},{storage},"
-        f"p{nprobe}]", run,
+        f"{scan_metric.name},{rerank_metric.name},{storage},p{nprobe}]",
+        run,
     )
 
 
@@ -373,7 +304,6 @@ def sharded_binary_refine(
     k: int,
     scan_metric: MetricType = MetricType.L2,
     rerank_metric: MetricType = MetricType.L2,
-    topk_mode: str = "auto",
     storage: str = "int8",
 ) -> tuple[jax.Array, jax.Array]:
     """The pod-slice three-stage refinement program: bit planes, int8
@@ -385,7 +315,7 @@ def sharded_binary_refine(
     and the exact rerank + pmax merge finishes exactly like
     sharded_ivf_search. ONE jitted shard_map program end to end."""
     return _binary_refine_fn(
-        mesh, r0, r1, k, scan_metric, rerank_metric, topk_mode, storage
+        mesh, r0, r1, k, scan_metric, rerank_metric, storage
     )(planes, p_scale, p_vsq, approx8, m_scale, m_vsq, valid,
       base, base_sqnorm, queries)
 
@@ -393,7 +323,7 @@ def sharded_binary_refine(
 @functools.lru_cache(maxsize=128)
 def _binary_refine_fn(
     mesh: Mesh, r0: int, r1: int, k: int, scan_metric: MetricType,
-    rerank_metric: MetricType, topk_mode: str, storage: str,
+    rerank_metric: MetricType, storage: str,
 ):
     from vearch_tpu.ops.binary_scan import _binary_scores, _mirror_rescore
     from vearch_tpu.ops.ivf import _select_topk
@@ -414,7 +344,7 @@ def _binary_refine_fn(
         local_n = psc.shape[0]
         # stage 0: local binary scan over this shard's bit planes
         scores = _binary_scores(q, pl, psc, pvsq, v, scan_metric)
-        _, c0 = _select_topk(scores, min(r0, local_n), topk_mode)
+        _, c0 = _select_topk(scores, min(r0, local_n))
         # stage 1: rescore this shard's own survivors against its
         # int8/int4 mirror slab — ids are still shard-local
         top_s, top_i = _mirror_rescore(
@@ -458,7 +388,7 @@ def _binary_refine_fn(
 
     return register_jit(
         f"sharded.binary_refine[{_mesh_tag(mesh)},r0_{r0},r1_{r1},k{k},"
-        f"{scan_metric.name},{rerank_metric.name},{topk_mode},{storage}]",
+        f"{scan_metric.name},{rerank_metric.name},{storage}]",
         run,
     )
 

@@ -132,7 +132,6 @@ class VearchClient:
         # or least-loaded replica when replica_read is on); an explicit
         # mode always wins
         load_balance: str | None = None,
-        columnar: bool = False,
         sort: Any = None,
         page_size: int | None = None,
         page_num: int | None = None,
@@ -148,9 +147,8 @@ class VearchClient:
         model's documented prediction, and router merge cost (schema in
         docs/OBSERVABILITY.md).
 
-        ``columnar`` is accepted and no longer read: a search with
-        ``fields=[]`` and no ``sort`` always asks the router for the
-        array form of the reply and builds the hit lists here.
+        A search with ``fields=[]`` and no ``sort`` asks the router for
+        the array form of the reply and builds the hit lists here.
 
         ``cache=False`` bypasses the router and partition result
         caches for this request — correctness-sensitive callers and
