@@ -44,20 +44,20 @@ MESH_PARAMS = {
 
 
 def _ivfpq_pair(rng, metric=MetricType.L2, storage="int8", n=N,
-                mesh_shape=None):
+                mesh_shape=None, d=D, train_rows=2000, nsubvector=8):
     """Same data, same training → one single-device index, one mesh."""
-    data = rng.standard_normal((n, D)).astype(np.float32)
+    data = rng.standard_normal((n, d)).astype(np.float32)
 
     def build(ms):
         params = IndexParams("IVFPQ", metric, {
-            "ncentroids": 16, "nsubvector": 8, "train_iters": 4,
+            "ncentroids": 16, "nsubvector": nsubvector, "train_iters": 4,
             "mirror_dtype": storage, "mesh_serving": ms,
             "mesh_shape": mesh_shape,
         })
-        store = RawVectorStore(D)
+        store = RawVectorStore(d)
         store.add(data)
         idx = IVFPQIndex(params, store)
-        idx.train(data[:2000])
+        idx.train(data[:train_rows])
         idx.absorb(n)
         return idx
 
@@ -534,6 +534,130 @@ def test_flat_sharded_absorb_appends(rng):
     hi = -(-2400 // 128) * 128
     expect = (hi - lo) * (D * 4 + 4)  # f32 rows + derived sqnorm column
     assert stats["h2d_bytes"] - bytes0 == expect
+
+
+# -- the raw shard placed as super-rows (ISSUE 30) ---------------------------
+
+# d -> rows a device row of the sharded raw store (mesh.row_pack): whole
+# 128-lane groups for a multiple of 16; 128 is row-major as it is; 100
+# would need 32 and keeps [cap, d]
+ROW_PACK = {96: 4, 64: 2, 128: 1, 100: 1}
+
+
+@pytest.mark.parametrize("d", sorted(ROW_PACK))
+def test_gather_from_the_packed_view_moves_bits(rng, d):
+    """The shared tail's gather on the raw slab as placed returns
+    exactly `base[ids]`: first and last rows, every sub-row."""
+    import jax.numpy as jnp
+
+    from vearch_tpu.parallel.sharded import _gather_rows
+
+    pack = mesh_lib.row_pack(d)
+    assert pack == ROW_PACK[d]
+    n = 64 * pack
+    base = rng.standard_normal((n, d)).astype(np.float32)
+    ids = rng.integers(0, n, size=(4, 40)).astype(np.int32)
+    ids[0, :8] = [0, 1, 2, 3, n - 4, n - 3, n - 2, n - 1]
+    got = _gather_rows(jnp.asarray(base.reshape(n // pack, pack * d)),
+                       jnp.asarray(ids), d)
+    assert got.shape == (4, 40, d)
+    assert np.array_equal(np.asarray(got), base[ids])
+
+
+@pytest.mark.parametrize("d", sorted(ROW_PACK))
+def test_mesh_rerank_on_the_packed_raw_shard(rng, d):
+    """A mesh IVFPQ search reranks against the raw shard as placed
+    (`[cap / pack, pack * d]`) and agrees bit for bit with the
+    single-device fused search, through every way rows reach the
+    shard: first placement, growth past capacity, a tail append across
+    a shard boundary, rows overwritten below the high-water mark."""
+    from vearch_tpu.ops.distance import host_sqnorms
+
+    pack = ROW_PACK[d]
+    n0 = 1001  # not a multiple of any pack
+    single, mesh, _ = _ivfpq_pair(rng, n=n0, d=d, train_rows=n0,
+                                  nsubvector=4)
+    q = rng.standard_normal((4, d)).astype(np.float32)
+
+    def agree(mask=None):
+        ss, si = single.search(q, 10, mask)
+        ms, mi = mesh.search(q, 10, mask)
+        assert np.array_equal(si, mi)
+        assert np.array_equal(ss, ms)
+        # the placed shard is the host rows, the column their sqnorms
+        cache = mesh.store._sh_cache
+        (base,) = cache.arrays
+        cap = cache.sqnorm.shape[0]
+        assert base.shape == (cap // pack, pack * d)
+        host = np.zeros((cap, d), np.float32)
+        host[:mesh.store.count] = mesh.store.host_view()
+        assert np.array_equal(np.asarray(base).reshape(cap, d), host)
+        assert np.array_equal(np.asarray(cache.sqnorm), host_sqnorms(host))
+        return mesh.mesh_info()["raw_placement"]
+
+    def grow(rows):
+        more = rng.standard_normal((rows, d)).astype(np.float32)
+        for idx in (single, mesh):
+            idx.store.add(more)
+            idx.absorb(idx.store.count)
+
+    # (i) first placement: 8 shards x 128 rows hold 1001
+    placed = agree()
+    assert placed["row_pack"] == pack
+    assert (placed["rebuilds"], placed["appends"]) == (1, 0)
+    # (iii) growth past capacity re-places at twice the capacity
+    grow(100)
+    grown = agree()
+    assert (grown["rebuilds"], grown["appends"]) == (2, 0)
+    assert mesh.store._sh_cache.sqnorm.shape[0] == 2048
+    # (ii) a tail append inside capacity, over the boundary between
+    # shards 4 and 5 (row 1280): the window's rows and nothing else
+    grow(300)
+    appended = agree()
+    assert (appended["rebuilds"], appended["appends"]) == (2, 1)
+    assert appended["h2d_bytes"] - grown["h2d_bytes"] == \
+        (1408 - 1024) * (4 * d + 4)
+    # (iv) rows rewritten below the high-water mark and re-absorbed,
+    # then the best hits deleted: masked on every shard
+    lo, n = 130, mesh.store.count
+    fresh = rng.standard_normal((3, d)).astype(np.float32)
+    for idx in (single, mesh):
+        idx.store._host[lo:lo + 3] = fresh
+        idx.indexed_count = lo
+        idx.absorb(n)
+    single.store._device_rows = lo
+    mesh.store._sh_cache.lower_rows(lo)
+    redone = agree()
+    assert (redone["rebuilds"], redone["appends"]) == (2, 2)
+    _, top = single.search(q, 10, None)
+    mask = np.ones(n, dtype=bool)
+    mask[np.unique(top[:, :4])] = False
+    agree(mask)
+    assert not set(np.unique(top[:, :4])) & set(
+        mesh.search(q, 10, mask)[1].ravel().tolist())
+
+
+def test_mesh_three_stage_reranks_on_the_packed_raw_shard(rng):
+    """`index/binary.py`'s mesh chain ends in the same tail: at 96
+    dimensions it reranks against four rows a device row, and the exact
+    scores it returns are the rows' own."""
+    from vearch_tpu.index.binary import IVFRaBitQIndex
+
+    d, n = 96, 2001
+    data = rng.standard_normal((n, d)).astype(np.float32)
+    store = RawVectorStore(d)
+    store.add(data)
+    idx = IVFRaBitQIndex(IndexParams("IVFRABITQ", MetricType.L2, {
+        "ncentroids": 16, "train_iters": 4, "mesh_serving": "on"}), store)
+    idx.train(data)
+    idx.absorb(n)
+    q = data[:8] + 0.01 * rng.standard_normal((8, d)).astype(np.float32)
+    scores, ids = idx.search(q, 10, None, None)
+    assert idx.mesh_info()["raw_placement"]["row_pack"] == 4
+    assert store._sh_cache.arrays[0].shape[1] == 4 * d
+    assert (ids[:, 0] == np.arange(8)).all(), ids[:, 0]
+    exact = -((q[:, None, :] - data[ids]) ** 2).sum(-1)
+    assert np.allclose(scores, exact, rtol=1e-4, atol=1e-4)
 
 
 def test_mesh_construction_cached_per_device_count():

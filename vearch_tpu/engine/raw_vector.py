@@ -143,24 +143,37 @@ class RawVectorStore:
         of a mesh-spanning partition). Growth within the cached capacity
         tail-appends only the new rows per shard; the derived sqnorm
         column is maintained on device by the cache (sqnorm_of=0) so it
-        stays bit-identical to a full rebuild."""
-        from vearch_tpu.parallel.mesh import ShardedRowCache
+        stays bit-identical to a full rebuild.
+
+        The buffer is placed as `[cap / pack, pack * d]`, `pack` =
+        `parallel.mesh.row_pack(d)` consecutive rows a device row (a
+        reshape of the contiguous host rows); at `pack` 1, `[cap, d]`.
+        Only `parallel/sharded.py` `_gather_rows` reads it. sqnorm stays
+        `[cap]`, n_rows logical."""
+        from vearch_tpu.parallel.mesh import ShardedRowCache, row_pack
+
+        pack = row_pack(self.dimension)
+
+        def packed(rows: np.ndarray) -> np.ndarray:
+            return rows.astype(self.store_dtype).reshape(
+                rows.shape[0] // pack, pack * self.dimension)
 
         def build(cap):
             host = np.zeros((cap, self.dimension), dtype=np.float32)
             host[: self._n] = self._host[: self._n]
-            return (host.astype(self.store_dtype),)
+            return (packed(host),)
 
         def append(lo, hi):
             win = np.zeros((hi - lo, self.dimension), dtype=np.float32)
             m = min(hi, self._host.shape[0]) - lo
             if m > 0:
                 win[:m] = self._host[lo : lo + m]
-            return (win.astype(self.store_dtype),)
+            return (packed(win),)
 
         with self._flush_lock:
             if self._sh_cache is None:
-                self._sh_cache = ShardedRowCache(align=128, sqnorm_of=0)
+                self._sh_cache = ShardedRowCache(
+                    align=128, sqnorm_of=0, pack=pack)
             (base,), _ = self._sh_cache.get(mesh, self._n, build, append)
             return base, self._sh_cache.sqnorm, self._n
 

@@ -918,13 +918,13 @@ class IVFPQIndex(_IVFBase):
         t_place0 = _time.monotonic()
         h2d0 = perf_model.h2d_bytes_total()
 
-        def note_place() -> None:
+        def note_place(**tags) -> None:
             # `bytes`: what the process uploaded during the phase: the
             # query batch on every request; a re-placement or a
             # tail-append of mirror, raw store or mask shows as more
             ivf_ops.note_mesh_phase(
                 "place", t_place0, _time.monotonic(),
-                {"bytes": perf_model.h2d_bytes_total() - h2d0})
+                {"bytes": perf_model.h2d_bytes_total() - h2d0, **tags})
 
         mesh = self._serving_mesh(params)
         a8, scale, vsq = self._mirror.flush_sharded(mesh)
@@ -942,7 +942,8 @@ class IVFPQIndex(_IVFBase):
         r = min(self._rerank_depth(k, params), max(n, 1))
         if path != "ivfpq_mesh_scan":
             base, base_sqn, _ = self.store.device_buffer_sharded(mesh)
-            note_place()
+            # rows a device row of the raw shard as placed (row_pack)
+            note_place(raw_pack=base.shape[1] // qd.shape[1])
             ivf_ops.note_dispatch(
                 "sharded_probe_scan_rerank" if probe
                 else "sharded_fused_scan_rerank"
@@ -984,7 +985,7 @@ class IVFPQIndex(_IVFBase):
             info["mirror_placement"] = dict(sh.stats)
         rs = getattr(self.store, "_sh_cache", None)
         if rs is not None:
-            info["raw_placement"] = dict(rs.stats)
+            info["raw_placement"] = {**rs.stats, "row_pack": rs.pack}
         return info
 
     def device_footprint_per_device_bytes(self) -> int:

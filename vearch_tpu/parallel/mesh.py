@@ -17,6 +17,7 @@ sharded ("query" axis). Collectives ride ICI:
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import numpy as np
@@ -119,6 +120,27 @@ def shard_queries(mesh: Mesh, q):
     return jax.device_put(q, sharding), b
 
 
+def row_pack(d: int) -> int:
+    """Rows of a row-sharded `[N, d]` raw store that are placed side by
+    side as one super-row, `[N / pack, pack * d]`: the least count that
+    makes the minor dimension whole 128-lane groups, for `d` a multiple
+    of 16 (96 -> 4, 64 -> 2, 32 -> 4); 1 for a multiple of 128 and for
+    every width that would need more than 8 (100 -> 32).
+
+    Why: the chip lays a minor dimension under 128 lanes out
+    column-major (`f32[N, 96]{0,1:T(8,128)}`), and a program that gathers
+    ROWS from it (the exact rerank) first copies the whole shard
+    row-major, in every dispatch. `[N / 4, 384]` is row-major as placed;
+    the rerank gathers super-row `id // pack` and keeps sub-row
+    `id % pack` (parallel/sharded.py `_gather_rows`). Arrays that feed a
+    matrix product (mirror, bit planes) are NOT packed: for a product
+    column-major is what the chip wants."""
+    if d % 128 == 0:
+        return 1
+    pack = 128 // math.gcd(d, 128)
+    return pack if pack <= 8 else 1
+
+
 def replicate(mesh: Mesh, x):
     spec = P(*([None] * np.ndim(x)))
     perf_model.note_h2d_bytes(int(getattr(x, "nbytes", 0)))
@@ -126,13 +148,16 @@ def replicate(mesh: Mesh, x):
 
 
 @functools.lru_cache(maxsize=16)
-def _tail_update_fn(ndim: int, with_sqnorm: bool):
+def _tail_update_fn(ndim: int, with_sqnorm: bool, pack: int = 1):
     """Per-device tail writer: dynamic_update_slice of the new rows into
     one shard's slab (NOT donated — a concurrent search may still hold
     the previous buffer; the device-side copy is the price of lock-free
     reads). Traced `off` so append offsets never retrace. The derived
     sqnorm tail arrives pre-computed host-side (ops/distance
-    host_sqnorms) so every placement path lands the identical column."""
+    host_sqnorms) so every placement path lands the identical column.
+    `off` counts the slab's own rows: super-rows of `pack` where the
+    slab is packed, and the sqnorm column, one entry a logical row,
+    starts at `off * pack`."""
     from vearch_tpu.ops.perf_model import register_jit
 
     def upd(dst, tail, off, sq=None, sq_tail=None):
@@ -140,12 +165,13 @@ def _tail_update_fn(ndim: int, with_sqnorm: bool):
         out = jax.lax.dynamic_update_slice(dst, tail, idx)
         if sq is None:
             return out
-        return out, jax.lax.dynamic_update_slice(sq, sq_tail, (off,))
+        sq_off = off if pack == 1 else off * pack
+        return out, jax.lax.dynamic_update_slice(sq, sq_tail, (sq_off,))
 
     fn = jax.jit(upd)
-    return register_jit(
-        f"mesh.tail_append[{ndim}d{',sqnorm' if with_sqnorm else ''}]", fn
-    )
+    tag = f"{ndim}d{',sqnorm' if with_sqnorm else ''}" \
+        f"{f',pack{pack}' if pack > 1 else ''}"
+    return register_jit(f"mesh.tail_append[{tag}]", fn)
 
 
 class ShardedRowCache:
@@ -173,11 +199,21 @@ class ShardedRowCache:
     change (engine apply_config -> index params -> mesh_from_shape)
     re-places every buffer onto the new mesh on the next get() with no
     explicit invalidation — the old mesh's placement is simply dropped.
+
+    `pack` (:func:`row_pack`; the raw store's, 1 everywhere else) places
+    `pack` logical rows as one device row: the host functions hand
+    `[rows / pack, pack * d]` views, and `n`, capacities, windows and
+    shard ownership stay in LOGICAL rows — `align` is a multiple of
+    every pack, so a window or a shard offset is whole super-rows. Only
+    the slices and offsets that touch a device array are divided.
     """
 
-    def __init__(self, align: int, sqnorm_of: int | None = None):
+    def __init__(self, align: int, sqnorm_of: int | None = None,
+                 pack: int = 1):
+        assert align % pack == 0, (align, pack)
         self.align = align
         self.sqnorm_of = sqnorm_of
+        self.pack = pack
         self._key = None
         self._rows = 0
         self.arrays: tuple | None = None
@@ -201,8 +237,8 @@ class ShardedRowCache:
     def get(self, mesh: Mesh, n: int, build_host_fn, append_host_fn=None):
         """build_host_fn(cap) -> tuple of host arrays with cap rows;
         append_host_fn(lo, hi) -> tuple of host arrays with hi-lo rows
-        (rows [lo, hi) of each cached array). Returns (device_arrays,
-        rebuilt)."""
+        (rows [lo, hi) of each cached array); both as
+        `[rows / pack, pack * d]`. Returns (device_arrays, rebuilt)."""
         cap = self.capacity(mesh, n)
         key = (id(mesh), cap)
         rebuilt = False
@@ -216,7 +252,7 @@ class ShardedRowCache:
                 from vearch_tpu.ops.distance import host_sqnorms
 
                 self.sqnorm = shard_rows(
-                    mesh, host_sqnorms(hosts[self.sqnorm_of])
+                    mesh, host_sqnorms(self._logical(hosts[self.sqnorm_of]))
                 )[0]
             self._key = key
             self._rows = n
@@ -227,6 +263,10 @@ class ShardedRowCache:
             perf_model.note_h2d_bytes(moved)
         return self.arrays, rebuilt
 
+    def _logical(self, host: np.ndarray) -> np.ndarray:
+        """The `[rows, d]` view of a 2-D host array handed in packed."""
+        return host.reshape(host.shape[0] * self.pack, -1)
+
     def _append(self, mesh: Mesh, n: int, cap: int, append_host_fn) -> None:
         """Tail-append rows [rows_hw, n) in place: the host window is
         align-rounded so every per-shard slice keeps lane-aligned static
@@ -236,6 +276,7 @@ class ShardedRowCache:
         existing buffers — zero copies, zero traffic."""
         n_shards = mesh.shape["data"]
         local_n = cap // n_shards
+        pack = self.pack
         lo = (self._rows // self.align) * self.align
         hi = min(-(-n // self.align) * self.align, cap)
         tails = [np.asarray(t) for t in append_host_fn(lo, hi)]
@@ -243,26 +284,26 @@ class ShardedRowCache:
         if self.sqnorm_of is not None:
             from vearch_tpu.ops.distance import host_sqnorms
 
-            sq_tail = host_sqnorms(tails[self.sqnorm_of])
+            sq_tail = host_sqnorms(self._logical(tails[self.sqnorm_of]))
         new_arrays = []
         new_sq = self.sqnorm
         for ai, arr in enumerate(self.arrays):
             want_sq = self.sqnorm_of == ai
-            upd = _tail_update_fn(arr.ndim, want_sq)
+            upd = _tail_update_fn(arr.ndim, want_sq, pack)
             parts = {}
             sq_parts = {}
             for sh in arr.addressable_shards:
-                s = (sh.index[0].start or 0) // local_n
+                s = (sh.index[0].start or 0) * pack // local_n
                 a = max(lo, s * local_n)
                 b = min(hi, (s + 1) * local_n)
                 if a >= b:
                     parts[s] = sh.data
                     continue
-                win = tails[ai][a - lo : b - lo]
+                win = tails[ai][(a - lo) // pack : (b - lo) // pack]
                 win_dev = jax.device_put(win, sh.device)
                 self.stats["h2d_bytes"] += win.nbytes
                 perf_model.note_h2d_bytes(win.nbytes)
-                off = np.int32(a - s * local_n)
+                off = np.int32((a - s * local_n) // pack)
                 if want_sq:
                     sq_sh = {
                         (q.index[0].start or 0) // local_n: q

@@ -9,6 +9,13 @@ Layouts (built by parallel/mesh.py):
     base    [N_pad, d]  rows sharded over "data"
     queries [B_pad, d]  sharded over "query", replicated over "data"
     outputs [B_pad, k]  sharded over "query" (global docids)
+    raw rerank store (engine/raw_vector.py device_buffer_sharded)
+            [cap / pack, pack * d]  rows sharded over "data", with
+            pack = mesh.row_pack(d): 96 -> 4, 64 -> 2, 128 and 768 -> 1.
+            The chip lays a minor dimension under 128 lanes out
+            column-major; a row gather then copies the whole shard, in
+            every dispatch. Whole 128-lane super-rows are row-major as
+            placed; `_gather_rows` gathers `id // pack`, keeps `id % pack`.
 """
 
 from __future__ import annotations
@@ -135,6 +142,63 @@ def _int8_search_fn(mesh: Mesh, r: int, metric: MetricType,
     )
 
 
+def _gather_rows(b, rows, d: int):
+    """Logical rows `rows` [...] of a raw slab as placed,
+    `[n / pack, pack * d]` (mesh.row_pack) -> [..., d]: super-row
+    `row // pack` off the row-major slab, then sub-row `row % pack`, so
+    no instruction reads the whole shard. `pack` 1 is the plain gather."""
+    pack = b.shape[1] // d
+    if pack == 1:
+        return b[rows]
+    sup = b[rows // pack]  # [..., pack * d]
+    sub = (rows % pack)[..., None]
+    vecs = sup[..., :d]
+    for j in range(1, pack):
+        vecs = jnp.where(sub == j, sup[..., j * d:(j + 1) * d], vecs)
+    return vecs
+
+
+def _rerank_tail(b, bsqn, q, cand_i, shard, k: int,
+                 rerank_metric: MetricType):
+    """The exact tail of the two mesh rerank programs, inside their
+    shard_map on shard `shard` of "data": score the merged candidates
+    `cand_i` [B, r] (global row ids, -1 = none) against this shard's raw
+    slab `b` as placed (`_gather_rows`) with `bsqn` [rows]; candidates
+    this shard does not own score -inf and the pmax merge recovers the
+    owner's exact score everywhere (ownership by the BASE slab's logical
+    rows — the mirror and the raw buffer are padded to different
+    alignments); then the final top-k."""
+    with jax.named_scope("rerank"):
+        local_nb = bsqn.shape[0]
+        local = cand_i - shard * local_nb
+        mine = (cand_i >= 0) & (local >= 0) & (local < local_nb)
+        safe = jnp.clip(local, 0, local_nb - 1)
+        vecs = _gather_rows(b, safe, q.shape[1])  # [B, rr, d]
+        bvsq = bsqn[safe]
+        qf = q.astype(b.dtype)
+        rdots = jax.lax.dot_general(
+            qf, vecs, (((1,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+            precision=dot_precision(qf, vecs),
+        )
+        if rerank_metric is MetricType.L2:
+            rscores = -(sqnorms(qf)[:, None] - 2.0 * rdots + bvsq)
+        elif rerank_metric is MetricType.COSINE:
+            qn = jnp.sqrt(jnp.maximum(sqnorms(qf), 1e-30))[:, None]
+            vn = jnp.sqrt(jnp.maximum(bvsq, 1e-30))
+            rscores = rdots / (qn * vn)
+        else:
+            rscores = rdots
+        rscores = jnp.where(mine, rscores, NEG_INF)
+    with jax.named_scope("pmax"):
+        rscores = jax.lax.pmax(rscores, "data")
+    with jax.named_scope("rerank"):
+        kk = min(k, rscores.shape[1])
+        out_s, out_pos = jax.lax.top_k(rscores, kk)
+        out_i = jnp.take_along_axis(cand_i, out_pos, axis=1)
+        return out_s, jnp.where(jnp.isfinite(out_s), out_i, -1)
+
+
 def sharded_ivf_search(
     mesh: Mesh,
     centroids: jax.Array | None,  # [nlist, d] f32 replicated (None: no probe)
@@ -143,7 +207,7 @@ def sharded_ivf_search(
     row_scale: jax.Array,         # [N_pad] f32 sharded P("data")
     row_vsq: jax.Array,           # [N_pad] f32 sharded P("data")
     valid: jax.Array,             # [N_pad] bool sharded P("data")
-    base: jax.Array,              # [cap, d] raw rows sharded P("data", None)
+    base: jax.Array,              # [cap/pack, pack*d] raw rows, P("data", None)
     base_sqnorm: jax.Array,       # [cap] f32 sharded P("data")
     queries: jax.Array,           # [B_pad, d] f32 sharded P("query", None)
     r: int,
@@ -234,40 +298,7 @@ def _ivf_search_fn(
             rr = min(r, all_s.shape[1])
             cand_s, pos = jax.lax.top_k(all_s, rr)
             cand_i = jnp.take_along_axis(all_i, pos, axis=1)
-        # exact rerank against the shard's raw slab: candidates this
-        # shard does not own score -inf and the pmax merge recovers the
-        # owner's exact score everywhere (ownership by the BASE slab
-        # size — the mirror and the raw buffer are padded to different
-        # alignments)
-        with jax.named_scope("rerank"):
-            local_nb = b.shape[0]
-            local = cand_i - shard * local_nb
-            mine = (cand_i >= 0) & (local >= 0) & (local < local_nb)
-            safe = jnp.clip(local, 0, local_nb - 1)
-            vecs = b[safe]  # [B, rr, d]
-            bvsq = bsqn[safe]
-            qf = q.astype(b.dtype)
-            rdots = jax.lax.dot_general(
-                qf, vecs, (((1,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-                precision=dot_precision(qf, vecs),
-            )
-            if rerank_metric is MetricType.L2:
-                rscores = -(sqnorms(qf)[:, None] - 2.0 * rdots + bvsq)
-            elif rerank_metric is MetricType.COSINE:
-                qn = jnp.sqrt(jnp.maximum(sqnorms(qf), 1e-30))[:, None]
-                vn = jnp.sqrt(jnp.maximum(bvsq, 1e-30))
-                rscores = rdots / (qn * vn)
-            else:
-                rscores = rdots
-            rscores = jnp.where(mine, rscores, NEG_INF)
-        with jax.named_scope("pmax"):
-            rscores = jax.lax.pmax(rscores, "data")
-        with jax.named_scope("rerank"):
-            kk = min(k, rscores.shape[1])
-            out_s, out_pos = jax.lax.top_k(rscores, kk)
-            out_i = jnp.take_along_axis(cand_i, out_pos, axis=1)
-            return out_s, jnp.where(jnp.isfinite(out_s), out_i, -1)
+        return _rerank_tail(b, bsqn, q, cand_i, shard, k, rerank_metric)
 
     # jit names the XLA module after the function: the serving program
     # is `jit_sharded_fused_scan_rerank` on the device trace (probe
@@ -296,7 +327,7 @@ def sharded_binary_refine(
     m_scale: jax.Array,      # [N_pad] f32 sharded P("data")
     m_vsq: jax.Array,        # [N_pad] f32 sharded P("data")
     valid: jax.Array,        # [N_pad] bool sharded P("data")
-    base: jax.Array,         # [cap, d] raw rows sharded P("data", None)
+    base: jax.Array,         # [cap/pack, pack*d] raw rows, P("data", None)
     base_sqnorm: jax.Array,  # [cap] f32 sharded P("data")
     queries: jax.Array,      # [B_pad, d] f32 sharded P("query", None)
     r0: int,
@@ -357,34 +388,8 @@ def _binary_refine_fn(
         rr = min(r1, all_s.shape[1])
         cand_s, pos = jax.lax.top_k(all_s, rr)
         cand_i = jnp.take_along_axis(all_i, pos, axis=1)
-        # stage 2: exact rerank against the shard's raw slab, pmax
-        # ownership merge (same math as _ivf_search_fn's tail)
-        local_nb = b.shape[0]
-        local = cand_i - shard * local_nb
-        mine = (cand_i >= 0) & (local >= 0) & (local < local_nb)
-        safe = jnp.clip(local, 0, local_nb - 1)
-        vecs = b[safe]  # [B, rr, d]
-        bvsq = bsqn[safe]
-        qf = q.astype(b.dtype)
-        rdots = jax.lax.dot_general(
-            qf, vecs, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-            precision=dot_precision(qf, vecs),
-        )
-        if rerank_metric is MetricType.L2:
-            rscores = -(sqnorms(qf)[:, None] - 2.0 * rdots + bvsq)
-        elif rerank_metric is MetricType.COSINE:
-            qn = jnp.sqrt(jnp.maximum(sqnorms(qf), 1e-30))[:, None]
-            vn = jnp.sqrt(jnp.maximum(bvsq, 1e-30))
-            rscores = rdots / (qn * vn)
-        else:
-            rscores = rdots
-        rscores = jnp.where(mine, rscores, NEG_INF)
-        rscores = jax.lax.pmax(rscores, "data")
-        kk = min(k, rscores.shape[1])
-        out_s, out_pos = jax.lax.top_k(rscores, kk)
-        out_i = jnp.take_along_axis(cand_i, out_pos, axis=1)
-        return out_s, jnp.where(jnp.isfinite(out_s), out_i, -1)
+        # stage 2: exact rerank against the raw slab, pmax ownership merge
+        return _rerank_tail(b, bsqn, q, cand_i, shard, k, rerank_metric)
 
     return register_jit(
         f"sharded.binary_refine[{_mesh_tag(mesh)},r0_{r0},r1_{r1},k{k},"
