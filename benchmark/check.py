@@ -34,16 +34,34 @@ def score_error(cfg: dict, ref: data.ExactReference, queries: np.ndarray,
     return float(np.nanmax(np.where(np.isnan(gap), np.inf, gap)))
 
 
+def filter_violations(filt: dict, ids: np.ndarray) -> int:
+    """Served ids ([n, rows, k], -1 = no hit) whose column value does not
+    satisfy their own request's `lo <= value < hi`."""
+    value = filt["col"][np.maximum(ids, 0)]
+    lo, hi = filt["lo"][:, None, None], filt["hi"][:, None, None]
+    return int(((ids >= 0) & ~((value >= lo) & (value < hi))).sum())
+
+
 def compare(cfg: dict, ref: data.ExactReference, queries: np.ndarray,
             truth: np.ndarray, q_idx: np.ndarray, ids: np.ndarray,
-            scores: np.ndarray) -> tuple[dict, np.ndarray]:
+            scores: np.ndarray, filt: dict | None = None
+            ) -> tuple[dict, np.ndarray]:
     """Checks on the window's answers: q_idx [n, rows] pool rows asked,
-    ids/scores [n, rows, k] served. Returns (checks, per-row recall)."""
+    ids/scores [n, rows, k] served; truth [pool, k]. Under a filter,
+    `filt` holds per request its bounds `lo`, `hi` [n] and the passing
+    set it asked for (`set_idx` [n]), the column `col` [N], and truth is
+    [S, pool, k]: each served row is held to the truth of its own
+    request's passing set. Returns (checks, per-row recall)."""
     lim = cfg["limits"]
-    k = truth.shape[1]
+    k = truth.shape[-1]
     flat_ids = ids.reshape(-1, k)
     flat_q = np.repeat(q_idx.ravel(), k).reshape(-1, k)
-    rec = (data.recall_rows(flat_ids, truth[q_idx.ravel()])
+    if filt is None:
+        want = truth[q_idx.ravel()]
+    else:
+        want = truth[np.repeat(filt["set_idx"], q_idx.shape[1]),
+                     q_idx.ravel()]
+    rec = (data.recall_rows(flat_ids, want)
            if flat_ids.size else np.zeros(0))
     short = int((flat_ids < 0).any(1).sum())
     checks = {
@@ -56,6 +74,9 @@ def compare(cfg: dict, ref: data.ExactReference, queries: np.ndarray,
                                            flat_ids, scores.reshape(-1, k)),
                       "limit": lim["score_err_max"], "op": "<="},
     }
+    if filt is not None:
+        checks["filter_violations"] = {
+            "value": filter_violations(filt, ids), "limit": 0, "op": "<="}
     return checks, rec
 
 

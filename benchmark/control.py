@@ -49,13 +49,26 @@ def answers(cfg, ref, queries, truth, precision: str = "HIGH"):
             np.take_along_axis(s, order, 1))
 
 
-def report(cfg, ref, queries, truth, win) -> None:
-    """Print the control's checks beside the program's (stderr)."""
+def report(cfg, ref, queries, truth, win, filtered=None) -> None:
+    """Print the control's checks beside the program's (stderr). Under a
+    filter (`filtered`: run.py's FilteredTruth) the control answers each
+    request from the truth of its own passing set."""
+    q_idx = win["q_idx"]
+    filt = None
+    if filtered is not None:
+        truth, filt = filtered.of(win["f_lo"], win["f_width"])
     for precision in ("HIGHEST", "HIGH", "DEFAULT"):
-        ids, scores = answers(cfg, ref, queries, truth, precision)
-        q_idx = win["q_idx"]
+        if filt is None:
+            ids, scores = answers(cfg, ref, queries, truth, precision)
+            ids, scores = ids[q_idx], scores[q_idx]
+        else:
+            per_set = [answers(cfg, ref, queries, t, precision)
+                       for t in truth]
+            sets = filt["set_idx"][:, None]
+            ids = np.stack([a[0] for a in per_set])[sets, q_idx]
+            scores = np.stack([a[1] for a in per_set])[sets, q_idx]
         checks, _ = check.compare(cfg, ref, queries, truth, q_idx,
-                                  ids[q_idx], scores[q_idx])
+                                  ids, scores, filt)
         print(json.dumps({"control": precision, "checks": checks,
                           "correct": all(check.passed(c)
                                          for c in checks.values())}),

@@ -3,7 +3,8 @@
 The generator is chip_smoke.py's (clustered Gaussians, queries = stored
 rows + noise), copied here so that later PRs may change the program but
 not the yardstick. The reference imports nothing of the program: exact
-float64 top-k in numpy over the same rows, in blocks.
+float64 top-k in numpy over the same rows, in blocks; under a filter,
+over the rows that pass it.
 """
 
 from __future__ import annotations
@@ -94,8 +95,17 @@ class ExactReference:
             return (rows * rows).sum(1)[None, :] - 2.0 * (q @ rows.T)
         return -(q @ rows.T)
 
-    def topk(self, queries: np.ndarray, k: int) -> np.ndarray:
-        """[Q, k] row ids, nearest first."""
+    def topk(self, queries: np.ndarray, k: int,
+             allowed: np.ndarray | None = None) -> np.ndarray:
+        """[Q, k] row ids, nearest first; with `allowed` ([n] bool), over
+        the rows where it holds and no others."""
+        if allowed is not None:
+            rows = np.flatnonzero(allowed)
+            if rows.size < k:
+                raise ValueError(f"{rows.size} rows pass, fewer than k={k}")
+            sub = ExactReference(self.base[rows], self.metric, self.block,
+                                 self.depth)
+            return rows[sub.topk(queries, k)]
         q64 = self._prep(queries)
         q32 = q64.astype(np.float32)
         n, depth = self.base.shape[0], max(self.depth, 4 * k)
@@ -111,6 +121,13 @@ class ExactReference:
         with _pool() as ex:
             cand = np.concatenate(
                 list(ex.map(candidates, range(0, n, self.block))), 1)
+        return self.rank(queries, cand, k)
+
+    def rank(self, queries: np.ndarray, cand: np.ndarray,
+             k: int) -> np.ndarray:
+        """[Q, k]: the k nearest of each query's own candidate rows
+        (cand [Q, C] row ids), scored in float64."""
+        q64 = self._prep(queries)
         out = np.empty((q64.shape[0], k), np.int64)
         for i in range(q64.shape[0]):
             key = self._keys(q64[i:i + 1], self._prep(self.base[cand[i]]))[0]
@@ -127,6 +144,51 @@ class ExactReference:
         if self.metric == "L2":
             return ((q - v) ** 2).sum(1)
         return (normalise(q) * normalise(v)).sum(1)
+
+
+#: a column with more distinct values than this is answered set by set
+MAX_VALUE_CLASSES = 256
+
+
+def range_sets(values: np.ndarray, lo: np.ndarray,
+               hi: np.ndarray) -> np.ndarray:
+    """[n, 2]: for each range filter `lo <= column < hi`, the run
+    [first, past-last) of the column's sorted distinct `values` that
+    pass. Two filters with the same run pass the same rows."""
+    return np.stack([np.searchsorted(values, lo, "left"),
+                     np.searchsorted(values, hi, "left")], 1)
+
+
+def range_truth(ref: ExactReference, queries: np.ndarray, k: int,
+                col: np.ndarray, sets: np.ndarray) -> np.ndarray:
+    """[S, Q, k]: the exact top-k under each of `sets` (rows of
+    `range_sets` over np.unique(col)).
+
+    A column of few distinct values (row i holds i % modulo) is answered
+    value by value: the top-k of a union of rows lies in the union of
+    each part's own exact top-k, so one pass over the corpus serves every
+    set; the k x (values in the set) candidates are then ranked in
+    float64. A column of many values is answered set by set."""
+    values = np.unique(col)
+    if values.size > MAX_VALUE_CLASSES:
+        return np.stack([ref.topk(queries, k, allowed=(
+            (col >= values[a]) & (col < (values[b] if b < values.size
+                                         else np.inf))))
+            for a, b in sets])
+    used = sorted({v for a, b in sets for v in range(a, b)})
+    own = {}
+    for v in used:
+        allowed = col == values[v]
+        own[v] = ref.topk(queries, min(k, int(allowed.sum())), allowed)
+    out = []
+    for a, b in sets:
+        cand = np.concatenate([own[v] for v in range(a, b)] or
+                              [np.empty((queries.shape[0], 0), np.int64)], 1)
+        if cand.shape[1] < k:
+            raise ValueError(f"{cand.shape[1]} rows pass values "
+                             f"[{a}, {b}), fewer than k={k}")
+        out.append(ref.rank(queries, cand, k))
+    return np.stack(out)
 
 
 def recall_rows(got: np.ndarray, want: np.ndarray) -> np.ndarray:

@@ -6,7 +6,9 @@ the servers' interpreter lock. One general generator reads a traffic
 mix's parameters: a closed loop (each thread is a caller that waits for
 its reply) or an open loop (requests are due at times fixed beforehand
 and are timed from then). Every answer is kept, so the harness can
-compare what the timed requests themselves returned.
+compare what the timed requests themselves returned. A mix may state a
+`filter`: each request then carries one range filter over a scalar
+column, drawn from the caller's own stream (`draw_filter`).
 
     python benchmark/loadgen.py <spec.json>   ->  writes spec["out"] (.npz)
 
@@ -46,6 +48,40 @@ def parse_profile(prof: dict) -> tuple[list[float], str]:
             ",".join(disp.get("tags") or []))
 
 
+#: a drawn bound keeps this far from a whole number: the column holds
+#: whole numbers (row i: i % modulo), so a float32 and a float64
+#: evaluation of `lo <= column < hi` then pass the same rows
+BOUND_MARGIN = 2.0 ** -10
+
+
+def draw_filter(rng: np.random.Generator, flt: dict) -> tuple[float, float]:
+    """(lo, width) of one request's filter: a class by weight, then `lo`
+    uniformly from the continuous [0, modulo - width). Because `lo` is
+    continuous no two requests carry the same filter, so a cache keyed
+    on the filter cannot answer for its evaluation; because row i holds
+    i % modulo, a whole `width` passes width / modulo of the rows
+    whatever `lo`."""
+    classes = flt["classes"]
+    weights = np.asarray([c["weight"] for c in classes], np.float64)
+    width = float(classes[rng.choice(len(classes),
+                                     p=weights / weights.sum())]["width"])
+    room = float(flt["modulo"]) - width
+    if not (width > 0 and room > 4 * BOUND_MARGIN):
+        raise ValueError(f"filter width {width} leaves no room for a lower "
+                         f"bound under modulo {flt['modulo']}")
+    while True:
+        lo = float(rng.uniform(0.0, room))
+        if all(abs(b - round(b)) >= BOUND_MARGIN for b in (lo, lo + width)):
+            return lo, width
+
+
+def filter_body(column: str, lo: float, width: float) -> dict:
+    """`lo <= column < lo + width` in the product's own filter form."""
+    return {"operator": "AND", "conditions": [
+        {"field": column, "operator": ">=", "value": lo},
+        {"field": column, "operator": "<", "value": lo + width}]}
+
+
 class Recorder:
     """Per-request records of one generator process."""
 
@@ -55,9 +91,11 @@ class Recorder:
         self.t_due, self.t_send, self.t_done, self.ok = [], [], [], []
         self.q_idx, self.ids, self.scores, self.prof, self.tags = \
             [], [], [], [], []
+        self.f_lo, self.f_width = [], []
         self.errors: list[str] = []
 
-    def add(self, t_due, t_send, t_done, q_idx, docs, prof, err):
+    def add(self, t_due, t_send, t_done, q_idx, docs, prof, err,
+            f_lo=np.nan, f_width=np.nan):
         ids = np.full((self.rows, self.k), -1, np.int64)
         scores = np.full((self.rows, self.k), np.nan, np.float64)
         # a reply that came is judged by what it says: rows or hits it
@@ -81,6 +119,8 @@ class Recorder:
             self.scores.append(scores)
             self.prof.append(vals)
             self.tags.append(tags)
+            self.f_lo.append(f_lo)
+            self.f_width.append(f_width)
             if err is not None and len(self.errors) < 5:
                 self.errors.append(err)
 
@@ -98,6 +138,8 @@ class Recorder:
             "prof": np.asarray(self.prof, np.float64).reshape(
                 n, len(PROFILE_FIELDS)),
             "tags": np.asarray(self.tags, dtype=str),
+            "f_lo": np.asarray(self.f_lo, np.float64),
+            "f_width": np.asarray(self.f_width, np.float64),
             "errors": np.asarray(self.errors, dtype=str),
         }
 
@@ -114,14 +156,20 @@ def make_sender(spec: dict, pool: np.ndarray, rec: Recorder):
     # inside a timed window
     client.max_retries_429 = 0
 
-    def send(q_idx: np.ndarray, t_due: float) -> None:
+    column = (spec.get("filter") or {}).get("column")
+
+    def send(q_idx: np.ndarray, t_due: float,
+             flt: tuple[float, float] | None = None) -> None:
+        """One request; `flt` is its (lo, width) where the mix filters."""
+        filters = filter_body(column, *flt) if flt else None
         t_send = time.monotonic()
         docs = prof = err = None
         try:
             out = client.search(
                 spec["db"], spec["space"],
                 vectors=[{"field": spec["field"], "feature": pool[q_idx]}],
-                limit=spec["k"], fields=[], index_params=spec["index_params"],
+                limit=spec["k"], filters=filters, fields=[],
+                index_params=spec["index_params"],
                 profile=spec["profile"], cache=spec["cache"])
             if spec["profile"]:
                 docs, prof = out["documents"], out["profile"]
@@ -130,7 +178,8 @@ def make_sender(spec: dict, pool: np.ndarray, rec: Recorder):
         except (rpc.RpcError, OSError, KeyError, ValueError) as e:
             err = f"{type(e).__name__}: {e}"
         t_done = time.monotonic()
-        rec.add(t_due, t_send, t_done, q_idx, docs, prof, err)
+        rec.add(t_due, t_send, t_done, q_idx, docs, prof, err,
+                *(flt or ()))
 
     return send
 
@@ -142,8 +191,10 @@ def run_closed(spec: dict, pool: np.ndarray, rec: Recorder) -> None:
         rng = np.random.default_rng([spec["seed"], spec["worker"], tid])
         while time.monotonic() < spec["t_start"]:
             time.sleep(0.0005)
+        flt = spec.get("filter")
         while (now := time.monotonic()) < spec["t_stop"]:
-            send(rng.integers(0, pool.shape[0], spec["rows"]), now)
+            q_idx = rng.integers(0, pool.shape[0], spec["rows"])
+            send(q_idx, now, draw_filter(rng, flt) if flt else None)
 
     threads = [threading.Thread(target=caller, args=(i,), name=f"caller{i}")
                for i in range(spec["threads"])]
@@ -159,12 +210,15 @@ def run_open(spec: dict, pool: np.ndarray, rec: Recorder) -> None:
     due = np.load(spec["due_path"])  # absolute monotonic seconds
     rng = np.random.default_rng([spec["seed"], spec["worker"]])
     picks = rng.integers(0, pool.shape[0], (due.size, spec["rows"]))
+    flt = spec.get("filter")
+    filters = [draw_filter(rng, flt) for _ in due] if flt else None
     work: queue.Queue = queue.Queue()
 
     def sender() -> None:
         send = make_sender(spec, pool, rec)
         while (item := work.get()) is not None:
-            send(picks[item], float(due[item]))
+            send(picks[item], float(due[item]),
+                 filters[item] if filters else None)
 
     threads = [threading.Thread(target=sender, name=f"sender{i}")
                for i in range(spec["threads"])]

@@ -137,29 +137,35 @@ class Cluster:
                 f"restored partition reads status {part['status']} with "
                 f"{part['doc_count']} docs, expected INDEXED with {self.rows}")
 
-    def search(self, queries: np.ndarray, profile: bool = True):
+    def search(self, queries: np.ndarray, profile: bool = True,
+               filters: dict | None = None):
         s = self.cfg["search"]
         return self.client.search(
             corpus.DB, self.space,
             vectors=[{"field": self.cfg["vector_field"], "feature": queries}],
-            limit=s["k"], fields=[], index_params=s["index_params"],
-            profile=profile, cache=False)
+            limit=s["k"], filters=filters, fields=[],
+            index_params=s["index_params"], profile=profile, cache=False)
 
-    def warm(self, pool: np.ndarray, warm_rows: list[int]) -> None:
+    def warm(self, pool: np.ndarray, warm_rows: list[int],
+             filters=()) -> None:
         """Every row count the mix can put into one dispatch, through the
-        served entry; then the whole pool once, so that whichever queries
-        the shadow-recall sampler picks have run their exact scan too.
-        Each must name the configuration's dispatch path: a failed build
-        serves brute force and would pass on answers alone."""
+        served entry (under each of `filters`, one of every class, where
+        the mix filters); then the whole pool once, so that whichever
+        queries the shadow-recall sampler picks have run their exact scan
+        too. Each must name the configuration's dispatch path: a failed
+        build serves brute force and would pass on answers alone."""
         tag = self.cfg["serving"]["dispatch_tag"]
         for rows in warm_rows:
-            for _ in range(2):
-                out = self.search(np.resize(pool, (rows, pool.shape[1])))
-                tags = out["profile"]["partitions"][self.pid][
-                    "dispatches"]["tags"]
-                if tag not in tags:
-                    raise RuntimeError(f"{rows}-row request served by {tags},"
-                                       f" not {tag}")
+            for flt in filters or [None]:
+                for _ in range(2):
+                    out = self.search(np.resize(pool, (rows, pool.shape[1])),
+                                      filters=flt)
+                    tags = out["profile"]["partitions"][self.pid][
+                        "dispatches"]["tags"]
+                    if tag not in tags:
+                        raise RuntimeError(
+                            f"{rows}-row request under {flt} served by "
+                            f"{tags}, not {tag}")
         if self.stats()["quality"]["sampling"]["rate"] > 0:
             for lo in range(0, pool.shape[0], 64):
                 self.search(pool[lo:lo + 64], profile=False)
@@ -180,6 +186,19 @@ class Cluster:
             if time.monotonic() > deadline:
                 raise RuntimeError(f"shadow sampler still busy: {c}")
             time.sleep(0.1)
+
+    def filter_cache_events(self) -> dict:
+        """The PS's `vearch_ps_filter_cache_events_total{event}`, from its
+        /metrics: what the engine's filter-mask cache answered."""
+        import urllib.request
+
+        name = "vearch_ps_filter_cache_events_total"
+        with urllib.request.urlopen(f"http://{self.ps_addr}/metrics",
+                                    timeout=30.0) as r:
+            text = r.read().decode()
+        return {line.split('event="')[1].split('"')[0]:
+                float(line.rsplit(" ", 1)[1])
+                for line in text.splitlines() if line.startswith(name + "{")}
 
     def write_read_delete(self, near: np.ndarray) -> int:
         """chip_smoke.py's guarantee check: an acknowledged write is read
@@ -218,19 +237,33 @@ class Cluster:
                 t.join(max(0.0, deadline - time.monotonic()))
 
 
-def spawn_generators(cell, cfg, router: str, pool_path: str, run_dir: str,
-                     seed: int, seconds: float, profile: bool):
-    """Start the mix's generator processes. Returns (procs, out paths,
-    t0 of the window)."""
-    mix = cell.traffic
-    t_start = time.monotonic() + SPAWN_LEAD_S
-    t0 = t_start + float(mix["warmup_s"])
-    t_stop = t0 + seconds + 0.25
-    procs, outs = [], []
-    if mix["loop"] == "open":
-        due = t_start + stats.open_loop_schedule(
-            float(mix["rate_per_s"]), t_stop - t_start,
-            int(mix["gaps_seed"]), seed)
+def mix_filter(cfg: dict, mix: dict) -> dict:
+    """The mix's `filter` with the modulo of the column it names, which
+    the configuration's `scalar_columns` states."""
+    flt = mix["filter"]
+    cols = {c["name"]: c for c in cfg.get("scalar_columns", [])}
+    if flt["column"] not in cols:
+        raise KeyError(f"mix {mix['name']!r} filters on {flt['column']!r}; "
+                       f"configuration {cfg['name']!r} has {sorted(cols)}")
+    return {**flt, "modulo": cols[flt["column"]]["modulo"]}
+
+
+def warm_filters(cfg: dict, mix: dict, seed: int) -> list[dict]:
+    """A filter of each class of the mix, for the warm-up; none where the
+    mix does not filter."""
+    if not mix.get("filter"):
+        return []
+    flt, rng = mix_filter(cfg, mix), np.random.default_rng(seed)
+    return [loadgen.filter_body(flt["column"], *loadgen.draw_filter(
+        rng, {**flt, "classes": [c]})) for c in flt["classes"]]
+
+
+def generator_specs(mix: dict, cfg: dict, router: str, pool_path: str,
+                    run_dir: str, seed: int, t_start: float, t_stop: float,
+                    profile: bool) -> list[dict]:
+    """What each generator process of the mix is told (loadgen.py's
+    spec). A mix without `filter` has no such key in its specs."""
+    specs = []
     for w in range(int(mix["processes"])):
         spec = {
             "router": router, "db": corpus.DB, "space": cfg["space"]["name"],
@@ -245,6 +278,29 @@ def spawn_generators(cell, cfg, router: str, pool_path: str, run_dir: str,
         }
         if mix["loop"] == "open":
             spec["due_path"] = os.path.join(run_dir, f"due{w}.npy")
+        if mix.get("filter"):
+            spec["filter"] = mix_filter(cfg, mix)
+        specs.append(spec)
+    return specs
+
+
+def spawn_generators(cell, cfg, router: str, pool_path: str, run_dir: str,
+                     seed: int, seconds: float, profile: bool):
+    """Start the mix's generator processes. Returns (procs, out paths,
+    t0 of the window)."""
+    mix = cell.traffic
+    t_start = time.monotonic() + SPAWN_LEAD_S
+    t0 = t_start + float(mix["warmup_s"])
+    t_stop = t0 + seconds + 0.25
+    procs, outs = [], []
+    if mix["loop"] == "open":
+        due = t_start + stats.open_loop_schedule(
+            float(mix["rate_per_s"]), t_stop - t_start,
+            int(mix["gaps_seed"]), seed)
+    for spec in generator_specs(mix, cfg, router, pool_path, run_dir, seed,
+                                t_start, t_stop, profile):
+        w = spec["worker"]
+        if mix["loop"] == "open":
             np.save(spec["due_path"], due[w::int(mix["processes"])])
         spec_path = os.path.join(run_dir, f"gen{w}.json")
         with open(spec_path, "w") as f:
@@ -260,7 +316,7 @@ def gather(outs: list[str]) -> dict:
     parts = [np.load(p) for p in outs]
     rec = {k: np.concatenate([p[k] for p in parts])
            for k in ("t_due", "t_send", "t_done", "ok", "q_idx", "ids",
-                     "scores", "prof", "tags")}
+                     "scores", "prof", "tags", "f_lo", "f_width")}
     rec["errors"] = [str(e) for p in parts for e in p["errors"]]
     return rec
 
@@ -290,19 +346,82 @@ def window_view(mix: dict, rec: dict, t0: float, seconds: float) -> dict:
         attempted = int(due.sum())
         late = stats.lateness_ms(rec["t_due"][due], rec["t_send"][due])
     win = {k: rec[k][mask] for k in ("t_due", "t_send", "t_done", "q_idx",
-                                     "ids", "scores", "prof", "tags")}
+                                     "ids", "scores", "prof", "tags",
+                                     "f_lo", "f_width")}
     return {"win": win, "lat_ms": lat, "attempted": attempted,
             "failed": attempted - int(mask.sum()), "late_ms": late}
 
 
+class FilteredTruth:
+    """The reference's answers under the filters a window sent: the exact
+    top-k of every pool query under every distinct passing set, made
+    after the window and kept beside `truth.npy` in a file of its own
+    (`truth-filter-<column>.npz`; `path` None keeps nothing)."""
+
+    def __init__(self, ref: data.ExactReference, queries: np.ndarray,
+                 k: int, col: np.ndarray, path: str | None = None):
+        self.ref, self.queries, self.k, self.col = ref, queries, k, col
+        self.values, counts = np.unique(col, return_counts=True)
+        #: rows holding one of the first i distinct values
+        self.rows_below = np.concatenate([[0], np.cumsum(counts)])
+        self.path = path
+        self.sets = np.zeros((0, 2), np.int64)
+        self.truth = np.zeros((0, queries.shape[0], k), np.int64)
+        if path and os.path.exists(path):
+            with np.load(path) as f:
+                self.sets, self.truth = f["sets"], f["truth"]
+
+    def of(self, lo: np.ndarray, width: np.ndarray) -> tuple[np.ndarray, dict]:
+        """(truth [S, pool, k], the `filt` of check.compare) for requests
+        that asked for `lo <= column < lo + width`."""
+        hi = lo + width
+        mine = data.range_sets(self.values, lo, hi)
+        known = {tuple(s) for s in self.sets.tolist()}
+        new = np.array(sorted({tuple(s) for s in mine.tolist()} - known),
+                       np.int64).reshape(-1, 2)
+        if new.size:
+            t_ref = time.monotonic()
+            self.truth = np.concatenate([self.truth, data.range_truth(
+                self.ref, self.queries, self.k, self.col, new)])
+            self.sets = np.concatenate([self.sets, new])
+            if self.path:
+                np.savez(self.path, sets=self.sets, truth=self.truth)
+            log("filtered reference", sets=int(new.shape[0]),
+                seconds=time.monotonic() - t_ref)
+        index = {tuple(s): i for i, s in enumerate(self.sets.tolist())}
+        set_idx = np.array([index[tuple(s)] for s in mine.tolist()], np.int64)
+        return self.truth, {"col": self.col, "lo": lo, "hi": hi,
+                            "set_idx": set_idx}
+
+    def pass_share(self, lo: np.ndarray, width: np.ndarray,
+                   classes: list[dict]) -> dict:
+        """Requests and mean share of rows that passed, by class (a class
+        is known by the width it asks)."""
+        sets = data.range_sets(self.values, lo, lo + width)
+        share = (self.rows_below[sets[:, 1]]
+                 - self.rows_below[sets[:, 0]]) / self.col.size
+        out = {}
+        for c in classes:
+            mine = width == float(c["width"])
+            out[c["name"]] = {
+                "requests": int(mine.sum()),
+                "pass_share": float(share[mine].mean()) if mine.any() else None}
+        return out
+
+
 def judge(cfg: dict, mix: dict, rec: dict, t0: float, seconds: float,
-          ref: data.ExactReference, queries: np.ndarray, truth: np.ndarray):
+          ref: data.ExactReference, queries: np.ndarray, truth: np.ndarray,
+          filtered: FilteredTruth | None = None):
     """The window's view of the generators' records and the checks on what
-    its requests themselves returned."""
+    its requests themselves returned; with `filtered`, each request held
+    to the truth of its own passing set (`truth` is not read)."""
     view = window_view(mix, rec, t0, seconds)
     win = view["win"]
+    filt = None
+    if filtered is not None:
+        truth, filt = filtered.of(win["f_lo"], win["f_width"])
     checks, _ = check.compare(cfg, ref, queries, truth, win["q_idx"],
-                              win["ids"], win["scores"])
+                              win["ids"], win["scores"], filt)
     return view, checks
 
 
@@ -375,7 +494,11 @@ def main(argv: list[str] | None = None) -> int:
         log("restored", **restored["partitions"][0])
         maker.join()
         base, queries = made["base"], made["queries"]
-        cl.warm(queries, [int(r) for r in mix["warm_rows"]])
+        flt = mix_filter(cfg, mix) if mix.get("filter") else None
+        cl.warm(queries, [int(r) for r in mix["warm_rows"]],
+                warm_filters(cfg, mix, seed))
+        # read outside the window: a scrape is work on the servers' interpreter
+        mask_cache = cl.filter_cache_events() if flt else None
         log("warmed", programs=perf_model.total_compiled_programs())
         pool_path = os.path.join(run_dir, "pool.npy")
         np.save(pool_path, queries)
@@ -416,6 +539,11 @@ def main(argv: list[str] | None = None) -> int:
             if rc != 0:
                 raise RuntimeError(f"a load generator exited with {rc}")
         rec = gather(outs)
+        if flt:
+            after = cl.filter_cache_events()
+            log("filter mask cache", after_warm_up=mask_cache,
+                after_generators=after,
+                hits_between=after.get("hit", 0.0) - mask_cache.get("hit", 0.0))
         mem = [d.memory_stats() or {} for d in devs]
         memory_peak = max((m.get("peak_bytes_in_use") or 0) for m in mem)
         wrd_failed = cl.write_read_delete(queries[1])
@@ -433,22 +561,34 @@ def main(argv: list[str] | None = None) -> int:
     # -- after the window: reference, comparison, metrics -----------------------
     ref = data.ExactReference(base, cfg["metric"])
     truth_path = os.path.join(entry, "truth.npy")
-    if os.path.exists(truth_path):
+    filtered = None
+    if flt:  # held to the truth of each request's passing set, not this one
+        truth = None
+        col = data.scalar_column(flt, rows)
+        filtered = FilteredTruth(
+            ref, queries, int(cfg["search"]["k"]), col,
+            os.path.join(entry, f"truth-filter-{flt['column']}.npz"))
+    elif os.path.exists(truth_path):
         truth = np.load(truth_path)
     else:
         t_ref = time.monotonic()
         truth = ref.topk(queries, int(cfg["search"]["k"]))
         np.save(truth_path, truth)
         log("reference", seconds=time.monotonic() - t_ref)
-    view, checks = judge(cfg, mix, rec, t0, args.seconds, ref, queries, truth)
+    view, checks = judge(cfg, mix, rec, t0, args.seconds, ref, queries, truth,
+                         filtered)
     win = view["win"]
+    if filtered is not None:
+        log("filters in the window",
+            **filtered.pass_share(win["f_lo"], win["f_width"],
+                                  flt["classes"]))
     checks["write_read_delete_failed"] = {"value": wrd_failed, "limit": 0,
                                           "op": "<="}
     checks["window_compiles"] = {"value": len(grew), "limit": 0, "op": "<="}
     if args.control:
         from benchmark import control
 
-        control.report(cfg, ref, queries, truth, win)
+        control.report(cfg, ref, queries, truth, win, filtered)
 
     obs = Obs(cell=cell, config=cfg, traffic=mix, seconds=args.seconds,
               setup_s=setup_s, recall=checks["recall_at_10"]["value"],

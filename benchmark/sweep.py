@@ -49,7 +49,8 @@ def main() -> int:
     cl = run.Cluster(cfg, rows, run_dir)
     try:
         cl.serve_from(entry)
-        cl.warm(queries, [int(r) for r in mix["warm_rows"]])
+        cl.warm(queries, [int(r) for r in mix["warm_rows"]],
+                run.warm_filters(cfg, mix, seed))
         pool_path = os.path.join(run_dir, "pool.npy")
         np.save(pool_path, queries)
         for rate in [float(r) for r in args.rates.split(",")]:

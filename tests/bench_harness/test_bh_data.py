@@ -54,6 +54,45 @@ def test_blocked_reference_equals_the_plain_float64_topk(metric):
     assert (got[:, 0] == q_rows).all()  # a query's nearest row is its source
 
 
+@pytest.mark.parametrize("width", [1, 30], ids=["pass2pct", "pass60pct"])
+@pytest.mark.parametrize("metric", ["L2", "Cosine"])
+def test_filtered_reference_equals_the_plain_topk_over_passing_rows(
+        metric, width, monkeypatch):
+    """`lo <= price < lo + width` of `i % 50`: the plain float64 top-k
+    over the passing rows alone, by `topk(allowed=)` and by
+    `range_truth`'s two ways (value by value; set by set)."""
+    cfg = small_cfg(metric)
+    base, queries, _ = data.make_data(cfg, 13)
+    col = data.scalar_column(cfg["scalar_columns"][0], base.shape[0])
+    lo = 7.25
+    allowed = (col >= lo) & (col < lo + width)
+    assert allowed.mean() == width / 50
+    rows = np.flatnonzero(allowed)
+    want = rows[plain_topk(base[rows], queries, 10, metric)]
+    ref = data.ExactReference(base, metric, block_rows=700, depth=16)
+    np.testing.assert_array_equal(ref.topk(queries, 10, allowed), want)
+    sets = data.range_sets(np.unique(col), np.array([lo]),
+                           np.array([lo + width]))
+    assert sets.tolist() == [[8, 8 + width]]
+    np.testing.assert_array_equal(
+        data.range_truth(ref, queries, 10, col, sets)[0], want)
+    monkeypatch.setattr(data, "MAX_VALUE_CLASSES", 4)
+    np.testing.assert_array_equal(
+        data.range_truth(ref, queries, 10, col, sets)[0], want)
+
+
+def test_a_filter_that_passes_fewer_rows_than_k_is_an_error():
+    base = np.random.default_rng(0).standard_normal((40, 4)).astype(np.float32)
+    ref = data.ExactReference(base, "L2")
+    col = (np.arange(40) % 10).astype(np.float64)
+    with pytest.raises(ValueError):
+        ref.topk(base[:2], 10, allowed=col == 3)
+    with pytest.raises(ValueError):
+        data.range_truth(ref, base[:2], 10, col, np.array([[3, 4]]))
+    assert data.range_truth(ref, base[:2], 10, col,
+                            np.array([[3, 6]])).shape == (1, 2, 10)
+
+
 def test_reference_scores_are_what_the_configuration_promises():
     base = np.array([[0, 0], [3, 4], [1, 0]], np.float32)
     q = np.array([[0, 0], [1, 0]], np.float32)
