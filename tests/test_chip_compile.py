@@ -216,6 +216,59 @@ def test_xla_probe_scan_and_rerank_compile(one_chip, widths):
         widths["fetch_k"], L2).compile())
 
 
+def _flat_args(S, b, nlist, cap, n_valid):
+    return (S((b, D), jnp.float32), S((nlist, D), jnp.float32),
+            S((nlist, cap, D), jnp.float32), S((nlist, cap), jnp.float32),
+            S((nlist, cap), jnp.int32), S((n_valid,), jnp.bool_))
+
+
+# benchmark/configs/sift1m-ivfflat.json: nlist 1024, nprobe 32, r 256.
+# cap: one tile (what ISSUE 32 expected of 1M rows), the 8192 the chip
+# run published (longest list 7,824, seed 3200000011), and a longer
+# list's 12288 at the mix's widest bucket
+@pytest.mark.parametrize("b,cap", [(64, 2048), (64, 8192), (256, 12288)])
+def test_ivfflat_probe_scan_compiles_in_tiles(one_chip, widths, b, cap):
+    """The serving program of `sift1m-ivfflat`: XLA module
+    `jit_ivfflat_candidates` (what the benchmark finds it by on the
+    device trace), its stages under their scopes, and no instruction
+    that writes more than ONE scan step's [B, tile, d] gather: no
+    [B, nprobe, cap, d], and no slice of the [nlist, cap, d] table.
+
+    Untiled (a step gathering [B, cap, d] whole) the chip's compiler
+    keeps the gather in one piece up to 1 MiB a slice (cap 2048: temp
+    0.001 GB) and past that cuts it into column slices of the WHOLE
+    table, copied in every step: at cap 10368 six `mini-gather-slice`
+    results of [1024, 1792, 128] f32 and 4.7 GB of temp beside a 5.4 GB
+    table (PERF.md section 6, PR 32). `probe_tile` keeps every step's
+    slice inside the 1 MiB, so temp stays under the step's own gather
+    plus the running top list whatever the longest list."""
+    nlist, nprobe = 1024, 32
+    tile = ivf_ops.probe_tile(cap, D * 4)
+    assert tile == min(cap, 2048) == ivf_ops.probe_tile_rows(D * 4)
+    compiled = ivf_ops.ivfflat_candidates.lower(
+        *_flat_args(_shapes(one_chip), b, nlist, cap, widths["n_store"]),
+        nprobe, RERANK, L2).compile()
+    _, temp = _report(f"ivfflat_candidates[B={b},cap={cap}]", compiled)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_ivfflat_candidates")
+    for scope in ("coarse", "gather", "score", "fold"):
+        assert f"/{scope}/" in text, scope
+    step_gather = b * tile * D
+    written = [
+        (name, shape) for name, shape, op in re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(",
+            text, re.M)
+        if op not in ("bitcast", "parameter", "get-tuple-element", "tuple",
+                      "while")
+        and any(np.prod([int(x) for x in dims.split(",")]) > step_gather
+                for dims in re.findall(r"\b\w+\[([\d,]+)\]", shape))]
+    assert not written, written
+    top_list = b * RERANK * (4 + 4)
+    assert temp < 1.25 * (b * cap * D * 4 + top_list), temp
+    # stronger, as compiled today: under two steps' own gathers
+    assert temp < 2 * step_gather * 4 + (64 << 20), temp
+
+
 @pytest.mark.parametrize("step", ["train_kmeans", "assign_sample",
                                   "assign_all_rows", "train_pq",
                                   "encode_pq"])

@@ -216,6 +216,68 @@ def test_ivfflat_and_flat_dispatch_counts():
     assert _search(feng, fvecs).tags == doc["flat"]
 
 
+def test_ivfflat_served_path_documented_dispatch_and_zero_new_programs(
+        tmp_path):
+    """The `ivfflat` row through the served path (client -> router -> PS
+    -> engine -> `IVFFlatIndex.search`): each search launches exactly
+    `ivfflat_scan`, and once the row counts a mix can put into one
+    dispatch (benchmark/traffic/b64x4-closed.json `warm_rows`: 64, 128,
+    256) have been served, repeating them adds ZERO compiled programs
+    (`window_compiles` 0 in the benchmark's cell)."""
+    from vearch_tpu.cluster import rpc
+    from vearch_tpu.cluster.standalone import StandaloneCluster
+    from vearch_tpu.sdk.client import VearchClient
+
+    n, doc = 2000, perf_model.DOCUMENTED_DISPATCHES
+    c = StandaloneCluster(data_dir=str(tmp_path / "c"), n_ps=1)
+    c.start()
+    try:
+        cl = VearchClient(c.router_addr)
+        cl.create_database("db")
+        cl.create_space("db", {
+            "name": "s", "partition_num": 1, "fields": [
+                {"name": "v", "data_type": "vector", "dimension": D,
+                 "index": {"index_type": "IVFFLAT", "metric_type": "L2",
+                           "params": {"ncentroids": 16, "train_iters": 4,
+                                      "training_threshold": n}}}]})
+        vecs = np.random.default_rng(17).standard_normal(
+            (n, D)).astype(np.float32)
+        cl.upsert("db", "s", [{"_id": f"d{i}", "v": vecs[i]}
+                              for i in range(n)])
+        ps = c.ps_nodes[0]
+        (pid, engine), = ps.engines.items()
+        engine.wait_for_index(timeout=300)
+        # the shadow-recall sampler's exact scans (`flat_scan`, in a
+        # background thread) are off, as in the benchmark's cells
+        rpc.call(ps.addr, "POST", "/ps/engine/config", {
+            "partition_id": int(pid),
+            "config": {"quality": {"sample_rate": 0.0}}})
+
+        def search(rows):
+            out = cl.search(
+                "db", "s", vectors=[{"field": "v", "feature": vecs[:rows]}],
+                limit=10, fields=[], profile=True, cache=False,
+                index_params={"nprobe": 4, "rerank": 256})
+            (part,) = out["profile"]["partitions"].values()
+            assert part["dispatches"]["tags"] == doc["ivfflat"], part
+            assert part["dispatches"]["path"] == "ivfflat"
+
+        for rows in (64, 128, 256):
+            search(rows)
+        before = perf_model.compiled_program_counts()
+        ledger = perf_model.PerfLedger()
+        ivf_ops.set_dispatch_ledger(ledger)
+        try:
+            for rows in (64, 128, 256, 256, 128, 64):
+                search(rows)
+        finally:
+            ivf_ops.set_dispatch_ledger(None)
+        assert ledger.tags == doc["ivfflat"] * 6
+        assert perf_model.compiled_program_counts() == before
+    finally:
+        c.stop()
+
+
 def test_ledger_per_search_aggregation(ivfpq_engine):
     eng, vecs = ivfpq_engine
     ledger = perf_model.PerfLedger()

@@ -428,6 +428,31 @@ class PSServer:
                 )
             return out
 
+        def _ivf_publish():
+            # the published IVF bucket tables of this node, every stat
+            # rendered from the first scrape (a fixed universe: the
+            # cardinality soak sees no series appear with a publish)
+            summed = ("publishes", "rows", "nlist", "bytes", "seconds")
+            out = dict.fromkeys(summed + ("cap", "fill"), 0.0)
+            slots = 0
+            for eng in list(self.engines.values()):
+                for info in ((self._ivf_info_safe(eng) or {})
+                             .get("fields", {}).values()):
+                    for stat in summed:
+                        out[stat] += float(info[stat])
+                    out["cap"] = max(out["cap"], float(info["cap"]))
+                    slots += info["nlist"] * info["cap"]
+            out["fill"] = out["rows"] / slots if slots else 0.0
+            return {(stat,): v for stat, v in out.items()}
+
+        m.callback_gauge("vearch_ps_ivf_publish",
+                         "the IVF bucket tables this node has published "
+                         "(index/ivf.py _publish), by stat: publishes "
+                         "so far, rows held, lists, slots a list (cap, "
+                         "the widest), device bytes, fill = rows / "
+                         "(nlist x cap), seconds the last publishes took",
+                         ("stat",), _ivf_publish)
+
         m.callback_gauge("vearch_engine_mesh_devices",
                          "devices the mesh serving data plane spans "
                          "per partition (0 = single-device path)",
@@ -3287,6 +3312,9 @@ class PSServer:
                     "raft": self.raft_nodes[pid].state()
                     if pid in self.raft_nodes else None,
                     "mesh": self._mesh_info_safe(eng),
+                    # the published IVF bucket table per field: rows,
+                    # nlist, cap, bytes, fill, publishes, seconds
+                    "ivf": self._ivf_info_safe(eng),
                     # tiered storage (HBM slab cache / host-RAM tiers /
                     # prefetch) — the doctor's prefetch-effectiveness
                     # check reads these blocks
@@ -3300,6 +3328,13 @@ class PSServer:
     def _mesh_info_safe(eng) -> dict | None:
         try:
             return eng.mesh_info()
+        except Exception:
+            return None
+
+    @staticmethod
+    def _ivf_info_safe(eng) -> dict | None:
+        try:
+            return eng.ivf_info()
         except Exception:
             return None
 

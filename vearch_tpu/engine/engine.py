@@ -1380,6 +1380,15 @@ class Engine:
         }
         return out
 
+    def ivf_info(self) -> dict[str, Any] | None:
+        """The published bucket table of each vector field whose index
+        keeps one (index/ivf.py `ivf_info`; surfaced in /ps/stats and
+        the `vearch_ps_ivf_publish` gauges); None when no field has
+        published."""
+        fields = {name: info for name, index in self.indexes.items()
+                  if (info := index.ivf_info()) is not None}
+        return {"fields": fields} if fields else None
+
     def tiering_info(self) -> dict[str, Any] | None:
         """Aggregate tiered-storage summary over the engine's vector
         fields (surfaced in /ps/stats and profile:true traces); None

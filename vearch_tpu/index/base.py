@@ -112,6 +112,12 @@ class VectorIndex(abc.ABC):
         not mesh-serving (single device)."""
         return None
 
+    def ivf_info(self) -> dict[str, Any] | None:
+        """The published padded bucket table of an IVF index (rows,
+        nlist, cap, bytes, fill, publishes, seconds), None for an index
+        that publishes none or has not yet."""
+        return None
+
     def tiering_info(self) -> dict[str, Any] | None:
         """Tiered-storage summary (per-tier hit/miss/pin counters,
         residency bytes — see docs/TIERING.md), None when this index

@@ -12,7 +12,9 @@ TPU wants static-shaped dense arrays:
 - `_publish` packs them into padded [nlist, cap, ...] device arrays
   (cap = max bucket length rounded up); a publish happens lazily on the
   first search after new rows were absorbed — the generation-swap pattern
-  (build arrays, then swap references atomically);
+  (build arrays, then swap references atomically; IVFFLAT, whose table
+  holds the raw rows, lets the old generation go before it places the
+  new one and hands a search one generation's arrays under the lock);
 - deletes never touch the index: the engine's validity mask is applied
   in-kernel per slot.
 
@@ -23,6 +25,7 @@ is the recall knob on top of nprobe.
 
 from __future__ import annotations
 
+import time
 from typing import Any
 
 import jax
@@ -38,7 +41,7 @@ from vearch_tpu.ops import ivf as ivf_ops
 from vearch_tpu.ops import kmeans as km
 from vearch_tpu.ops import perf_model
 from vearch_tpu.ops import pq as pq_ops
-from vearch_tpu.ops.distance import sqnorms, to_device_mask
+from vearch_tpu.ops.distance import to_device_mask
 
 
 #: rows of one piece of a bulk absorb: the default training sample's
@@ -94,6 +97,8 @@ class _IVFBase(VectorIndex):
         # published device state
         self._bucket_ids: jax.Array | None = None
         self._cap = 0
+        #: the last publish, as `ivf_info` reports it (None before one)
+        self._published: dict[str, Any] | None = None
 
     def _device_state_arrays(self) -> tuple:
         """Device tensors this index keeps resident beyond the raw store
@@ -274,6 +279,31 @@ class _IVFBase(VectorIndex):
         self._bucket_ids = jnp.asarray(ids)
         return ids
 
+    def _note_publish(self, t0: float, *arrays: jax.Array) -> None:
+        """A publish is done: the whole padded table went up again
+        (Python loop over the lists + one upload). Keep what it placed
+        for `ivf_info` and leave an `ivf.publish` span: on the search
+        that paid for it when that one is profiled, else process-level
+        (ops/ivf.py note_phase), beside `engine.replace_raw`."""
+        rows = sum(len(mm) for mm in self._members)
+        slots = self.nlist * self._cap
+        table = {
+            "rows": rows, "nlist": self.nlist, "cap": self._cap,
+            "bytes": sum(int(a.nbytes) for a in arrays),
+            "fill": round(rows / slots, 6) if slots else 0.0,
+        }
+        t1 = time.monotonic()
+        self._published = {
+            **table, "seconds": round(t1 - t0, 3),
+            "publishes": (self._published or {}).get("publishes", 0) + 1}
+        ivf_ops.note_phase("ivf.publish", t0, t1, table)
+
+    def ivf_info(self) -> dict[str, Any] | None:
+        """The published bucket table (rows held, lists, slots a list,
+        device bytes, rows / slots), how long its publish took and how
+        many there have been; None until the first."""
+        return dict(self._published) if self._published else None
+
     def _valid_device(self, valid_mask, n: int) -> jax.Array:
         # pad to store capacity so the probe kernels keep a stable input
         # shape across ingest (capacity only changes on rare doublings)
@@ -354,24 +384,52 @@ class IVFFlatIndex(_IVFBase):
             self._bucket_vecs, self._bucket_sqnorm,
         )
 
-    def _publish(self) -> None:
-        # under the absorb lock: a concurrent absorb would grow _members
-        # between capacity sizing and the fill loop (found by the
-        # concurrency stress test)
+    def _bucket_shape(self) -> int:
+        """A list longer than one scan step's tile gets whole tiles
+        (ops/ivf.py `probe_tile`: the program then scans it tile by
+        tile and gathers no slice wider than the compiler keeps in one
+        piece)."""
+        cap = super()._bucket_shape()
+        tile = ivf_ops.probe_tile_rows(
+            self.store.dimension * self.store.store_dtype.itemsize)
+        return cap if cap <= tile else -(-cap // tile) * tile
+
+    def _publish(self) -> tuple[tuple, dict[str, Any]]:
+        """Publish if a publish is due, and return the table to scan,
+        (vecs, sqnorm, ids) of ONE generation, with what `_note_publish`
+        kept of it. Under the absorb lock: a concurrent absorb would
+        grow _members between capacity sizing and the fill loop (found
+        by the concurrency stress test), and a search that read the
+        three arrays one by one beside a publish could pair one
+        generation's ids with another's rows."""
         with self._absorb_lock:
-            ids = self._publish_ids()
-            cap = ids.shape[1]
-            d = self.store.dimension
-            host = self.store.host_view()
-            vecs = np.zeros((self.nlist, cap, d), dtype=np.float32)
-            for c, mm in enumerate(self._members):
-                if mm:
-                    vecs[c, : len(mm)] = self._maybe_normalize(
-                        host[np.asarray(mm, dtype=np.int64)]
-                    )
-            self._bucket_vecs = jnp.asarray(vecs, dtype=self.store.store_dtype)
-            self._bucket_sqnorm = sqnorms(self._bucket_vecs)
-            self._dirty = False
+            if self._dirty or self._bucket_vecs is None:
+                self._publish_locked()
+            return (self._bucket_vecs, self._bucket_sqnorm,
+                    self._bucket_ids), self._published
+
+    def _publish_locked(self) -> None:
+        t0 = time.monotonic()
+        # the old table goes first: every search that could scan it is
+        # waiting for this lock (it saw _dirty), and old and new side by
+        # side are twice the table on the device (2 x 4.4 GB of a
+        # chip's 16 at 1M x 128 under a cap of 8192)
+        self._bucket_vecs = self._bucket_sqnorm = self._bucket_ids = None
+        ids = self._publish_ids()
+        cap = ids.shape[1]
+        d = self.store.dimension
+        host = self.store.host_view()
+        vecs = np.zeros((self.nlist, cap, d), dtype=np.float32)
+        for c, mm in enumerate(self._members):
+            if mm:
+                vecs[c, : len(mm)] = self._maybe_normalize(
+                    host[np.asarray(mm, dtype=np.int64)]
+                )
+        self._bucket_vecs = jnp.asarray(vecs, dtype=self.store.store_dtype)
+        self._bucket_sqnorm = ivf_ops.bucket_sqnorms(self._bucket_vecs)
+        self._dirty = False
+        self._note_publish(t0, self._bucket_vecs, self._bucket_sqnorm,
+                           self._bucket_ids)
 
     def search(
         self,
@@ -381,10 +439,10 @@ class IVFFlatIndex(_IVFBase):
         params: dict | None = None,
     ) -> tuple[np.ndarray, np.ndarray]:
         assert self.trained, "IVFFLAT search before training"
-        if self._dirty or self._bucket_vecs is None:
-            self._publish()
+        t_probe = time.monotonic()
+        (bucket_vecs, bucket_sqnorm, bucket_ids), published = self._publish()
         nprobe = self._nprobe(params)
-        r = min(self._rerank_depth(k, params), self._cap * nprobe)
+        r = min(self._rerank_depth(k, params), published["cap"] * nprobe)
         q = self._maybe_normalize(np.asarray(queries, np.float32))
         metric = (
             MetricType.INNER_PRODUCT
@@ -393,13 +451,22 @@ class IVFFlatIndex(_IVFBase):
         )
         valid = self._valid_device(valid_mask, self.store.count)
         host_probes = self._host_probes(q, nprobe)
+        # the probe phase: from the index's entry to the launch (a
+        # publish if one was due, the mask, host probe selection), with
+        # what the program is about to scan: `nprobe` lists of `cap`
+        # slots a query, `fill` of them holding a row
+        ivf_ops.note_phase(
+            "ivf.probe", t_probe, time.monotonic(),
+            {"nprobe": nprobe, "cap": published["cap"],
+             "fill": published["fill"]},
+            request_only=True)
         ivf_ops.note_dispatch("ivfflat_scan")
         scores, ids = ivf_ops.ivfflat_candidates(
             jnp.asarray(q, dtype=self.store.store_dtype),
             self.centroids,
-            self._bucket_vecs,
-            self._bucket_sqnorm,
-            self._bucket_ids,
+            bucket_vecs,
+            bucket_sqnorm,
+            bucket_ids,
             valid,
             nprobe,
             min(max(r, k), 2048),
@@ -407,6 +474,7 @@ class IVFFlatIndex(_IVFBase):
             probes=None if host_probes is None
             else jnp.asarray(host_probes),
         )
+        ivf_ops.capture_launched()
         scores, ids = jax.device_get((scores, ids))
         # IVFFLAT scores are already exact — no rerank needed; cosine
         # similarity needs the query-norm correction only for reporting,
@@ -649,6 +717,7 @@ class IVFPQIndex(_IVFBase):
             self._publish_locked()
 
     def _publish_locked(self) -> None:
+        t0 = time.monotonic()
         ids = self._publish_ids()
         cap = ids.shape[1]
         d = self.store.dimension
@@ -685,6 +754,8 @@ class IVFPQIndex(_IVFBase):
         self._bucket_scale = jnp.asarray(scales)
         self._bucket_vsq = jnp.asarray(vsq)
         self._dirty = False
+        self._note_publish(t0, self._bucket_resid8, self._bucket_scale,
+                           self._bucket_vsq, self._bucket_ids)
 
     def reconstruction_error(self, sample: int = 256,
                              seed: int = 0) -> float | None:

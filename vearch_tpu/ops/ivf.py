@@ -203,14 +203,17 @@ def clear_phase_observer(fn) -> None:
 
 
 def note_phase(name: str, t0: float, t1: float,
-               tags: dict | None = None) -> None:
-    """Record a rare host-side window under its full span name: on the
+               tags: dict | None = None, request_only: bool = False) -> None:
+    """Record a host-side window under its full span name: on the
     current request's capture when there is one (the request that paid
-    for it; replayed under ps.search), else to the phase observer."""
+    for it; replayed under ps.search), else to the phase observer: a
+    rare one (a re-placement, a publish) then still leaves a
+    process-level span. `request_only` is for a window every search
+    has (the IVF probe phase): with no capture it is dropped."""
     cap = getattr(_capture_tls, "capture", None)
     if cap is not None:
         cap.phases.append((name, t0, t1, tags))
-    elif _phase_observer is not None:
+    elif _phase_observer is not None and not request_only:
         _phase_observer(name, t0, t1, tags)
 
 
@@ -275,6 +278,42 @@ def _fold_topk(
     return top_s, jnp.take_along_axis(i_cat, pos, axis=1)
 
 
+#: the widest slice one step of the IVFFLAT probe scan gathers per
+#: query. Up to 1 MiB (2048 rows of 128 f32) the TPU compiler keeps the
+#: step's [B, rows, d] gather as one operation and hands it to the
+#: product without a buffer in HBM; past it, it cuts the gather into
+#: column slices of the WHOLE [nlist, cap, d] table and copies each in
+#: every step (at cap 10368: 4.7 GB of temp beside a 5.4 GB table;
+#: tests/test_chip_compile.py holds the program to this)
+PROBE_SLICE_BYTES = 1 << 20
+
+
+def probe_tile(cap: int, row_bytes: int) -> int:
+    """Rows of a list that one scan step gathers: all `cap` of them
+    while they fit PROBE_SLICE_BYTES, else the largest divisor of `cap`
+    that does (a table published by index/ivf.py has `cap` rounded up
+    to a multiple of `probe_tile_rows`, so that is the divisor)."""
+    limit = max(PROBE_SLICE_BYTES // row_bytes, 1)
+    if cap <= limit:
+        return cap
+    return next(t for t in range(limit, 0, -1) if cap % t == 0)
+
+
+def probe_tile_rows(row_bytes: int) -> int:
+    """The tile a publish rounds a long list's `cap` up to: the most
+    rows inside PROBE_SLICE_BYTES, in whole 128-row blocks."""
+    return max(PROBE_SLICE_BYTES // row_bytes // 128 * 128, 128)
+
+
+@jax.jit
+def bucket_sqnorms(bucket_vecs: jax.Array) -> jax.Array:
+    """[nlist, cap] squared norms of a published [nlist, cap, d] table,
+    as one fused multiply-and-reduce: taken eagerly (`sqnorms`), the
+    squares are a second table-sized array on the device for the length
+    of the publish (8.7 GB at peak beside a 4.4 GB table)."""
+    return sqnorms(bucket_vecs)
+
+
 @functools.partial(jax.jit, static_argnames=("nprobe", "r", "metric"))
 def ivfflat_candidates(
     queries: jax.Array,      # [B, d] (store dtype)
@@ -291,44 +330,64 @@ def ivfflat_candidates(
     """Scan nprobe buckets per query; return top-r (scores, docids).
 
     `probes` overrides the in-kernel matmul selection — the HNSW coarse
-    quantizer computes them on host (quantizer_type=hnsw)."""
+    quantizer computes them on host (quantizer_type=hnsw).
+
+    A list longer than `probe_tile` rows is scanned in that many-row
+    tiles, one scan step each: the table is read as
+    [nlist * tiles, tile, d] (a view: `cap` is a multiple of the tile)
+    and a step gathers [B, tile, d]. Every slot of every probed list is
+    scored and folded either way; the stages carry `jax.named_scope`s
+    (`coarse`, `gather`, `score`, `fold`), as the full-scan programs'
+    do."""
     b = queries.shape[0]
     if probes is None:
-        probes = _coarse_probes(
-            queries.astype(jnp.float32), centroids, nprobe
-        )  # [B, nprobe]
+        with jax.named_scope("coarse"):
+            probes = _coarse_probes(
+                queries.astype(jnp.float32), centroids, nprobe
+            )  # [B, nprobe]
     nprobe = int(probes.shape[1])
     q_sq = sqnorms(queries)  # [B]
+    nlist, cap, d = bucket_vecs.shape
+    tile = probe_tile(cap, d * bucket_vecs.dtype.itemsize)
+    tiles = cap // tile
+    bucket_vecs = bucket_vecs.reshape(nlist * tiles, tile, d)
+    bucket_ids = bucket_ids.reshape(nlist * tiles, tile)
+    bucket_sqnorm = bucket_sqnorm.reshape(nlist * tiles, tile)
 
     init = (
         jnp.full((b, r), NEG_INF, jnp.float32),
         jnp.full((b, r), -1, jnp.int32),
     )
 
-    def step(best, pr):
-        c = probes[:, pr]  # [B]
-        # c == -1 marks a padded probe slot (host HNSW selection came up
-        # short): scan cell 0 for shape but mask every hit — scanning a
-        # real cell twice would DUPLICATE its docids in the top-k
-        cell_ok = c >= 0
-        c = jnp.maximum(c, 0)
-        vecs = bucket_vecs[c]  # [B, cap, d]
-        ids = bucket_ids[c]  # [B, cap]
-        vsq = bucket_sqnorm[c]  # [B, cap]
-        dots = jax.lax.dot_general(
-            queries, vecs, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-            precision=dot_precision(queries, vecs),
-        )  # [B, cap]
-        if metric is MetricType.L2:
-            scores = -(q_sq[:, None] - 2.0 * dots + vsq)
-        else:
-            scores = dots
-        ok = (ids >= 0) & valid[jnp.maximum(ids, 0)] & cell_ok[:, None]
-        scores = jnp.where(ok, scores, NEG_INF)
-        return _fold_topk(best, scores, ids), None
+    def step(best, s):
+        with jax.named_scope("gather"):
+            c = probes[:, s // tiles]  # [B]
+            # c == -1 marks a padded probe slot (host HNSW selection
+            # came up short): scan cell 0 for shape but mask every hit —
+            # scanning a real cell twice would DUPLICATE its docids in
+            # the top-k
+            cell_ok = c >= 0
+            c = jnp.maximum(c, 0) * tiles + s % tiles
+            vecs = bucket_vecs[c]  # [B, tile, d]
+            ids = bucket_ids[c]  # [B, tile]
+            vsq = bucket_sqnorm[c]  # [B, tile]
+        with jax.named_scope("score"):
+            dots = jax.lax.dot_general(
+                queries, vecs, (((1,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+                precision=dot_precision(queries, vecs),
+            )  # [B, tile]
+            if metric is MetricType.L2:
+                scores = -(q_sq[:, None] - 2.0 * dots + vsq)
+            else:
+                scores = dots
+            ok = (ids >= 0) & valid[jnp.maximum(ids, 0)] & cell_ok[:, None]
+            scores = jnp.where(ok, scores, NEG_INF)
+        with jax.named_scope("fold"):
+            return _fold_topk(best, scores, ids), None
 
-    (best_s, best_i), _ = jax.lax.scan(step, init, jnp.arange(nprobe))
+    (best_s, best_i), _ = jax.lax.scan(
+        step, init, jnp.arange(nprobe * tiles))
     # masked slots keep -inf scores; null their ids so rerank skips them
     return best_s, jnp.where(jnp.isfinite(best_s), best_i, -1)
 
