@@ -216,10 +216,10 @@ def test_xla_probe_scan_and_rerank_compile(one_chip, widths):
         widths["fetch_k"], L2).compile())
 
 
-def _flat_args(S, b, nlist, cap, n_valid):
+def _flat_args(S, b, nlist, cap):
     return (S((b, D), jnp.float32), S((nlist, D), jnp.float32),
             S((nlist, cap, D), jnp.float32), S((nlist, cap), jnp.float32),
-            S((nlist, cap), jnp.int32), S((n_valid,), jnp.bool_))
+            S((nlist, cap), jnp.int32), S((nlist, cap), jnp.bool_))
 
 
 # benchmark/configs/sift1m-ivfflat.json: nlist 1024, nprobe 32, r 256.
@@ -241,18 +241,39 @@ def test_ivfflat_probe_scan_compiles_in_tiles(one_chip, widths, b, cap):
     results of [1024, 1792, 128] f32 and 4.7 GB of temp beside a 5.4 GB
     table (PERF.md section 6, PR 32). `probe_tile` keeps every step's
     slice inside the 1 MiB, so temp stays under the step's own gather
-    plus the running top list whatever the longest list."""
+    plus the running top list whatever the longest list.
+
+    The validity mask arrives slot-major, `pred[nlist, cap]`, and a
+    step gathers it by list row as it gathers the ids (PR 33): the
+    module has no docid-indexed `pred[n_store]` operand, and no gather
+    of single mask elements out of a 1-d operand (the 131,072 lookups
+    a step that were 77 % of the program on the chip, PERF.md section
+    6)."""
     nlist, nprobe = 1024, 32
     tile = ivf_ops.probe_tile(cap, D * 4)
     assert tile == min(cap, 2048) == ivf_ops.probe_tile_rows(D * 4)
     compiled = ivf_ops.ivfflat_candidates.lower(
-        *_flat_args(_shapes(one_chip), b, nlist, cap, widths["n_store"]),
+        *_flat_args(_shapes(one_chip), b, nlist, cap),
         nprobe, RERANK, L2).compile()
     _, temp = _report(f"ivfflat_candidates[B={b},cap={cap}]", compiled)
     text = compiled.as_text()
     assert text.startswith("HloModule jit_ivfflat_candidates")
     for scope in ("coarse", "gather", "score", "fold"):
         assert f"/{scope}/" in text, scope
+    entry = text.splitlines()[0]  # the module's own operands
+    assert f"pred[{nlist},{cap}]" in entry
+    assert not re.search(r"pred\[\d+\]", entry), "a 1-d mask operand"
+    assert f"pred[{widths['n_store']}]" not in text
+    mask_gathers = [
+        (shape, operand) for shape, operand in re.findall(
+            r"= (pred\[[\d,]+\])\S* gather\(%?([\w.\-]+)", text)]
+    assert mask_gathers, "the mask is gathered somewhere"
+    for shape, operand in mask_gathers:
+        # by list row, out of the [nlist * tiles, tile] view
+        assert shape == f"pred[{b},{tile}]", (shape, operand)
+        (op_shape,) = set(re.findall(
+            rf"%?{re.escape(operand)} = (pred\[[\d,]+\])", text))
+        assert op_shape == f"pred[{nlist * cap // tile},{tile}]", op_shape
     step_gather = b * tile * D
     written = [
         (name, shape) for name, shape, op in re.findall(

@@ -498,13 +498,13 @@ def test_padded_probe_slots_never_duplicate_results():
     vecs = rng.standard_normal((nlist, cap, d)).astype(np.float32)
     ids = np.arange(nlist * cap, dtype=np.int32).reshape(nlist, cap)
     sqn = (vecs ** 2).sum(-1).astype(np.float32)
-    valid = np.ones(nlist * cap, dtype=bool)
+    ok = np.ones((nlist, cap), dtype=bool)  # slot-major, as the table
     q = rng.standard_normal((3, d)).astype(np.float32)
     # every row probes cell 2 once plus two padded slots
     probes = np.array([[2, -1, -1]] * 3, dtype=np.int32)
     scores, out = ivf_ops.ivfflat_candidates(
         jnp.asarray(q), jnp.asarray(cents), jnp.asarray(vecs),
-        jnp.asarray(sqn), jnp.asarray(ids), jnp.asarray(valid),
+        jnp.asarray(sqn), jnp.asarray(ids), jnp.asarray(ok),
         3, 16, MetricType.L2, probes=jnp.asarray(probes),
     )
     out = np.asarray(out)
@@ -513,6 +513,263 @@ def test_padded_probe_slots_never_duplicate_results():
         assert len(real) == len(set(real.tolist())), row
         # only cell 2's docids can appear
         assert all(16 <= i < 24 for i in real), row
+
+
+def _probe_table(rng, nlist, cap, d):
+    """A padded [nlist, cap, d] table as `IVFFlatIndex` publishes one:
+    lists of unequal length over shuffled docids, `-1` beyond a list's
+    end, and ROWS in the padding that would win if they were scored."""
+    lengths = rng.integers(cap // 4, cap + 1, nlist)
+    lengths[0] = cap  # one full list, as the longest always is
+    docids = rng.permutation(int(lengths.sum())).astype(np.int32)
+    ids = np.full((nlist, cap), -1, np.int32)
+    at = 0
+    for c, n in enumerate(lengths):
+        ids[c, :n] = docids[at:at + n]
+        at += n
+    cents = rng.standard_normal((nlist, d)).astype(np.float32) * 3
+    vecs = (cents[:, None, :]
+            + rng.standard_normal((nlist, cap, d)).astype(np.float32))
+    return cents, vecs, ids
+
+
+def _plain_probe_scan(q, cents, vecs, ids, ok, nprobe, r, metric, probes):
+    """ivfflat_candidates in plain numpy, float64: the nprobe lists
+    nearest each query (or the ones handed in, `-1` skipped), every
+    slot the mask lets through scored, the best r kept."""
+    q64, v64 = q.astype(np.float64), vecs.astype(np.float64)
+    if probes is None:
+        c64 = cents.astype(np.float64)
+        coarse = 2 * q64 @ c64.T - (c64 ** 2).sum(1)[None]
+        probes = np.argsort(-coarse, axis=1, kind="stable")[:, :nprobe]
+    out_i = np.full((q.shape[0], r), -1, np.int64)
+    out_s = np.full((q.shape[0], r), -np.inf)
+    for b in range(q.shape[0]):
+        lists = [c for c in probes[b] if c >= 0]
+        cand_i = np.concatenate([ids[c][ok[c]] for c in lists])
+        cand_v = np.concatenate([v64[c][ok[c]] for c in lists])
+        if metric is MetricType.L2:
+            s = -((q64[b][None] - cand_v) ** 2).sum(1)
+        else:
+            s = cand_v @ q64[b]
+        order = np.argsort(-s, kind="stable")[:r]
+        out_i[b, :order.size] = cand_i[order]
+        out_s[b, :order.size] = s[order]
+    return out_s, out_i
+
+
+@pytest.mark.parametrize("case", [
+    "all_alive", "mask_60pct", "mask_2pct", "all_false", "several_tiles",
+    "padded_probe_slots", "inner_product"])
+def test_probe_scan_under_the_slot_major_mask_is_the_plain_reference(
+        case, monkeypatch):
+    """`ivfflat_candidates` takes its validity mask in the table's own
+    order, `[nlist, cap]`, true where a slot holds a row that is alive
+    and passes the filter: the ids and scores are the plain numpy
+    reference's whatever share of the slots the mask lets through,
+    however many tiles a list is scanned in, with `-1` probe slots and
+    under inner product. The padding holds rows (`_probe_table`): a
+    slot scored where the mask says false would be served."""
+    import jax.numpy as jnp
+
+    from vearch_tpu.ops import ivf as ivf_ops
+
+    rng = np.random.default_rng(33)
+    nlist, d, b, nprobe, r = 8, 16, 5, 3, 16
+    cap = 48 if case == "several_tiles" else 32
+    cents, vecs, ids = _probe_table(rng, nlist, cap, d)
+    n = int(ids.max()) + 1
+    share = {"mask_60pct": 0.6, "mask_2pct": 0.02, "all_false": 0.0}
+    valid = rng.random(n) < share.get(case, 1.0)
+    # what the index builds: one scatter over rows, padding stays false
+    ok = (ids >= 0) & valid[np.maximum(ids, 0)]
+    assert ok.sum() == valid.sum()
+    metric = (MetricType.INNER_PRODUCT if case == "inner_product"
+              else MetricType.L2)
+    q = (cents[rng.integers(0, nlist, b)]
+         + rng.standard_normal((b, d)).astype(np.float32))
+    probes = None
+    if case == "padded_probe_slots":
+        probes = np.array([[2, -1, 5], [-1, -1, 0], [7, 1, -1],
+                           [0, -1, -1], [3, 4, 6]], np.int32)
+    if case == "several_tiles":
+        monkeypatch.setattr(ivf_ops, "PROBE_SLICE_BYTES", 16 * d * 4)
+        assert ivf_ops.probe_tile(cap, d * 4) == 16  # three steps a list
+    scores, out = ivf_ops.ivfflat_candidates(
+        jnp.asarray(q), jnp.asarray(cents), jnp.asarray(vecs),
+        jnp.asarray((vecs ** 2).sum(-1)), jnp.asarray(ids),
+        jnp.asarray(ok), nprobe, r, metric,
+        probes=None if probes is None else jnp.asarray(probes))
+    want_s, want_i = _plain_probe_scan(q, cents, vecs, ids, ok, nprobe, r,
+                                       metric, probes)
+    assert (np.asarray(out) == want_i).all()
+    got_s = np.asarray(scores, np.float64)
+    found = want_i >= 0
+    assert (got_s[~found] == -np.inf).all()
+    np.testing.assert_allclose(got_s[found], want_s[found], rtol=2e-5,
+                               atol=2e-4)
+    if case == "all_false":
+        assert not found.any()
+    elif case == "mask_2pct":  # fewer rows pass than the depth asks for
+        assert found.any() and not found.all()
+    else:
+        assert found.all()
+
+
+# -- the slot-major mask's cache and the guarantees behind it -----------------
+
+
+def _flat_engine(rng, rows=3000, nlist=16):
+    """An IVFFLAT engine with a scalar column, probing EVERY list: its
+    answers are exact, so brute force over the rows a mask lets through
+    is what each search must return."""
+    schema = TableSchema(
+        name="flatmask",
+        fields=[
+            FieldSchema("price", DataType.FLOAT),
+            FieldSchema("emb", DataType.VECTOR, dimension=D,
+                        index=IndexParams("IVFFLAT", MetricType.L2, {
+                            "ncentroids": nlist, "nprobe": nlist,
+                            "training_threshold": 1000})),
+        ],
+    )
+    eng = Engine(schema)
+    vecs = clustered_data(rng, n=rows)
+    price = (np.arange(rows) % 50).astype(np.float32)
+    eng.upsert([{"_id": f"d{i}", "emb": vecs[i], "price": float(price[i])}
+                for i in range(rows)])
+    eng.wait_for_index()
+    eng.build_index()
+    eng.search(SearchRequest(vectors={"emb": vecs[:1]}, k=1))  # publishes
+    return eng, vecs, price
+
+
+def _keys(res):
+    return [[it.key for it in r.items] for r in res]
+
+
+def _brute_keys(vecs, queries, allowed, k):
+    rows = np.flatnonzero(allowed)
+    d = ((queries[:, None].astype(np.float64)
+          - vecs[rows][None].astype(np.float64)) ** 2).sum(-1)
+    return [[f"d{rows[j]}" for j in np.argsort(row, kind="stable")[:k]]
+            for row in d]
+
+
+def _mask_counts(eng):
+    info = eng.indexes["emb"].ivf_info()
+    return info["mask_builds"], info["mask_hits"], info["publishes"]
+
+
+def _price_range(lo, hi):
+    return {"operator": "AND", "conditions": [
+        {"field": "price", "operator": ">=", "value": lo},
+        {"field": "price", "operator": "<", "value": hi}]}
+
+
+def test_same_mask_twice_is_one_build_and_one_hit(rng):
+    eng, vecs, _ = _flat_engine(rng)
+    q = vecs[:8]
+    eng.search(SearchRequest(vectors={"emb": q}, k=5))
+    builds, hits, publishes = _mask_counts(eng)
+    assert builds >= 1
+    first = _keys(eng.search(SearchRequest(vectors={"emb": q}, k=5)))
+    again = _keys(eng.search(SearchRequest(vectors={"emb": q}, k=5)))
+    # the engine handed back the same alive mask: found again, twice
+    assert _mask_counts(eng) == (builds, hits + 2, publishes)
+    assert first == again == _brute_keys(vecs, q, np.ones(len(vecs), bool), 5)
+    idx = eng.indexes["emb"]
+    src, n, ok = idx._mask_entry
+    assert src is eng._device_alive_mask(eng.table.doc_count)
+    assert ok.shape == (idx.nlist, idx._cap) and ok.dtype == np.bool_
+    assert int(np.asarray(ok).sum()) == n == len(vecs)
+
+
+def test_a_deleted_row_is_gone_from_the_very_next_search(rng):
+    eng, vecs, _ = _flat_engine(rng)
+    q = vecs[40:44]
+    assert [r[0] for r in _keys(eng.search(
+        SearchRequest(vectors={"emb": q}, k=3)))] == [
+            "d40", "d41", "d42", "d43"]
+    builds, hits, publishes = _mask_counts(eng)
+    eng.delete(["d41", "d43"])
+    alive = np.ones(len(vecs), bool)
+    alive[[41, 43]] = False
+    got = _keys(eng.search(SearchRequest(vectors={"emb": q}, k=3)))
+    assert got == _brute_keys(vecs, q, alive, 3)
+    # a new alive mask, the same table: one more mask, no publish
+    assert _mask_counts(eng) == (builds + 1, hits, publishes)
+
+
+def test_an_appended_row_is_found_under_a_new_tables_mask(rng):
+    eng, vecs, _ = _flat_engine(rng)
+    eng.search(SearchRequest(vectors={"emb": vecs[:2]}, k=3))
+    builds, hits, publishes = _mask_counts(eng)
+    old = eng.indexes["emb"]._mask_entry
+    new = (vecs[7] + 9.0).astype(np.float32)
+    eng.upsert([{"_id": "fresh", "emb": new, "price": 1.0}])
+    res = eng.search(SearchRequest(vectors={"emb": new[None]}, k=3))
+    assert res[0].items[0].key == "fresh"
+    # the publish dropped the old table's mask; the new one's was built
+    assert _mask_counts(eng) == (builds + 1, hits, publishes + 1)
+    idx = eng.indexes["emb"]
+    assert idx._mask_entry is not old and idx._mask_entry[1] == len(vecs) + 1
+    assert idx._slot_of.shape == (len(vecs) + 1,)
+    flat = np.asarray(idx._bucket_ids).reshape(-1)
+    assert (flat[idx._slot_of] == np.arange(len(vecs) + 1)).all()
+
+
+@pytest.mark.parametrize("lo,hi", [(10.0, 40.0), (7.0, 8.0)])
+def test_filtered_search_is_brute_force_fresh_and_repeated(rng, lo, hi):
+    """60 % and 2 % of the rows pass. A fresh filter is a new host mask:
+    one build; the same filter again is the engine's own cached array:
+    a hit. Deleted rows stay out of both."""
+    eng, vecs, price = _flat_engine(rng)
+    eng.delete(["d10", "d57"])
+    allowed = (price >= lo) & (price < hi)
+    allowed[[10, 57]] = False
+    q = vecs[[10, 57, 300, 301, 302]]
+    want = _brute_keys(vecs, q, allowed, 10)
+    req = lambda: SearchRequest(vectors={"emb": q}, k=10,  # noqa: E731
+                                filters=_price_range(lo, hi))
+    builds, hits, _ = _mask_counts(eng)
+    assert _keys(eng.search(req())) == want
+    assert _mask_counts(eng)[:2] == (builds + 1, hits)
+    assert _keys(eng.search(req())) == want
+    assert _mask_counts(eng)[:2] == (builds + 1, hits + 1)
+    # another filter between two uses of one: ONE entry, so a rebuild
+    eng.search(SearchRequest(vectors={"emb": q}, k=10,
+                             filters=_price_range(0.0, 5.0)))
+    assert _keys(eng.search(req())) == want
+    assert _mask_counts(eng)[:2] == (builds + 3, hits + 1)
+
+
+@pytest.mark.parametrize("mask", ["host", "device", "host_short"])
+def test_a_row_absorbed_after_the_masks_n_is_never_served(rng, mask):
+    """The engine takes its mask at `n` rows, then lets the index absorb
+    what a concurrent writer added since: the table may hold rows the
+    mask has never heard of. They stay masked, as `to_device_mask`'s
+    padding kept them."""
+    import jax.numpy as jnp
+
+    eng, vecs, _ = _flat_engine(rng)
+    n = eng.table.doc_count
+    valid = {"host": np.ones(n, bool), "device": jnp.ones(n, bool),
+             "host_short": np.ones(n - 5, bool)}[mask]
+    late = (vecs[3] + 7.0).astype(np.float32)
+    eng.upsert([{"_id": "late", "emb": late, "price": 0.0}])
+    idx = eng.indexes["emb"]
+    idx.absorb(eng.vector_stores["emb"].count)
+    _, ids = idx.search(np.stack([late, vecs[n - 1]]), 5, valid)
+    assert idx._slot_of.shape == (n + 1,)  # the table holds the row
+    assert n not in ids.tolist()[0] and (ids >= 0).all()
+    if mask == "host_short":  # rows past the mask's own end too
+        assert (ids < n - 5).all()
+    else:
+        assert ids[1, 0] == n - 1
+    # under a mask that covers it, it is the nearest
+    _, ids = idx.search(late[None], 5, None)
+    assert ids[0, 0] == n
 
 
 @pytest.mark.parametrize("rows", [2500, 3000, 700])

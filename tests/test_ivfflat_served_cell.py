@@ -296,12 +296,21 @@ def test_a_padded_probe_slot_never_duplicates_a_docid(world, monkeypatch):
     assert (ids == want).all()
 
 
+def _ivf_gauges(w) -> dict:
+    """`vearch_ps_ivf_publish{stat}` off the PS's /metrics, by stat."""
+    import urllib.request
+
+    with urllib.request.urlopen(f"http://{w.ps.addr}/metrics") as r:
+        text = r.read().decode()
+    return {line.split('stat="')[1].split('"')[0]:
+            float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
+            if line.startswith("vearch_ps_ivf_publish{")}
+
+
 def test_an_appended_row_is_found_after_the_publish_it_forces(world):
     """One written row sets the index dirty; the next search absorbs it
     and re-publishes the whole table before it scans. The publish is a
     span with what it placed, and `/ps/stats` and `/metrics` count it."""
-    import urllib.request
-
     from vearch_tpu.cluster import tracing
 
     w = world
@@ -327,13 +336,10 @@ def test_an_appended_row_is_found_after_the_publish_it_forces(world):
     probe = [s for s in tracing.snapshot() if s.name == "ivf.probe"
              and s.trace_id == publish.trace_id][-1]
     assert probe.t0_ns <= publish.t0_ns and publish.t1_ns <= probe.t1_ns
-    with urllib.request.urlopen(f"http://{w.ps.addr}/metrics") as r:
-        text = r.read().decode()
-    gauges = {line.split('stat="')[1].split('"')[0]:
-              float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
-              if line.startswith("vearch_ps_ivf_publish{")}
+    gauges = _ivf_gauges(w)
     assert sorted(gauges) == sorted(
-        ["publishes", "rows", "nlist", "cap", "bytes", "fill", "seconds"])
+        ["publishes", "rows", "nlist", "cap", "bytes", "fill", "seconds",
+         "mask_builds", "mask_hits"])
     assert gauges["rows"] == ROWS + 1 and gauges["cap"] == after["cap"]
     assert gauges["fill"] == pytest.approx(after["fill"], abs=1e-6)
     assert w.client.delete(corpus.DB, w.space,
@@ -341,6 +347,45 @@ def test_an_appended_row_is_found_after_the_publish_it_forces(world):
     docs, _ = w.search(vec)
     assert all(h["_id"] != "bench_new" for h in docs[0])
     assert w.ivf_stats()["publishes"] == after["publishes"]  # a mask
+
+
+def _probe_span(trace_id=None):
+    from vearch_tpu.cluster import tracing
+
+    return [s for s in tracing.snapshot() if s.name == "ivf.probe"
+            and trace_id in (None, s.trace_id)][-1]
+
+
+def test_the_same_mask_twice_is_one_build_and_one_hit(world):
+    """The slot-major validity mask is built once per (published table,
+    mask) and found again while the engine hands back the same mask: a
+    delete makes the next search build one, the search after it hits.
+    `/ps/stats`, the `ivf.probe` span and the gauge say which."""
+    w = world
+    q_idx = np.arange(B)
+    ids, _ = w.served(q_idx)
+    victim = int(ids[0, 0])
+    assert w.client.delete(corpus.DB, w.space,
+                           document_ids=[f"doc{victim}"]) == 1
+    before = w.ivf_stats()
+    ids, _ = w.served(q_idx)
+    assert victim not in ids
+    built = _probe_span()
+    ids, _ = w.served(q_idx)
+    assert victim not in ids
+    hit = _probe_span()
+    after = w.ivf_stats()
+    assert built.tags["mask"] == "built" and hit.tags["mask"] == "hit"
+    assert built.trace_id != hit.trace_id
+    assert 0 <= hit.tags["mask_ms"] <= built.tags["mask_ms"]
+    assert (after["mask_builds"], after["mask_hits"], after["publishes"]) == (
+        before["mask_builds"] + 1, before["mask_hits"] + 1,
+        before["publishes"])
+    gauges = _ivf_gauges(w)
+    assert (gauges["mask_builds"], gauges["mask_hits"]) == (
+        after["mask_builds"], after["mask_hits"])
+    w.live = getattr(w, "live", np.ones(ROWS, bool)).copy()
+    w.live[victim] = False
 
 
 def test_the_dispatch_span_carries_its_launch_and_the_probe_phase_its_tags(
@@ -359,6 +404,8 @@ def test_the_dispatch_span_carries_its_launch_and_the_probe_phase_its_tags(
     assert probe.tags["nprobe"] == w.params["nprobe"] == 32
     assert probe.tags["cap"] == stats["cap"]
     assert probe.tags["fill"] == stats["fill"]
+    assert probe.tags["mask"] in ("hit", "built")
+    assert probe.tags["mask_ms"] * 1e6 <= probe.t1_ns - probe.t0_ns
     assert probe.t1_ns <= kernel.t0_ns + 1_000_000
     # the table was scanned in tiles: cap / 128 steps a probed list
     assert stats["cap"] > TILE_ROWS

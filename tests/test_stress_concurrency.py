@@ -114,6 +114,119 @@ def test_concurrent_upsert_search_delete(rng):
     eng.close()
 
 
+def test_ivfflat_mask_and_table_are_of_one_generation(rng, monkeypatch):
+    """Four searching threads beside a writer and a deleter: every
+    dispatch scans (rows, norms, ids, slot-major mask) of ONE published
+    table. The writer forces a publish a batch (a new table, often a
+    new `cap`), the deleter a new mask on the table that stands; a
+    search that paired one generation's mask with another's ids would
+    let padding through, or hide a row that is alive. Checked on every
+    dispatch, on what the program was handed, and on every answer."""
+    from vearch_tpu.ops import ivf as ivf_ops
+
+    schema = TableSchema(
+        "stress_mask",
+        fields=[FieldSchema("v", DataType.VECTOR, dimension=D,
+                            index=IndexParams("IVFFLAT", MetricType.L2,
+                                              {"ncentroids": 8, "nprobe": 8,
+                                               "training_threshold": 300}))],
+        refresh_interval_ms=30,
+    )
+    eng = Engine(schema)
+    # the four threads INSIDE the index at once, as a PS's handler
+    # threads are with 64-row requests: the scheduler's one dispatcher
+    # thread would run them one after another
+    eng.micro_batch = False
+    eng.start_refresh_loop()
+    vecs = rng.standard_normal((3000, D)).astype(np.float32)
+    eng.upsert([{"_id": f"seed{i}", "v": vecs[i]} for i in range(400)])
+    eng.wait_for_index(timeout=120)
+    stable = np.arange(100, 400)  # docids nobody deletes
+
+    errors: list[Exception] = []
+    dispatches = []
+    scan = ivf_ops.ivfflat_candidates
+
+    def checked_scan(q, cents, bucket_vecs, sqnorm, bucket_ids, bucket_ok,
+                     *args, **kw):
+        try:
+            ids, ok = np.asarray(bucket_ids), np.asarray(bucket_ok)
+            assert ids.shape == ok.shape == bucket_vecs.shape[:2]
+            assert not ok[ids < 0].any(), "padding let through"
+            held = np.isin(ids, stable)
+            assert held.sum() == stable.size and ok[held].all(), (
+                "an alive row masked")
+            dispatches.append(ids.shape[1])
+        except Exception as e:
+            errors.append(e)
+        return scan(q, cents, bucket_vecs, sqnorm, bucket_ids, bucket_ok,
+                    *args, **kw)
+
+    monkeypatch.setattr(ivf_ops, "ivfflat_candidates", checked_scan)
+    stop = threading.Event()
+
+    def paced(steps):
+        """Each step after the searchers got two more dispatches in (a
+        first search compiles for seconds: unpaced, the writer is done
+        before it returns)."""
+        for step in steps:
+            seen = len(dispatches)
+            step()
+            for _ in range(500):
+                if len(dispatches) >= seen + 2 or errors:
+                    break
+                stop.wait(0.02)
+
+    def writer():
+        try:
+            paced(lambda base=base: eng.upsert(
+                [{"_id": f"w{base + i}", "v": vecs[base + i]}
+                 for i in range(100)]) for base in range(400, 1600, 100))
+        except Exception as e:
+            errors.append(e)
+
+    def deleter():
+        try:
+            paced(lambda lo=lo: eng.delete(
+                [f"seed{i}" for i in range(lo, lo + 5)])
+                for lo in range(0, 100, 5))
+        except Exception as e:
+            errors.append(e)
+
+    def searcher(tid: int):
+        try:
+            mine = stable[tid::4][:8]
+            while not stop.is_set():
+                res = eng.search(SearchRequest(vectors={"v": vecs[mine]}, k=3))
+                # every list is probed: a stored, alive row is its own
+                # nearest neighbour in every answer
+                assert [r.items[0].key for r in res] == [
+                    f"seed{i}" for i in mine]
+        except Exception as e:
+            errors.append(e)
+
+    threads = [threading.Thread(target=writer),
+               threading.Thread(target=deleter)]
+    threads += [threading.Thread(target=searcher, args=(t,))
+                for t in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads[:2]:
+        t.join(timeout=180)
+    stop.set()
+    for t in threads[2:]:
+        t.join(timeout=60)
+    assert not errors, errors[:3]
+    assert dispatches
+    info = eng.indexes["v"].ivf_info()
+    assert info["publishes"] >= 2 and info["mask_builds"] >= info["publishes"]
+    # what the writers and the deleter left is what a search now sees
+    res = eng.search(SearchRequest(vectors={"v": vecs[[0, 99, 1599]]}, k=1))
+    assert [r.items[0].key for r in res][2] == "w1599"
+    assert not {"seed0", "seed99"} & {r.items[0].key for r in res}
+    eng.close()
+
+
 def test_cluster_stress_under_lockcheck(tmp_path, rng):
     """The same class of stress, but against the replicated cluster
     layer with VEARCH_LOCKCHECK enabled: every ps/raft/wal/querycache

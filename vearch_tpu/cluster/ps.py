@@ -432,7 +432,8 @@ class PSServer:
             # the published IVF bucket tables of this node, every stat
             # rendered from the first scrape (a fixed universe: the
             # cardinality soak sees no series appear with a publish)
-            summed = ("publishes", "rows", "nlist", "bytes", "seconds")
+            summed = ("publishes", "rows", "nlist", "bytes", "seconds",
+                      "mask_builds", "mask_hits")
             out = dict.fromkeys(summed + ("cap", "fill"), 0.0)
             slots = 0
             for eng in list(self.engines.values()):

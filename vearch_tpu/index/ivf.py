@@ -16,7 +16,9 @@ TPU wants static-shaped dense arrays:
   holds the raw rows, lets the old generation go before it places the
   new one and hands a search one generation's arrays under the lock);
 - deletes never touch the index: the engine's validity mask is applied
-  in-kernel per slot.
+  in-kernel per slot (IVFFLAT hands its program the mask in the table's
+  own slot-major order, built once per (published table, mask) by one
+  scatter over the rows: `IVFFlatIndex._bucket_ok`).
 
 Search: ops/ivf.py scan kernels + exact rerank against the raw device
 buffer. Rerank depth `rerank` (default 4*k, min 64… capped by candidates)
@@ -99,6 +101,10 @@ class _IVFBase(VectorIndex):
         self._cap = 0
         #: the last publish, as `ivf_info` reports it (None before one)
         self._published: dict[str, Any] | None = None
+        #: slot-major validity masks built / found again (IVFFLAT's
+        #: `_bucket_ok`; an index that looks its mask up by docid keeps 0)
+        self._mask_builds = 0
+        self._mask_hits = 0
 
     def _device_state_arrays(self) -> tuple:
         """Device tensors this index keeps resident beyond the raw store
@@ -301,8 +307,12 @@ class _IVFBase(VectorIndex):
     def ivf_info(self) -> dict[str, Any] | None:
         """The published bucket table (rows held, lists, slots a list,
         device bytes, rows / slots), how long its publish took and how
-        many there have been; None until the first."""
-        return dict(self._published) if self._published else None
+        many there have been, and how many slot-major validity masks
+        were built for it and found again; None until the first."""
+        if not self._published:
+            return None
+        return {**self._published, "mask_builds": self._mask_builds,
+                "mask_hits": self._mask_hits}
 
     def _valid_device(self, valid_mask, n: int) -> jax.Array:
         # pad to store capacity so the probe kernels keep a stable input
@@ -378,10 +388,18 @@ class IVFFlatIndex(_IVFBase):
         super().__init__(params, store)
         self._bucket_vecs: jax.Array | None = None
         self._bucket_sqnorm: jax.Array | None = None
+        #: [rows held] the flat slot `c * cap + j` of every row of the
+        #: published table (a row it does not hold: the spare slot
+        #: `nlist * cap`), kept on the host beside it
+        self._slot_of: np.ndarray | None = None
+        #: (source mask, n, [nlist, cap] device mask) of the published
+        #: table: ONE entry, dropped with the table
+        self._mask_entry: tuple[Any, int, jax.Array] | None = None
 
     def _device_state_arrays(self) -> tuple:
         return super()._device_state_arrays() + (
             self._bucket_vecs, self._bucket_sqnorm,
+            self._mask_entry[2] if self._mask_entry else None,
         )
 
     def _bucket_shape(self) -> int:
@@ -394,29 +412,80 @@ class IVFFlatIndex(_IVFBase):
             self.store.dimension * self.store.store_dtype.itemsize)
         return cap if cap <= tile else -(-cap // tile) * tile
 
-    def _publish(self) -> tuple[tuple, dict[str, Any]]:
+    def _publish(self, valid_mask, n: int) -> tuple[tuple, dict[str, Any]]:
         """Publish if a publish is due, and return the table to scan,
-        (vecs, sqnorm, ids) of ONE generation, with what `_note_publish`
-        kept of it. Under the absorb lock: a concurrent absorb would
+        (vecs, sqnorm, ids, ok) of ONE generation, with what
+        `_note_publish` kept of it and the `mask` / `mask_ms` tags of
+        `_bucket_ok`. Under the absorb lock: a concurrent absorb would
         grow _members between capacity sizing and the fill loop (found
         by the concurrency stress test), and a search that read the
-        three arrays one by one beside a publish could pair one
-        generation's ids with another's rows."""
+        arrays one by one beside a publish could pair one generation's
+        ids, or its mask, with another's rows."""
         with self._absorb_lock:
             if self._dirty or self._bucket_vecs is None:
                 self._publish_locked()
+            t0 = time.monotonic()
+            ok, how = self._bucket_ok(valid_mask, n)
+            tags = {**self._published, "mask": how,
+                    "mask_ms": round((time.monotonic() - t0) * 1e3, 3)}
             return (self._bucket_vecs, self._bucket_sqnorm,
-                    self._bucket_ids), self._published
+                    self._bucket_ids, ok), tags
+
+    def _bucket_ok(self, valid_mask, n: int) -> tuple[jax.Array, str]:
+        """The validity mask in the published table's slot-major order,
+        `[nlist, cap]` bool on the device: true where the slot holds a
+        row below `n` that `valid_mask` (the engine's device-resident
+        alive mask, a host filter mask, or None: all alive) lets
+        through; padding, and a row absorbed after the mask was taken,
+        stay false.
+
+        Kept per (published table, source mask, n), one entry, by
+        identity with a strong reference to the source (a live
+        object's id cannot be reused; `_mesh_valid_sharded`'s rule):
+        the engine hands back the SAME alive mask until a delete or a
+        write, and the same filter mask on a filter-cache hit. A miss
+        is one scatter over ROWS on the host (1M rows into 8.4M slots:
+        about 10 ms) and one upload, where the program's per-slot
+        lookup `valid[ids]` cost 135 ms of every dispatch on the chip
+        (PERF.md section 6, PR 33). Call under the absorb lock."""
+        entry = self._mask_entry
+        if entry is not None and entry[0] is valid_mask and entry[1] == n:
+            self._mask_hits += 1
+            return entry[2], "hit"
+        slot_of = self._slot_of
+        slots = self.nlist * self._cap
+        ok = np.zeros(slots + 1, dtype=np.bool_)
+        m = min(n, slot_of.shape[0])
+        if valid_mask is None:
+            ok[slot_of[:m]] = True
+        else:
+            # a device-resident mask comes down once per build
+            v = np.asarray(valid_mask)[:m]
+            ok[slot_of[:v.shape[0]]] = v
+        # the spare slot took the rows the table does not hold
+        dev = jnp.asarray(ok[:slots].reshape(self.nlist, self._cap))
+        # the old entry (and its 1 byte a slot on the device) goes as
+        # the new one is kept
+        self._mask_entry = (valid_mask, n, dev)
+        self._mask_builds += 1
+        return dev, "built"
 
     def _publish_locked(self) -> None:
         t0 = time.monotonic()
-        # the old table goes first: every search that could scan it is
-        # waiting for this lock (it saw _dirty), and old and new side by
-        # side are twice the table on the device (2 x 4.4 GB of a
-        # chip's 16 at 1M x 128 under a cap of 8192)
+        # the old table goes first, its mask with it: every search that
+        # could scan it is waiting for this lock (it saw _dirty), and
+        # old and new side by side are twice the table on the device
+        # (2 x 4.4 GB of a chip's 16 at 1M x 128 under a cap of 8192)
         self._bucket_vecs = self._bucket_sqnorm = self._bucket_ids = None
+        self._mask_entry = self._slot_of = None
         ids = self._publish_ids()
         cap = ids.shape[1]
+        # where each row sits: one pass over the host ids just built
+        flat = ids.reshape(-1)
+        held = np.flatnonzero(flat >= 0)
+        slot_of = np.full(self.indexed_count, flat.shape[0], dtype=np.int32)
+        slot_of[flat[held]] = held
+        self._slot_of = slot_of
         d = self.store.dimension
         host = self.store.host_view()
         vecs = np.zeros((self.nlist, cap, d), dtype=np.float32)
@@ -440,7 +509,8 @@ class IVFFlatIndex(_IVFBase):
     ) -> tuple[np.ndarray, np.ndarray]:
         assert self.trained, "IVFFLAT search before training"
         t_probe = time.monotonic()
-        (bucket_vecs, bucket_sqnorm, bucket_ids), published = self._publish()
+        (bucket_vecs, bucket_sqnorm, bucket_ids, bucket_ok), published = (
+            self._publish(valid_mask, self.store.count))
         nprobe = self._nprobe(params)
         r = min(self._rerank_depth(k, params), published["cap"] * nprobe)
         q = self._maybe_normalize(np.asarray(queries, np.float32))
@@ -449,16 +519,17 @@ class IVFFlatIndex(_IVFBase):
             if self.metric is MetricType.COSINE
             else self.metric
         )
-        valid = self._valid_device(valid_mask, self.store.count)
         host_probes = self._host_probes(q, nprobe)
         # the probe phase: from the index's entry to the launch (a
         # publish if one was due, the mask, host probe selection), with
         # what the program is about to scan: `nprobe` lists of `cap`
-        # slots a query, `fill` of them holding a row
+        # slots a query, `fill` of them holding a row, under a slot-
+        # major mask that was found again (`hit`) or `built`
         ivf_ops.note_phase(
             "ivf.probe", t_probe, time.monotonic(),
             {"nprobe": nprobe, "cap": published["cap"],
-             "fill": published["fill"]},
+             "fill": published["fill"], "mask": published["mask"],
+             "mask_ms": published["mask_ms"]},
             request_only=True)
         ivf_ops.note_dispatch("ivfflat_scan")
         scores, ids = ivf_ops.ivfflat_candidates(
@@ -467,7 +538,7 @@ class IVFFlatIndex(_IVFBase):
             bucket_vecs,
             bucket_sqnorm,
             bucket_ids,
-            valid,
+            bucket_ok,
             nprobe,
             min(max(r, k), 2048),
             metric,

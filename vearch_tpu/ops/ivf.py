@@ -321,7 +321,7 @@ def ivfflat_candidates(
     bucket_vecs: jax.Array,  # [nlist, cap, d] store dtype
     bucket_sqnorm: jax.Array,  # [nlist, cap] f32
     bucket_ids: jax.Array,   # [nlist, cap] i32
-    valid: jax.Array,        # [n_pad] bool (docid-indexed)
+    bucket_ok: jax.Array,    # [nlist, cap] bool (slot-major, as the table)
     nprobe: int,
     r: int,
     metric: MetricType = MetricType.L2,
@@ -331,6 +331,13 @@ def ivfflat_candidates(
 
     `probes` overrides the in-kernel matmul selection — the HNSW coarse
     quantizer computes them on host (quantizer_type=hnsw).
+
+    `bucket_ok` is the validity mask in the table's own order: true
+    where the slot holds a row that is alive and passes the request's
+    filter, false on padding. A step reads it as it reads `bucket_ids`,
+    one row a query: looked up by docid (`valid[ids]`) the same 131,072
+    elements a step were 77 % of the program on the chip (PERF.md
+    section 6, PR 33).
 
     A list longer than `probe_tile` rows is scanned in that many-row
     tiles, one scan step each: the table is read as
@@ -353,6 +360,7 @@ def ivfflat_candidates(
     bucket_vecs = bucket_vecs.reshape(nlist * tiles, tile, d)
     bucket_ids = bucket_ids.reshape(nlist * tiles, tile)
     bucket_sqnorm = bucket_sqnorm.reshape(nlist * tiles, tile)
+    bucket_ok = bucket_ok.reshape(nlist * tiles, tile)
 
     init = (
         jnp.full((b, r), NEG_INF, jnp.float32),
@@ -371,6 +379,7 @@ def ivfflat_candidates(
             vecs = bucket_vecs[c]  # [B, tile, d]
             ids = bucket_ids[c]  # [B, tile]
             vsq = bucket_sqnorm[c]  # [B, tile]
+            ok = bucket_ok[c] & cell_ok[:, None]  # [B, tile]
         with jax.named_scope("score"):
             dots = jax.lax.dot_general(
                 queries, vecs, (((1,), (2,)), ((0,), (0,))),
@@ -381,7 +390,6 @@ def ivfflat_candidates(
                 scores = -(q_sq[:, None] - 2.0 * dots + vsq)
             else:
                 scores = dots
-            ok = (ids >= 0) & valid[jnp.maximum(ids, 0)] & cell_ok[:, None]
             scores = jnp.where(ok, scores, NEG_INF)
         with jax.named_scope("fold"):
             return _fold_topk(best, scores, ids), None
