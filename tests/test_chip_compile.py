@@ -31,6 +31,7 @@ from vearch_tpu.engine.types import IndexParams, MetricType
 from vearch_tpu.index.int8_mirror import Int8Mirror
 from vearch_tpu.index import ivf as ivf_index
 from vearch_tpu.index.ivf import IVFPQIndex
+from vearch_tpu.ops import binary_scan as binary_ops
 from vearch_tpu.ops import ivf as ivf_ops
 from vearch_tpu.ops import kmeans as km
 from vearch_tpu.ops import pallas_kernels, perf_model
@@ -288,6 +289,112 @@ def test_ivfflat_probe_scan_compiles_in_tiles(one_chip, widths, b, cap):
     assert temp < 1.25 * (b * cap * D * 4 + top_list), temp
     # stronger, as compiled today: under two steps' own gathers
     assert temp < 2 * step_gather * 4 + (64 << 20), temp
+
+
+# benchmark/configs/gist1m-960-ivfrabitq.json: 960-d, r0 512 (the
+# product's default), r1 256, the fetch-k tier of k = 10; the published
+# corpus (1,000,000 rows) and the cell's cut of it (500,000)
+GIST_ROWS, GIST_CELL_ROWS, GIST_D, GIST_R0 = 1_000_000, 500_000, 960, 512
+
+
+def _refine_args(S, b, rows, packed: bool):
+    """`binary_refine_rerank`'s arguments at the capacities the code
+    picks for the restored corpus (the mirrors 512-aligned, the store
+    exactly its rows), the int8 rows and the raw store as placed for a
+    gather (`packed`: `[n / 2, 1920]`, `row_pack(960)` 2) or as plain
+    `[n, 960]`."""
+    n = -(-rows // 512) * 512
+    pack = row_pack(GIST_D) if packed else 1
+    return n, (
+        S((b, GIST_D), jnp.float32), S((n, GIST_D // 8), jnp.uint8),
+        S((n,), jnp.float32), S((n,), jnp.float32),
+        S((n // pack, pack * GIST_D), jnp.int8),
+        S((n,), jnp.float32), S((n,), jnp.float32), S((n,), jnp.bool_),
+        S((rows // pack, pack * GIST_D), jnp.float32),
+        S((rows,), jnp.float32))
+
+
+def _as_large_as_a_store(compiled, n, d):
+    """Entry instructions whose result holds n * d elements or more, of
+    any type: an unpacked +-1 operand, a copy of the int8 rows or of the
+    raw store. Asynchronous prefetches (`copy-start` / `-done`) of a
+    parameter move nothing the program would not read anyway."""
+    return [name for name in _score_sized(compiled, n, d)
+            if not name.startswith(("copy-start", "copy-done"))]
+
+
+@pytest.mark.parametrize("rows,b", [
+    (GIST_CELL_ROWS, 8), (GIST_CELL_ROWS, 64), (GIST_CELL_ROWS, 256),
+    (GIST_ROWS, 64), (GIST_ROWS, 256)])
+def test_three_stage_refinement_compiles_without_a_whole_store_copy(
+        one_chip, widths, rows, b):
+    """The serving program of `gist1m-960-ivfrabitq` at every row bucket
+    its mix warms (64, 128 -> 256, 256) and the write check's 8, at the
+    cell's rows and at the published corpus's: XLA
+    module `jit_binary_refine_rerank` (what the benchmark finds it by on
+    the device trace), its five stages under their scopes, and what was
+    mended for the chip (PERF.md section 6, PR 34):
+
+    - the bit planes' unpack is fused into stage 0's product: NO
+      instruction writes an `[N, 960]` array, of bf16 or any other type
+      (the unpacked operand would be 1.92 GB, 16x the planes);
+    - ONE instruction writes the `[B, N]` f32 score matrix, and temp is
+      that matrix (at B=8 not even that);
+    - 960 is no multiple of 128, so the chip lays `[N, 960]` int8 rows
+      and float32 rows out column-major: handed in like that, stage 1's
+      and stage 2's row gathers each copy their whole operand row-major
+      in every dispatch (0.96 + 3.84 GB: 16.4 of 24.8 ms on the chip).
+      As placed for a gather, `[N / 2, 1920]` super-rows
+      (`Int8Mirror.flush(packed=True)`, `RawVectorStore.device_buffer(
+      packed=True)`), both parameters are row-major and no instruction
+      is as large as either."""
+    S = _shapes(one_chip)
+    n, args = _refine_args(S, b, rows, packed=True)
+    assert row_pack(GIST_D) == 2 and n in (500_224, 1_000_448)
+    assert perf_model.refine_depths(widths["fetch_k"], rows) == (
+        GIST_R0, 160)
+    compiled = binary_ops.binary_refine_rerank.lower(
+        *args, GIST_R0, RERANK, widths["fetch_k"], scan_metric=L2,
+        rerank_metric=L2, storage="int8").compile()
+    _, temp = _report(f"binary_refine_rerank[{rows} x 960, B={b}]",
+                      compiled)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_binary_refine_rerank")
+    for scope in ("unpack", "stage0_score", "stage0_select",
+                  "stage1_rescore", "rerank"):
+        assert f"/{scope}/" in text, scope
+    entry = text.splitlines()[0]  # the module's own operands, as placed
+    assert f"s8[{n // 2},{2 * GIST_D}]{{1,0:" in entry
+    assert f"f32[{rows // 2},{2 * GIST_D}]{{1,0:" in entry
+    assert _as_large_as_a_store(compiled, rows, GIST_D) == []
+    matrix = perf_model.scan_peak_bytes(b, n)
+    assert temp < 1.25 * matrix, (temp, matrix)
+    if b > 8:  # at 8 rows the matrix is smaller than a stage's gather
+        written = _score_sized(compiled, b, n, "f32")
+        assert len(written) == 1 and matrix <= temp, (written, temp)
+    # stage 0 selects r0 = 512 through `_blocked_topk`: the widest sort
+    # is over the r0 * BLOCK gathered scores of a query, never a row
+    assert 0 < _widest_sort_input(compiled, b) <= max(
+        n // ivf_ops.BLOCK, GIST_R0 * ivf_ops.BLOCK)
+
+
+def test_three_stage_refinement_on_plain_layouts_copies_both_stores(
+        one_chip, widths):
+    """Why the two stores are placed as super-rows: the same program
+    handed `[N, 960]` int8 rows and float32 rows (how the parent placed
+    them) compiles to a row-major `copy` of each, whole, before its
+    gather: two instructions as large as a store, and 4.1 GB of temp."""
+    n, args = _refine_args(_shapes(one_chip), 64, GIST_ROWS, packed=False)
+    compiled = binary_ops.binary_refine_rerank.lower(
+        *args, GIST_R0, RERANK, widths["fetch_k"], scan_metric=L2,
+        rerank_metric=L2, storage="int8").compile()
+    _, temp = _report("binary_refine_rerank[B=64, plain layouts]", compiled)
+    entry = compiled.as_text().splitlines()[0]
+    assert f"s8[{n},{GIST_D}]{{0,1:" in entry  # column-major as placed
+    assert f"f32[{GIST_ROWS},{GIST_D}]{{0,1:" in entry
+    copies = _as_large_as_a_store(compiled, GIST_ROWS, GIST_D)
+    assert len(copies) == 2 and all(c.startswith("copy") for c in copies)
+    assert temp > GIST_ROWS * GIST_D * 4
 
 
 @pytest.mark.parametrize("step", ["train_kmeans", "assign_sample",

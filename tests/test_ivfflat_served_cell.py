@@ -296,6 +296,13 @@ def test_a_padded_probe_slot_never_duplicates_a_docid(world, monkeypatch):
     assert (ids == want).all()
 
 
+def _latest(spans):
+    """The span that ended last. `tracing.snapshot()` walks every tracer
+    the process still holds, in no order: under xdist an earlier test
+    file's servers may come after this one's, so position says nothing."""
+    return max(spans, key=lambda s: s.t1_ns)
+
+
 def _ivf_gauges(w) -> dict:
     """`vearch_ps_ivf_publish{stat}` off the PS's /metrics, by stat."""
     import urllib.request
@@ -328,13 +335,14 @@ def test_an_appended_row_is_found_after_the_publish_it_forces(world):
     assert after["nlist"] == 64 and after["cap"] % TILE_ROWS == 0
     assert after["fill"] == pytest.approx(
         after["rows"] / (after["nlist"] * after["cap"]), abs=1e-6)
-    publish = [s for s in tracing.snapshot() if s.name == "ivf.publish"][-1]
+    publish = _latest(s for s in tracing.snapshot()
+                      if s.name == "ivf.publish")
     assert {k: publish.tags[k] for k in
             ("rows", "nlist", "cap", "bytes", "fill")} == {
         k: after[k] for k in ("rows", "nlist", "cap", "bytes", "fill")}
     # the request that paid for it carries it, inside its probe phase
-    probe = [s for s in tracing.snapshot() if s.name == "ivf.probe"
-             and s.trace_id == publish.trace_id][-1]
+    probe = _latest(s for s in tracing.snapshot() if s.name == "ivf.probe"
+                    and s.trace_id == publish.trace_id)
     assert probe.t0_ns <= publish.t0_ns and publish.t1_ns <= probe.t1_ns
     gauges = _ivf_gauges(w)
     assert sorted(gauges) == sorted(
@@ -352,8 +360,8 @@ def test_an_appended_row_is_found_after_the_publish_it_forces(world):
 def _probe_span(trace_id=None):
     from vearch_tpu.cluster import tracing
 
-    return [s for s in tracing.snapshot() if s.name == "ivf.probe"
-            and trace_id in (None, s.trace_id)][-1]
+    return _latest(s for s in tracing.snapshot() if s.name == "ivf.probe"
+                   and trace_id in (None, s.trace_id))
 
 
 def test_the_same_mask_twice_is_one_build_and_one_hit(world):
@@ -395,8 +403,8 @@ def test_the_dispatch_span_carries_its_launch_and_the_probe_phase_its_tags(
     w = world
     w.search(w.queries[:B - 4])  # 60 rows in a 64 bucket
     spans = tracing.snapshot()
-    kernel = [s for s in spans if s.name == "kernel.ivfflat_scan"][-1]
-    probe = [s for s in spans if s.name == "ivf.probe"][-1]
+    kernel = _latest(s for s in spans if s.name == "kernel.ivfflat_scan")
+    probe = _latest(s for s in spans if s.name == "ivf.probe")
     assert kernel.trace_id == probe.trace_id
     assert kernel.tags["rows"] == B - 4 and kernel.tags["bucket_rows"] == B
     assert 0 < kernel.tags["launch_us"] * 1e3 <= kernel.t1_ns - kernel.t0_ns

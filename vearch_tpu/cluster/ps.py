@@ -3316,6 +3316,10 @@ class PSServer:
                     # the published IVF bucket table per field: rows,
                     # nlist, cap, bytes, fill, publishes, seconds
                     "ivf": self._ivf_info_safe(eng),
+                    # the three-stage refinement funnel per field:
+                    # searches, rows scored a stage, r0 / r1, device
+                    # bytes of the planes, the int8 rows, the raw store
+                    "refine": self._refine_info_safe(eng),
                     # tiered storage (HBM slab cache / host-RAM tiers /
                     # prefetch) — the doctor's prefetch-effectiveness
                     # check reads these blocks
@@ -3336,6 +3340,13 @@ class PSServer:
     def _ivf_info_safe(eng) -> dict | None:
         try:
             return eng.ivf_info()
+        except Exception:
+            return None
+
+    @staticmethod
+    def _refine_info_safe(eng) -> dict | None:
+        try:
+            return eng.refine_info()
         except Exception:
             return None
 

@@ -1389,6 +1389,14 @@ class Engine:
                   if (info := index.ivf_info()) is not None}
         return {"fields": fields} if fields else None
 
+    def refine_info(self) -> dict[str, Any] | None:
+        """The three-stage refinement funnel of each vector field whose
+        index serves one (index/binary.py `refine_info`; surfaced in
+        /ps/stats); None when no field has served such a search."""
+        fields = {name: info for name, index in self.indexes.items()
+                  if (info := index.refine_info()) is not None}
+        return {"fields": fields} if fields else None
+
     def tiering_info(self) -> dict[str, Any] | None:
         """Aggregate tiered-storage summary over the engine's vector
         fields (surfaced in /ps/stats and profile:true traces); None

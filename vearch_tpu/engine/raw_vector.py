@@ -34,6 +34,7 @@ import numpy as np
 from vearch_tpu.ops import ivf as ivf_ops
 from vearch_tpu.ops import perf_model
 from vearch_tpu.ops.distance import host_sqnorms
+from vearch_tpu.parallel.mesh import row_pack
 from vearch_tpu.tools import lockcheck
 
 
@@ -51,6 +52,12 @@ class RawVectorStore:
         self._device: jax.Array | None = None  # [capacity, d] store_dtype
         self._device_sqnorm: jax.Array | None = None  # [capacity] f32
         self._device_rows = 0  # rows already mirrored to device
+        # the same three for the placement a row GATHER takes at a width
+        # that is no multiple of 128 (`device_buffer(packed=True)`):
+        # [capacity / pack, pack * d]; never placed at `row_pack` 1
+        self._packed: jax.Array | None = None
+        self._packed_sqnorm: jax.Array | None = None
+        self._packed_rows = 0
         # concurrent first placements race: a second searcher (another
         # request thread, the shadow-recall sampler) saw `_device` set
         # and `_device_sqnorm` still None mid-placement and crashed, or
@@ -82,6 +89,14 @@ class RawVectorStore:
         self._n += b
         return start
 
+    def placed_bytes(self) -> int:
+        """Device bytes of the single-device placements that exist now:
+        the buffer in each form that was asked for, with its squared
+        norms (metadata: no sync)."""
+        return sum(int(a.nbytes) for a in (
+            self._device, self._device_sqnorm, self._packed,
+            self._packed_sqnorm) if a is not None)
+
     def host_view(self) -> np.ndarray:
         """[n, d] float32 host rows (training / rerank / dump path)."""
         return self._host[: self._n]
@@ -89,52 +104,92 @@ class RawVectorStore:
     def get(self, docid: int) -> np.ndarray:
         return self._host[docid]
 
-    def device_buffer(self) -> tuple[jax.Array, jax.Array, int]:
+    def device_buffer(
+        self, packed: bool = False
+    ) -> tuple[jax.Array, jax.Array, int]:
         """Returns (base [capacity, d], base_sqnorm [capacity], n_rows).
 
         Flushes any dirty tail to the device. Rows >= n_rows are padding
         and must be masked by the caller. The buffer is rebuilt only when
         capacity changed; otherwise the tail lands via dynamic_update_slice
         on the existing device array.
-        """
-        with self._flush_lock:
-            return self._device_buffer_locked()
 
-    def _device_buffer_locked(self) -> tuple[jax.Array, jax.Array, int]:
+        `packed`: the buffer as a program that GATHERS rows wants it
+        (the exact rerank; ops/ivf.py `gather_rows`):
+        `[capacity / pack, pack * d]`, `pack` = `parallel.mesh.row_pack(d)`
+        consecutive rows a device row. The chip lays `[capacity, d]` out
+        column-major when `d` is no multiple of 128 (960, 96): right for
+        a matrix product, but a row gather then copies the whole store
+        row-major in every dispatch (3.84 GB at 1M x 960). Whole 128-lane
+        super-rows are row-major as placed. At `pack` 1 (128, 768) this
+        IS the plain buffer; past it the store keeps a placement for each
+        form that was asked for (a FLAT scan beside a rerank holds two)."""
+        with self._flush_lock:
+            return self._device_buffer_locked(packed)
+
+    def _device_buffer_locked(
+        self, packed: bool = False
+    ) -> tuple[jax.Array, jax.Array, int]:
+        pack = row_pack(self.dimension) if packed else 1
+        form = "_packed" if pack > 1 else "_device"
+        dev: jax.Array | None = getattr(self, form)
+        sqn: jax.Array | None = getattr(self, form + "_sqnorm")
+        rows: int = getattr(self, form + "_rows")
         # snapshot n once: a concurrent upsert may advance self._n while we
         # flush; rows past the snapshot flush on the next call
         n = self._n
         cap = self._host.shape[0]
-        if self._device is None or self._device.shape[0] != cap:
+        d = self.dimension
+        # a placement holds whole super-rows: `placed` device rows
+        placed = -(-cap // pack)
+        if dev is None or dev.shape[0] != placed:
             t0 = time.monotonic()
-            self._device = jnp.asarray(self._host, dtype=self.store_dtype)
-            self._device_sqnorm = jnp.asarray(
-                host_sqnorms(np.asarray(self._device))
-            )
+            # let the old placement go BEFORE the new one goes up: a
+            # doubled store beside its predecessor is 3x the old bytes
+            # at the peak (11.5 GB of a 16 GB chip at 1M x 960); a
+            # search still in flight keeps its own reference
+            dev = sqn = None
+            setattr(self, form, None)
+            setattr(self, form + "_sqnorm", None)
+            host = self._host
+            if placed * pack != cap:  # a capacity of no whole super-rows
+                host = np.zeros((placed * pack, d), dtype=np.float32)
+                host[:cap] = self._host
+            dev = jnp.asarray(host.reshape(placed, pack * d),
+                              dtype=self.store_dtype)
+            # norms of the rows AS STORED: for float32 the host rows are
+            # those (and 7.68 GB does not come back down to be squared)
+            stored = (self._host if self.store_dtype == jnp.float32
+                      else np.asarray(dev).reshape(-1, d)[:cap])
+            sqn = jnp.asarray(host_sqnorms(stored))
             # .nbytes is metadata — no host sync
-            nbytes = int(self._device.nbytes) + int(self._device_sqnorm.nbytes)
+            nbytes = int(dev.nbytes) + int(sqn.nbytes)
             perf_model.note_h2d_bytes(nbytes)
-            self._device_rows = n
+            rows = n
             # the whole store goes up again (first placement, or the
             # host array grew past its capacity after a write): seconds
             # at a million rows, paid by whichever search comes next
             ivf_ops.note_phase("engine.replace_raw", t0, time.monotonic(),
                                {"bytes": nbytes})
-        elif self._device_rows < n:
-            tail = jnp.asarray(
-                self._host[self._device_rows : n], dtype=self.store_dtype
-            )
+        elif rows < n:
+            # whole super-rows: from the one that holds the first new
+            # row (its older rows are rewritten as they are) to the one
+            # that holds the last (host rows past n are zeros)
+            lo = rows // pack * pack
+            hi = min(-(-n // pack) * pack, cap)
+            tail = jnp.asarray(self._host[lo:hi], dtype=self.store_dtype)
             perf_model.note_h2d_bytes(int(tail.nbytes))
-            self._device = jax.lax.dynamic_update_slice(
-                self._device, tail, (self._device_rows, 0)
-            )
-            self._device_sqnorm = jax.lax.dynamic_update_slice(
-                self._device_sqnorm,
-                jnp.asarray(host_sqnorms(np.asarray(tail))),
-                (self._device_rows,),
-            )
-            self._device_rows = n
-        return self._device, self._device_sqnorm, n
+            tail_sqn = jnp.asarray(host_sqnorms(np.asarray(tail)))
+            if (hi - lo) % pack:  # the last super-row of an odd capacity
+                tail = jnp.pad(tail, ((0, pack - (hi - lo) % pack), (0, 0)))
+            dev = jax.lax.dynamic_update_slice(
+                dev, tail.reshape(-1, pack * d), (lo // pack, 0))
+            sqn = jax.lax.dynamic_update_slice(sqn, tail_sqn, (lo,))
+            rows = n
+        setattr(self, form, dev)
+        setattr(self, form + "_sqnorm", sqn)
+        setattr(self, form + "_rows", rows)
+        return dev, sqn, n
 
     _sh_cache = None
 
@@ -148,9 +203,9 @@ class RawVectorStore:
         The buffer is placed as `[cap / pack, pack * d]`, `pack` =
         `parallel.mesh.row_pack(d)` consecutive rows a device row (a
         reshape of the contiguous host rows); at `pack` 1, `[cap, d]`.
-        Only `parallel/sharded.py` `_gather_rows` reads it. sqnorm stays
+        Only `ops/ivf.py` `gather_rows` reads it. sqnorm stays
         `[cap]`, n_rows logical."""
-        from vearch_tpu.parallel.mesh import ShardedRowCache, row_pack
+        from vearch_tpu.parallel.mesh import ShardedRowCache
 
         pack = row_pack(self.dimension)
 
@@ -187,8 +242,8 @@ class RawVectorStore:
             data = np.load(path)
             self._host = data.copy()
             self._n = data.shape[0]
-            self._device = None
-            self._device_rows = 0
+            self._device = self._packed = None
+            self._device_rows = self._packed_rows = 0
             if self._sh_cache is not None:
                 self._sh_cache.invalidate()
 
@@ -209,7 +264,7 @@ class RawVectorStore:
             off += p.shape[0]
         self._host = host
         self._n = n
-        self._device = None
-        self._device_rows = 0
+        self._device = self._packed = None
+        self._device_rows = self._packed_rows = 0
         if self._sh_cache is not None:
             self._sh_cache.invalidate()

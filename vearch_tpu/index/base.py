@@ -118,6 +118,13 @@ class VectorIndex(abc.ABC):
         that publishes none or has not yet."""
         return None
 
+    def refine_info(self) -> dict[str, Any] | None:
+        """The three-stage refinement funnel of an index that serves
+        one (index/binary.py IVFRABITQ: searches, rows scored a stage,
+        depths, device bytes of the three representations), None for
+        every other index and before the first such search."""
+        return None
+
     def tiering_info(self) -> dict[str, Any] | None:
         """Tiered-storage summary (per-tier hit/miss/pin counters,
         residency bytes — see docs/TIERING.md), None when this index

@@ -44,6 +44,7 @@ from vearch_tpu.ops import binary_scan as binary_ops
 from vearch_tpu.ops import ivf as ivf_ops
 from vearch_tpu.ops import perf_model
 from vearch_tpu.ops.distance import to_device_mask
+from vearch_tpu.tools import lockcheck
 
 
 @register_index("BINARYIVF")
@@ -69,6 +70,10 @@ class BinaryIVFIndex(IVFFlatIndex):
         return bits.astype(np.float32)
 
 
+#: rows `_absorb_rows` quantises at a time
+ABSORB_ROWS = 4096
+
+
 @register_index("IVFRABITQ")
 class IVFRaBitQIndex(IVFPQIndex):
     """1-bit stage-0 tier + progressive three-stage refinement.
@@ -90,6 +95,12 @@ class IVFRaBitQIndex(IVFPQIndex):
         # stage-0 tier: packed sign planes of the (normalized) rows,
         # same append/flush/shard machinery as the int8 mirror
         self._bits = Int8Mirror(store.dimension, storage="bits")
+        #: what `refine_info` reports: fused three-stage searches served
+        #: and the rows each stage scored for them, the last depths
+        self._refine = {"searches": 0, "r0": 0, "r1": 0,
+                        "stage_rows": dict.fromkeys(
+                            binary_ops.REFINE_STAGES, 0)}
+        self._refine_lock = lockcheck.make_lock("index_refine_info")
 
     def _train_extra(self, sample: np.ndarray) -> None:
         # no codebooks to train; only the coarse quantizer (in base train)
@@ -100,16 +111,29 @@ class IVFRaBitQIndex(IVFPQIndex):
         self, rows: np.ndarray, assign: np.ndarray, start_docid: int
     ) -> None:
         cents = np.asarray(self.centroids)
-        resid = rows - cents[assign]
-        scale = np.maximum(
-            np.abs(resid).mean(axis=1), 1e-12
-        ).astype(np.float32)
-        recon = cents[assign] + scale[:, None] * np.sign(resid)
-        self._mirror.append(recon.astype(np.float32), start=start_docid)
-        # stage-0 bit planes quantize the ROW itself (not the residual):
-        # the binary scan is partition-global, so its estimator must not
-        # depend on a per-row centroid term the kernel can't afford
-        self._bits.append(rows, start=start_docid)
+        # in pieces: every row's result is its own, and a piece's
+        # [rows, d] f32 temporaries (gathered centroids, residual, its
+        # magnitudes and signs, the reconstruction, the dequantised
+        # copy) stay in the host's cache. Whole, a million 960-d rows
+        # held the build's host at 40 of its 46 GB and its `assign`
+        # phase read 100 s; in pieces 28-35 s (PERF.md section 6, PR 34)
+        for tier in (self._mirror, self._bits):
+            tier.reserve(start_docid + rows.shape[0])
+        for lo in range(0, rows.shape[0], ABSORB_ROWS):
+            piece, cent = rows[lo:lo + ABSORB_ROWS], cents[
+                assign[lo:lo + ABSORB_ROWS]]
+            resid = piece - cent
+            scale = np.maximum(
+                np.abs(resid).mean(axis=1), 1e-12
+            ).astype(np.float32)
+            recon = cent + scale[:, None] * np.sign(resid)
+            self._mirror.append(recon.astype(np.float32),
+                                start=start_docid + lo)
+            # stage-0 bit planes quantize the ROW itself (not the
+            # residual): the binary scan is partition-global, so its
+            # estimator must not depend on a per-row centroid term the
+            # kernel can't afford
+            self._bits.append(piece, start=start_docid + lo)
 
     def device_footprint_bytes(self) -> int:
         return super().device_footprint_bytes() + self._bits.device_bytes()
@@ -164,7 +188,8 @@ class IVFRaBitQIndex(IVFPQIndex):
             )
         t_flush0 = time.monotonic()
         planes, p_scale, p_vsq = self._bits.flush()
-        approx8, m_scale, m_vsq = self._mirror.flush()
+        # stage 1 GATHERS its r0 rows: the payload as placed for that
+        approx8, m_scale, m_vsq = self._mirror.flush(packed=True)
         n_pad = planes.shape[0]
         valid = to_device_mask(valid_mask, self.indexed_count, n_pad)
         ivf_ops.note_stage_phase("flush", t_flush0, time.monotonic())
@@ -196,8 +221,17 @@ class IVFRaBitQIndex(IVFPQIndex):
             binary_ops.note_refine_search(
                 "disk", self.indexed_count, r0, r1, k, q.shape[0])
             return self._pad_to_k(scores, ids, k)
-        base, base_sqnorm, _ = self.store.device_buffer()
+        base, base_sqnorm, _ = self.store.device_buffer(packed=True)
         t0 = time.monotonic()
+        # the place phase: from the index's entry to the launch (both
+        # flushes, the raw store's, the mask, the query upload), with
+        # what the program is about to funnel and read
+        ivf_ops.note_phase(
+            "refine.place", t_flush0, t0,
+            {"r0": r0, "r1": r1, "rows": self.indexed_count,
+             "plane_bytes": self._bits.placed_bytes(),
+             "mirror_bytes": self._mirror.placed_bytes()},
+            request_only=True)
         ivf_ops.note_dispatch("binary_refine_rerank")
         scores, ids = binary_ops.binary_refine_rerank(
             qd, planes, p_scale, p_vsq, approx8, m_scale, m_vsq, valid,
@@ -205,11 +239,37 @@ class IVFRaBitQIndex(IVFPQIndex):
             scan_metric=metric, rerank_metric=self.metric,
             storage=self.mirror_storage,
         )
+        ivf_ops.capture_launched()
         scores, ids = jax.device_get((scores, ids))
         ivf_ops.note_stage_phase("refine", t0, time.monotonic())
+        self._note_refine(r0, r1, q.shape[0])
         binary_ops.note_refine_search(
             "fused", self.indexed_count, r0, r1, k, q.shape[0])
         return self._pad_to_k(scores, ids, k)
+
+    def _note_refine(self, r0: int, r1: int, batch: int) -> None:
+        with self._refine_lock:
+            info = self._refine
+            info["searches"] += 1
+            info["r0"], info["r1"] = r0, r1
+            for stage, rows in zip(binary_ops.REFINE_STAGES,
+                                   (self.indexed_count, r0, r1)):
+                info["stage_rows"][stage] += rows * batch
+
+    def refine_info(self) -> dict[str, Any] | None:
+        """The three-stage funnel as this index served it on one chip:
+        searches, the rows each stage scored for them (every row of the
+        partition, r0, r1, times the query rows), the last depths, and
+        the device bytes of the three representations as placed; None
+        before the first such search."""
+        with self._refine_lock:
+            if not self._refine["searches"]:
+                return None
+            info = {**self._refine,
+                    "stage_rows": dict(self._refine["stage_rows"])}
+        return {**info, "plane_bytes": self._bits.placed_bytes(),
+                "mirror_bytes": self._mirror.placed_bytes(),
+                "raw_bytes": self.store.placed_bytes()}
 
     def _search_binary_mesh(
         self, q: np.ndarray, k: int, valid_mask, params, metric,
@@ -254,6 +314,26 @@ class IVFRaBitQIndex(IVFPQIndex):
         mesh = self._serving_mesh(None)
         n_shards = int(mesh.shape["data"])
         return base + -(-self._bits.device_bytes() // max(n_shards, 1))
+
+    def reconstruction_error(self, sample: int = 256,
+                             seed: int = 0) -> float | None:
+        """The STORED stage-1 rows (the int8 reconstruction, dequantised)
+        against the raw rows; host numpy only. IVFPQ's reads PQ codes,
+        of which this index has none (it raised on every sample of the
+        quality monitor: `engine.quality_info` internal errors)."""
+        with self._absorb_lock:
+            n = min(int(self.indexed_count), self._mirror.count)
+            if not self.trained or n == 0 or self.mirror_storage != "int8":
+                return None
+            ids = np.sort(np.random.default_rng(seed).choice(
+                n, size=min(int(sample), n), replace=False))
+            raw = self._maybe_normalize(
+                np.asarray(self.store.host_view()[ids], dtype=np.float32))
+            approx = (self._mirror._h8[ids].astype(np.float32)
+                      * self._mirror._h_scale[ids, None])
+            num = np.linalg.norm(raw - approx, axis=1)
+            den = np.maximum(np.linalg.norm(raw, axis=1), 1e-12)
+            return float(np.mean(num / den))
 
     def _publish(self) -> None:
         # probe mode unsupported for 1-bit codes; the stage-0/stage-1

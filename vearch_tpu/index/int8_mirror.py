@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from vearch_tpu.ops import perf_model
+from vearch_tpu.parallel.mesh import row_pack
 from vearch_tpu.tools import lockcheck
 
 
@@ -85,7 +86,12 @@ class Int8Mirror:
         self._d8: jax.Array | None = None
         self._d_scale: jax.Array | None = None
         self._d_vsq: jax.Array | None = None
-        self._d_rows = 0
+        self._d_rows = 0   # rows of the payload `_d8` holds
+        self._dc_rows = 0  # rows of the two columns
+        # the row payload as a program that GATHERS rows takes it
+        # (`flush(packed=True)`); never placed at pack 1
+        self._dp8: jax.Array | None = None
+        self._dp_rows = 0
         # append vs flush race: a concurrent append may REPLACE the
         # host arrays (capacity growth) while flush reads them — the
         # tail-flush would mix old and new buffers. One leaf lock
@@ -103,6 +109,13 @@ class Int8Mirror:
         cap = self._h8.shape[0]
         return cap * self._row_width + 2 * cap * 4
 
+    def placed_bytes(self) -> int:
+        """Device bytes of what is placed now: the payload in each form
+        that was asked for, and the two columns (metadata: no sync)."""
+        return sum(int(a.nbytes) for a in (
+            self._d8, self._dp8, self._d_scale, self._d_vsq)
+            if a is not None)
+
     def append_quantized(
         self, q8: np.ndarray, scale: np.ndarray, vsq: np.ndarray,
         start: int | None = None,
@@ -111,25 +124,38 @@ class Int8Mirror:
         with self._flush_lock:
             self._append_locked(q8, scale, vsq, start)
 
+    def reserve(self, rows: int) -> None:
+        """Room for `rows` rows before they arrive in pieces: a caller
+        that knows the total (an absorb that quantises a piece at a
+        time) gets the capacity ONE append of them all would, not the
+        next doubling above it (1,048,576 for 1,000,000 rows: 4.8 % more
+        rows in every scan)."""
+        with self._flush_lock:
+            self._grow_locked(rows)
+
+    def _grow_locked(self, need: int) -> None:
+        if self._h8.shape[0] >= need:
+            return
+        # capacity stays 512-aligned: the two-stage top-k takes a
+        # score row in whole blocks of 128 (ops/ivf.py BLOCK), and
+        # a ragged row falls back to one sort of the whole row
+        cap = max(need, self._h8.shape[0] * 2, 1024)
+        cap = -(-cap // 512) * 512
+        g8 = np.zeros((cap, self._row_width), dtype=self._row_dtype)
+        gs = np.zeros(cap, dtype=np.float32)
+        gv = np.zeros(cap, dtype=np.float32)
+        g8[: self._n] = self._h8[: self._n]
+        gs[: self._n] = self._h_scale[: self._n]
+        gv[: self._n] = self._h_vsq[: self._n]
+        self._h8, self._h_scale, self._h_vsq = g8, gs, gv
+
     def _append_locked(
         self, q8: np.ndarray, scale: np.ndarray, vsq: np.ndarray,
         start: int | None,
     ) -> None:
         start = self._n if start is None else start
         need = start + q8.shape[0]
-        if self._h8.shape[0] < need:
-            # capacity stays 512-aligned: the two-stage top-k takes a
-            # score row in whole blocks of 128 (ops/ivf.py BLOCK), and
-            # a ragged row falls back to one sort of the whole row
-            cap = max(need, self._h8.shape[0] * 2, 1024)
-            cap = -(-cap // 512) * 512
-            g8 = np.zeros((cap, self._row_width), dtype=self._row_dtype)
-            gs = np.zeros(cap, dtype=np.float32)
-            gv = np.zeros(cap, dtype=np.float32)
-            g8[: self._n] = self._h8[: self._n]
-            gs[: self._n] = self._h_scale[: self._n]
-            gv[: self._n] = self._h_vsq[: self._n]
-            self._h8, self._h_scale, self._h_vsq = g8, gs, gv
+        self._grow_locked(need)
         sl = slice(start, need)
         self._h8[sl] = q8
         self._h_scale[sl] = scale
@@ -137,8 +163,9 @@ class Int8Mirror:
         self._n = max(self._n, need)
         # rows below the mirrored high-water mark were overwritten
         # (re-absorb after load_state): force re-upload from `start`
-        if start < self._d_rows:
-            self._d_rows = start
+        self._d_rows = min(self._d_rows, start)
+        self._dc_rows = min(self._dc_rows, start)
+        self._dp_rows = min(self._dp_rows, start)
         if self._sh_cache is not None:
             self._sh_cache.lower_rows(start)
 
@@ -192,38 +219,60 @@ class Int8Mirror:
 
     _sh_cache = None
 
-    def flush(self) -> tuple[jax.Array, jax.Array, jax.Array]:
-        """Device views [cap, d] / [cap] / [cap]; rows >= count are padding."""
-        with self._flush_lock:
-            return self._flush_locked()
+    def flush(
+        self, packed: bool = False
+    ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """Device views [cap, w] / [cap] / [cap]; rows >= count are padding.
 
-    def _flush_locked(self) -> tuple[jax.Array, jax.Array, jax.Array]:
+        `packed`: the row payload as a program that GATHERS rows from it
+        wants it (the three-stage chain's stage 1; ops/ivf.py
+        `gather_rows`): `[cap / pack, pack * w]`, `pack` =
+        `parallel.mesh.row_pack(w)` consecutive rows a device row. At a
+        width that is no multiple of 128 the chip lays `[cap, w]` out
+        column-major, which a matrix product wants and a row gather
+        copies whole, in every dispatch. At `pack` 1 this is the plain
+        view; past it the payload is placed once for each form that was
+        asked for. The two columns are the same arrays either way."""
+        with self._flush_lock:
+            return self._flush_locked(packed)
+
+    def _placed(self, dev: jax.Array | None, done: int, host: np.ndarray,
+                pack: int = 1) -> jax.Array:
+        """`host` ([cap, w] or [cap]) on the device, as
+        `[cap / pack, pack * w]`: placed whole when the capacity changed,
+        else the rows from `done` on appended (whole super-rows: the one
+        that holds row `done` goes up again as it is; the capacity is
+        512-aligned and host rows past the count are zeros)."""
         n = self._n
-        cap = self._h8.shape[0]
-        if self._d8 is None or self._d8.shape[0] != cap:
-            self._d8 = jnp.asarray(self._h8)
-            self._d_scale = jnp.asarray(self._h_scale)
-            self._d_vsq = jnp.asarray(self._h_vsq)
+        view = host.reshape(host.shape[0] // pack, -1) if pack > 1 else host
+        if dev is None or dev.shape[0] != view.shape[0]:
+            dev = jnp.asarray(view)
             # .nbytes is metadata — no host sync
-            perf_model.note_h2d_bytes(
-                int(self._d8.nbytes) + int(self._d_scale.nbytes)
-                + int(self._d_vsq.nbytes)
-            )
+            perf_model.note_h2d_bytes(int(dev.nbytes))
+        elif done < n:
+            lo = done // pack * pack
+            tail = host[lo:-(-n // pack) * pack]
+            perf_model.note_h2d_bytes(int(tail.nbytes))
+            if pack > 1:
+                tail = tail.reshape(-1, view.shape[1])
+            dev = jax.lax.dynamic_update_slice(
+                dev, jnp.asarray(tail),
+                (lo // pack,) + (0,) * (view.ndim - 1))
+        return dev
+
+    def _flush_locked(
+        self, packed: bool = False
+    ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        n = self._n
+        self._d_scale = self._placed(self._d_scale, self._dc_rows,
+                                     self._h_scale)
+        self._d_vsq = self._placed(self._d_vsq, self._dc_rows, self._h_vsq)
+        self._dc_rows = n
+        pack = row_pack(self._row_width) if packed else 1
+        if pack == 1:
+            self._d8 = self._placed(self._d8, self._d_rows, self._h8)
             self._d_rows = n
-        elif self._d_rows < n:
-            sl = slice(self._d_rows, n)
-            perf_model.note_h2d_bytes(
-                int(self._h8[sl].nbytes) + int(self._h_scale[sl].nbytes)
-                + int(self._h_vsq[sl].nbytes)
-            )
-            self._d8 = jax.lax.dynamic_update_slice(
-                self._d8, jnp.asarray(self._h8[sl]), (self._d_rows, 0)
-            )
-            self._d_scale = jax.lax.dynamic_update_slice(
-                self._d_scale, jnp.asarray(self._h_scale[sl]), (self._d_rows,)
-            )
-            self._d_vsq = jax.lax.dynamic_update_slice(
-                self._d_vsq, jnp.asarray(self._h_vsq[sl]), (self._d_rows,)
-            )
-            self._d_rows = n
-        return self._d8, self._d_scale, self._d_vsq
+            return self._d8, self._d_scale, self._d_vsq
+        self._dp8 = self._placed(self._dp8, self._dp_rows, self._h8, pack)
+        self._dp_rows = n
+        return self._dp8, self._d_scale, self._d_vsq

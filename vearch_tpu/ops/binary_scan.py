@@ -40,7 +40,7 @@ import numpy as np
 
 from vearch_tpu.engine.types import MetricType
 from vearch_tpu.ops.distance import sqnorms
-from vearch_tpu.ops.ivf import NEG_INF, _select_topk, unpack_int4
+from vearch_tpu.ops.ivf import NEG_INF, _select_topk, gather_rows, unpack_int4
 from vearch_tpu.ops.perf_model import register_jit
 from vearch_tpu.tools import lockcheck
 
@@ -97,18 +97,22 @@ def _binary_scores(
     valid: jax.Array,      # [N_pad] bool
     metric: MetricType,
 ) -> jax.Array:
-    signs = unpack_bits_pm1(planes)  # [N, d_pad] bf16 (transient)
-    qp = _pad_queries(queries, signs.shape[1])
-    dots = jax.lax.dot_general(
-        qp.astype(jnp.bfloat16), signs, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * row_scale[None, :]
-    if metric is MetricType.L2:
-        scores = -(sqnorms(queries)[:, None] - 2.0 * dots
-                   + row_vsq[None, :])
-    else:
-        scores = dots
-    return jnp.where(valid[None, :], scores, NEG_INF)
+    # named scopes (as ops/ivf.py's): the stage in every operation's
+    # op_name, free at run time and no part of the compile cache's key
+    with jax.named_scope("unpack"):
+        signs = unpack_bits_pm1(planes)  # [N, d_pad] bf16 (transient)
+    with jax.named_scope("stage0_score"):
+        qp = _pad_queries(queries, signs.shape[1])
+        dots = jax.lax.dot_general(
+            qp.astype(jnp.bfloat16), signs, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * row_scale[None, :]
+        if metric is MetricType.L2:
+            scores = -(sqnorms(queries)[:, None] - 2.0 * dots
+                       + row_vsq[None, :])
+        else:
+            scores = dots
+        return jnp.where(valid[None, :], scores, NEG_INF)
 
 
 @functools.partial(jax.jit, static_argnames=("r", "metric"))
@@ -128,7 +132,8 @@ def binary_scan_candidates(
     block-max selection machinery with the int8 scan."""
     scores = _binary_scores(queries, planes, row_scale, row_vsq, valid,
                             metric)
-    return _select_topk(scores, r)
+    with jax.named_scope("stage0_select"):
+        return _select_topk(scores, r)
 
 
 def _mirror_rescore(
@@ -142,24 +147,31 @@ def _mirror_rescore(
     storage: str,
 ) -> tuple[jax.Array, jax.Array]:
     """Stage 1: rescore the stage-0 candidates against the int8/int4
-    mirror rows (gather + batched matvec) and keep the top r1."""
-    safe = jnp.clip(cand_i, 0, approx8.shape[0] - 1)
-    rows = approx8[safe]  # [B, r0, w]
-    vals = rows.astype(jnp.bfloat16) if storage == "int8" \
-        else unpack_int4(rows)
-    dots = jax.lax.dot_general(
-        queries.astype(jnp.bfloat16), vals, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * m_scale[safe]
-    if metric is MetricType.L2:
-        scores = -(sqnorms(queries)[:, None] - 2.0 * dots + m_vsq[safe])
-    else:
-        scores = dots
-    scores = jnp.where(cand_i >= 0, scores, NEG_INF)
-    r1 = min(r1, scores.shape[1])
-    top_s, pos = jax.lax.top_k(scores, r1)
-    ids = jnp.take_along_axis(cand_i, pos, axis=1)
-    return top_s, jnp.where(jnp.isfinite(top_s), ids, -1)
+    mirror rows (gather + batched matvec) and keep the top r1. The
+    payload may come as placed for a gather, `[N_pad / pack, pack * w]`
+    (`Int8Mirror.flush(packed=True)`, ops/ivf.py `gather_rows`)."""
+    with jax.named_scope("stage1_rescore"):
+        d = queries.shape[1]
+        safe = jnp.clip(cand_i, 0, m_scale.shape[0] - 1)
+        rows = gather_rows(
+            approx8, safe, d if storage == "int8" else d // 2)  # [B, r0, w]
+        vals = rows.astype(jnp.bfloat16) if storage == "int8" \
+            else unpack_int4(rows)
+        dots = jax.lax.dot_general(
+            queries.astype(jnp.bfloat16), vals,
+            (((1,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * m_scale[safe]
+        if metric is MetricType.L2:
+            scores = -(sqnorms(queries)[:, None] - 2.0 * dots
+                       + m_vsq[safe])
+        else:
+            scores = dots
+        scores = jnp.where(cand_i >= 0, scores, NEG_INF)
+        r1 = min(r1, scores.shape[1])
+        top_s, pos = jax.lax.top_k(scores, r1)
+        ids = jnp.take_along_axis(cand_i, pos, axis=1)
+        return top_s, jnp.where(jnp.isfinite(top_s), ids, -1)
 
 
 @functools.partial(
@@ -202,11 +214,13 @@ def binary_refine_rerank(
     planes: jax.Array,       # [N_pad, d/8] uint8
     row_scale: jax.Array,    # [N_pad] f32
     row_vsq: jax.Array,      # [N_pad] f32
-    approx8: jax.Array,      # [N_pad, d] int8 / [N_pad, d/2] int4-packed
+    approx8: jax.Array,      # [N_pad, d] int8 / [N_pad, d/2] int4-packed,
+                             # or [N_pad / pack, pack * w] (gather_rows)
     m_scale: jax.Array,      # [N_pad] f32
     m_vsq: jax.Array,        # [N_pad] f32
     valid: jax.Array,        # [N_pad] bool
-    base: jax.Array,         # [capacity, d] raw store buffer
+    base: jax.Array,         # [capacity, d] raw store buffer, or
+                             # [capacity / pack, pack * d] (gather_rows)
     base_sqnorm: jax.Array,  # [capacity] f32
     r0: int,
     r1: int,

@@ -721,6 +721,25 @@ def exact_rerank_gathered(
     return top_s, jnp.take_along_axis(cand_ids, pos, axis=1)
 
 
+def gather_rows(b: jax.Array, rows: jax.Array, d: int) -> jax.Array:
+    """Logical rows `rows` [...] of a row store as placed for a gather,
+    `[n / pack, pack * d]` (parallel/mesh.py `row_pack`; the mesh raw
+    slab, and on one chip the raw store and the int8 mirror at a width
+    that is no multiple of 128) -> [..., d]: super-row `row // pack` off
+    the row-major array, then sub-row `row % pack`, so no instruction
+    reads the whole store. `pack` 1, a plain `[n, d]`, is the plain
+    gather."""
+    pack = b.shape[1] // d
+    if pack == 1:
+        return b[rows]
+    sup = b[rows // pack]  # [..., pack * d]
+    sub = (rows % pack)[..., None]
+    vecs = sup[..., :d]
+    for j in range(1, pack):
+        vecs = jnp.where(sub == j, sup[..., j * d:(j + 1) * d], vecs)
+    return vecs
+
+
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def exact_rerank(
     queries: jax.Array,     # [B, d] (store dtype)
@@ -733,11 +752,13 @@ def exact_rerank(
     """Exact re-scoring of candidate docids against the raw device buffer.
 
     One row gather + batched matvec; recovers exact ordering (and exact
-    user-facing scores) on top of ADC approximations.
+    user-facing scores) on top of ADC approximations. `base` may be the
+    store as placed for a gather, `[capacity / pack, pack * d]`
+    (`gather_rows`).
     """
     with jax.named_scope("rerank"):
         safe = jnp.maximum(cand_ids, 0)
-        vecs = base[safe]  # [B, r, d]
+        vecs = gather_rows(base, safe, queries.shape[1])  # [B, r, d]
         vsq = base_sqnorm[safe]  # [B, r]
         dots = jax.lax.dot_general(
             queries, vecs, (((1,), (2,)), ((0,), (0,))),

@@ -15,7 +15,8 @@ Layouts (built by parallel/mesh.py):
             The chip lays a minor dimension under 128 lanes out
             column-major; a row gather then copies the whole shard, in
             every dispatch. Whole 128-lane super-rows are row-major as
-            placed; `_gather_rows` gathers `id // pack`, keeps `id % pack`.
+            placed; `ops/ivf.py` `gather_rows` gathers `id // pack`, keeps
+            `id % pack`.
 """
 
 from __future__ import annotations
@@ -31,6 +32,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from vearch_tpu.engine.types import MetricType
 from vearch_tpu.ops import kmeans as km
 from vearch_tpu.ops.distance import brute_force_search, dot_precision, sqnorms
+from vearch_tpu.ops.ivf import gather_rows as _gather_rows
 from vearch_tpu.ops.perf_model import register_jit
 from vearch_tpu.parallel import mesh as mesh_lib
 
@@ -140,22 +142,6 @@ def _int8_search_fn(mesh: Mesh, r: int, metric: MetricType,
         f"sharded.int8[{_mesh_tag(mesh)},r{r},{metric.name},{storage}]",
         run,
     )
-
-
-def _gather_rows(b, rows, d: int):
-    """Logical rows `rows` [...] of a raw slab as placed,
-    `[n / pack, pack * d]` (mesh.row_pack) -> [..., d]: super-row
-    `row // pack` off the row-major slab, then sub-row `row % pack`, so
-    no instruction reads the whole shard. `pack` 1 is the plain gather."""
-    pack = b.shape[1] // d
-    if pack == 1:
-        return b[rows]
-    sup = b[rows // pack]  # [..., pack * d]
-    sub = (rows % pack)[..., None]
-    vecs = sup[..., :d]
-    for j in range(1, pack):
-        vecs = jnp.where(sub == j, sup[..., j * d:(j + 1) * d], vecs)
-    return vecs
 
 
 def _rerank_tail(b, bsqn, q, cand_i, shard, k: int,
