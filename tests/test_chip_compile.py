@@ -174,10 +174,11 @@ def test_fused_scan_rerank_compiles(one_chip, widths, b, r):
     assert len(big) <= 1, big
     if b > 8:
         assert len(big) == 1 and matrix <= temp < 1.25 * matrix, (big, temp)
-    # the widest sort or `TopK` is over the block maxima or the r * BLOCK
-    # gathered scores of a query, never over a row
-    assert 0 < _widest_sort_input(compiled, b) <= max(
-        n // ivf_ops.BLOCK, r * ivf_ops.BLOCK)
+    # the widest sort or `TopK` is over the block maxima or the 16 r
+    # group maxima of a query, never over a row or the r * BLOCK
+    # gathered scores
+    assert 0 < _widest_sort_input(compiled, b) \
+        <= perf_model.select_width(r, n) < r * ivf_ops.BLOCK
 
 
 def _probe_args(S, b, nlist, cap, n_valid):
@@ -373,9 +374,10 @@ def test_three_stage_refinement_compiles_without_a_whole_store_copy(
         written = _score_sized(compiled, b, n, "f32")
         assert len(written) == 1 and matrix <= temp, (written, temp)
     # stage 0 selects r0 = 512 through `_blocked_topk`: the widest sort
-    # is over the r0 * BLOCK gathered scores of a query, never a row
-    assert 0 < _widest_sort_input(compiled, b) <= max(
-        n // ivf_ops.BLOCK, GIST_R0 * ivf_ops.BLOCK)
+    # is over the 16 r0 group maxima of a query (8,192), never a row or
+    # the r0 * BLOCK gathered scores (65,536)
+    assert 0 < _widest_sort_input(compiled, b) \
+        <= perf_model.select_width(GIST_R0, n) == 8_192
 
 
 def test_three_stage_refinement_on_plain_layouts_copies_both_stores(
@@ -479,6 +481,10 @@ def test_mesh_fused_program_compiles_for_four_chips(topo, one_chip, widths):
                              widths["n_store"]))
     assert 0.20 < per_device / single < 0.30, (per_device, single)
     assert "all-gather" in compiled.as_text()
+    # a shard's selection sorts no wider than the one-chip program's
+    local_n = n_mirror // 4
+    assert 0 < _widest_sort_input(compiled, 64) \
+        <= perf_model.select_width(RERANK, local_n)
 
 
 # benchmark/configs/deep10m-mesh4-ivfpq.json: 96-d rows over the 4 chips
@@ -544,3 +550,6 @@ def test_deep_mesh_program_compiles_for_four_chips(topo, widths, rows, b):
     assert set(shard_sized) <= set(written), shard_sized
     matrix = perf_model.scan_peak_bytes(b, local_n)
     assert matrix <= temp < 1.25 * matrix, (temp, matrix)
+    # a shard's selection: the block maxima or the 16 r group maxima
+    assert 0 < _widest_sort_input(compiled, b) \
+        <= perf_model.select_width(RERANK, local_n)

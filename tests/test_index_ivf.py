@@ -328,6 +328,74 @@ def test_select_topk_on_tied_scores(b):
         assert len(set(i[row][live[row]].tolist())) == live[row].sum()
 
 
+def _selection_input(pattern, b, n, r, rng):
+    """[b, n] f32 scores that put the top-r of a row where a level of
+    `_blocked_topk` could lose it: 128-score blocks, then groups of 8
+    consecutive lanes of a gathered block."""
+    from vearch_tpu.ops.ivf import BLOCK, GROUP
+
+    x = rng.standard_normal((b, n)).astype(np.float32)
+    nblk = n // BLOCK
+    if pattern == "one_block":
+        # a docid-ordered cluster: the whole top-r in adjacent blocks,
+        # every group of them full of top scores
+        x[:, 5 * BLOCK:5 * BLOCK + r] += 100.0
+    elif pattern == "few_groups":
+        # the third group of r / 4 blocks raised: twice the r / 8
+        # groups that could hold the top-r, eight top scores each, and
+        # half of them must lose
+        for j in rng.choice(nblk, r // 4, replace=False):
+            x[:, j * BLOCK + 2 * GROUP:j * BLOCK + 3 * GROUP] += 100.0
+    elif pattern == "one_lane":
+        # lane 17 of 2 r blocks: one top score a block and a group,
+        # more raised blocks than the first level keeps
+        x[:, rng.choice(nblk, 2 * r, replace=False) * BLOCK + 17] += 100.0
+    elif pattern == "ties":
+        # nine values: the r-th score ties with thousands
+        x = rng.integers(0, 9, (b, n)).astype(np.float32)
+    elif pattern == "few_finite":
+        live = rng.choice(n, r - 50, replace=False)
+        keep = x[:, live]
+        x[:] = -np.inf
+        x[:, live] = keep
+    elif pattern == "inf_blocks":
+        # r / 2 live blocks: half the gathered blocks are all -inf
+        dead = np.ones(nblk, bool)
+        dead[rng.choice(nblk, r // 2, replace=False)] = False
+        x.reshape(b, nblk, BLOCK)[:, dead] = -np.inf
+    else:
+        assert pattern == "random"
+    return x
+
+
+@pytest.mark.parametrize("pattern", [
+    "random", "one_block", "few_groups", "one_lane", "ties",
+    "few_finite", "inf_blocks"])
+@pytest.mark.parametrize("r", [128, 256, 512])
+@pytest.mark.parametrize("b", [5, 8, 64])
+def test_select_topk_is_exact_at_every_level(b, r, pattern):
+    """`_select_topk` against numpy's sort of the row, at the fewest
+    blocks from which r selects in levels: the same multiset of scores
+    in descending order, ids that hold exactly those scores, none
+    twice, masked slots -1."""
+    import jax
+    import jax.numpy as jnp
+
+    from vearch_tpu.ops.ivf import BLOCK, _select_topk
+
+    n = BLOCK * 4 * r
+    x = _selection_input(pattern, b, n, r, np.random.default_rng(b * r))
+    s, i = map(np.asarray, jax.jit(_select_topk, static_argnums=1)(
+        jnp.asarray(x), r))
+    np.testing.assert_array_equal(s, -np.sort(-x, axis=1)[:, :r])
+    live = np.isfinite(s)
+    assert np.all(i[~live] == -1) and np.all(i[live] >= 0)
+    rows = np.arange(b)[:, None]
+    np.testing.assert_array_equal(x[rows, np.maximum(i, 0)][live], s[live])
+    for row in range(b):
+        assert len(set(i[row][live[row]].tolist())) == live[row].sum()
+
+
 @pytest.mark.parametrize("scan", ["int4", "binary"])
 def test_other_full_scans_select_their_exact_top_r(scan):
     """int4 and the 1-bit stage-0 scan hand their own [B, N] scores to

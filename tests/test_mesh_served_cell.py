@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from benchmark import cells, check, corpus, data, loadgen
+from vearch_tpu.ops import perf_model
 
 CELL = "deep10m-mesh4.b64x4-closed"
 ROWS, B, SEED = 20_000, 64, 2_718_281_829
@@ -127,6 +128,13 @@ def test_the_dispatch_span_carries_launch_and_rows_and_place_its_bytes(world):
     assert kernel.trace_id == place.trace_id
     assert kernel.tags["rows"] == B - 4 and kernel.tags["bucket_rows"] == B
     assert 0 < kernel.tags["launch_us"] * 1e3 <= kernel.t1_ns - kernel.t0_ns
+    # the selection of a SHARD's rows: at this size the plain row
+    index = world.engine.indexes["emb"]
+    shard_rows = index._mirror._sh_cache.capacity(
+        index._serving_mesh(None), index.indexed_count) // len(jax.devices())
+    assert kernel.tags["select_width"] == shard_rows \
+        == perf_model.select_width(
+            world.cfg["search"]["index_params"]["rerank"], shard_rows)
     # at least this request's own query batch went up during the phase
     assert place.tags["bytes"] >= B * world.cfg["dimension"] * 4
     assert place.t1_ns <= kernel.t0_ns + 1_000_000

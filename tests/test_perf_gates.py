@@ -15,6 +15,8 @@ comes only from a chip run, see PERF.md):
   state the index publishes.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -192,8 +194,8 @@ def test_serving_path_is_chosen_from_what_the_index_observes(
 def test_graft_entry_returns_the_served_program():
     """The driver's compile check (`__graft_entry__.entry()`) lowers
     what the one-chip cells serve, under the module name their device
-    trace shows, with the two-stage selection in it: three `top_k`s
-    (block maxima, gathered blocks, rerank)."""
+    trace shows, with the selection's two levels in it: four `top_k`s
+    (block maxima, group maxima, the chosen groups' scores, rerank)."""
     import jax
 
     import __graft_entry__
@@ -202,7 +204,7 @@ def test_graft_entry_returns_the_served_program():
     text = jax.jit(fn).lower(*args).as_text()
     assert text.startswith("module @jit_int8_scan_rerank ")
     assert "@int8_scan_candidates" in text and "@exact_rerank" in text
-    assert text.count("chlo.top_k") == 3
+    assert text.count("chlo.top_k") == 4
     assert len(args) == 7 and args[5].shape == (args[1].shape[0], 128)
 
 
@@ -847,15 +849,46 @@ def test_full_scan_materializes_score_matrix():
     assert perf_model.scan_traffic_bytes(n_pad, d) == n_pad * d
 
 
+def _topk_widths(jaxpr):
+    """Columns of the operand of every `top_k` of a jaxpr, nested ones
+    included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "top_k":
+            yield eqn.invars[0].aval.shape[-1]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _topk_widths(sub)
+
+
 def test_blockmax_selection_matches_kernel_constants():
-    # mirrors ops/ivf.py _select_topk: r blocks of BLOCK (128) scores,
-    # never more blocks than exist, so stage 2 sorts r * 128 scores a
-    # query: 32,768 at the benchmark's rerank 256
+    # mirrors ops/ivf.py _select_topk: r blocks of BLOCK (128) scores
+    # gathered, never more blocks than exist; from r = GROUP_MIN_R on
+    # their r * 128 scores are selected from in groups of GROUP (8), so
+    # the widest sort after the gather takes the 16 r group maxima:
+    # 4,096 at the benchmark's rerank 256, 8,192 at the three-stage
+    # program's r0 = 512, not 32,768 and 65,536
     assert perf_model.BLOCK == ivf_ops.BLOCK == 128
+    assert (ivf_ops.GROUP, ivf_ops.GROUP_MIN_R) == (8, 64)
     assert perf_model.blockmax_selected_blocks(128, 1_000_448) == 128
     assert perf_model.blockmax_selected_blocks(256, 1_000_448) \
         * perf_model.BLOCK == 32_768
     assert perf_model.blockmax_selected_blocks(128, 2048) == 16
+    assert perf_model.select_width(256, 1_000_448) == 7_816  # N / 128
+    assert perf_model.select_width(256, 1_000_064) == 7_813
+    assert perf_model.select_width(512, 500_224) == 8_192  # 16 r0
+    assert perf_model.select_width(512, 1_000_448) == 8_192
+    assert perf_model.select_width(256, 262_144) == 4_096  # 16 r
+    assert perf_model.select_width(256, 8_192) == 8_192  # the plain row
+    # the model follows the code: the widest `top_k` operand of the
+    # traced selection, on either side of every rule it has
+    for r, n_pad in [(256, 1_000_448), (512, 500_224), (512, 262_144),
+                     (512, 262_016), (128, 65_536), (128, 65_408),
+                     (128, 65_536 + 40), (64, 65_536), (16, 131_072),
+                     (132, 131_072), (100, 131_072), (300, 200)]:
+        jaxpr = jax.make_jaxpr(
+            lambda x, r=r: ivf_ops._select_topk(x, r))(
+                jax.ShapeDtypeStruct((8, n_pad), jnp.float32))
+        assert max(_topk_widths(jaxpr.jaxpr)) \
+            == perf_model.select_width(r, n_pad), (r, n_pad)
 
 
 # -- gate 4: HBM footprint model ---------------------------------------------

@@ -123,12 +123,21 @@ class World:
         return (0.45 * self.base[a[keep]] + 0.55 * self.base[b[keep]]
                 ).astype(np.float32)
 
-    def refine_stats(self) -> dict:
+    def partition_stats(self) -> dict:
         from vearch_tpu.cluster import rpc
 
         (part,) = rpc.call(self.ps.addr, "GET",
                            "/ps/stats")["partitions"].values()
-        return part["refine"]["fields"]["emb"]
+        return part
+
+    def refine_stats(self) -> dict:
+        return self.partition_stats()["refine"]["fields"]["emb"]
+
+    def select_stats(self) -> dict:
+        """{site tag: {select_width: dispatches}}, empty before the
+        first full-scan dispatch."""
+        return (self.partition_stats()["select"] or {"fields": {}}
+                )["fields"].get("emb", {})
 
 
 @pytest.fixture(scope="module")
@@ -333,3 +342,36 @@ def test_the_dispatch_is_stamped_and_the_place_span_says_what_is_placed(
     assert {s: after["stage_rows"][s] - before["stage_rows"][s]
             for s in ("binary", "int8", "exact")} == {
         "binary": ROWS * B, "int8": 512 * B, "exact": 256 * B}
+
+
+@pytest.mark.parametrize("tag,index_params", [
+    ("binary_refine_rerank", {}),
+    ("fused_scan_rerank", {"stage0": "off"})])
+def test_a_full_scan_dispatch_says_how_wide_its_selection_sorts(
+        world, tag, index_params):
+    """`kernel.{tag}` of the three-stage dispatch and of the int8 chain
+    (the same index with `stage0: off`) carries `select_width`, the
+    scores a query that the widest sort of the program's `_select_topk`
+    takes (`perf_model.select_width`; at these 8,192 padded rows the
+    plain row, at the cell's 500,224 the 16 x r0 group maxima), and
+    `/ps/stats` `partitions.<pid>.select` counts the dispatch under it."""
+    from vearch_tpu.cluster import tracing
+
+    w = world
+    n_pad = w.index._bits._h8.shape[0]
+    r = {"binary_refine_rerank": 512, "fused_scan_rerank": 256}[tag]
+    width = perf_model.select_width(r, n_pad)
+    assert width == n_pad == 8_192
+    assert perf_model.select_width(512, 500_224) == 16 * 512
+    before = w.select_stats().get(tag, {}).get(str(width), 0)
+    out = w.client.search(
+        corpus.DB, w.space,
+        vectors=[{"field": "emb", "feature": w.queries[:B]}], limit=w.k,
+        fields=[], profile=True, cache=False,
+        index_params={**w.params, **index_params})
+    (part,) = out["profile"]["partitions"].values()
+    assert part["dispatches"]["tags"] == [tag]
+    kernel = max((s for s in tracing.snapshot()
+                  if s.name == f"kernel.{tag}"), key=lambda s: s.t1_ns)
+    assert kernel.tags["select_width"] == width
+    assert w.select_stats()[tag][str(width)] == before + 1

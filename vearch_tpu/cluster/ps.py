@@ -3320,6 +3320,10 @@ class PSServer:
                     # searches, rows scored a stage, r0 / r1, device
                     # bytes of the planes, the int8 rows, the raw store
                     "refine": self._refine_info_safe(eng),
+                    # full-scan dispatches per field and site, by the
+                    # scores a query that the widest sort of the
+                    # program's selection takes (`select_width`)
+                    "select": self._select_info_safe(eng),
                     # tiered storage (HBM slab cache / host-RAM tiers /
                     # prefetch) — the doctor's prefetch-effectiveness
                     # check reads these blocks
@@ -3347,6 +3351,13 @@ class PSServer:
     def _refine_info_safe(eng) -> dict | None:
         try:
             return eng.refine_info()
+        except Exception:
+            return None
+
+    @staticmethod
+    def _select_info_safe(eng) -> dict | None:
+        try:
+            return eng.select_info()
         except Exception:
             return None
 

@@ -1330,8 +1330,12 @@ class Engine:
               # query uploaded); the rest of the window waits for the
               # device. Only the sites that stamp it carry it.
               **({"launch_us": int((t_launch - t0) * 1e6)}
-                 if t_launch is not None else {})}]
-            for tag, t0, t1, t_launch, rows, bucket_rows in capture.events
+                 if t_launch is not None else {}),
+              # a full-scan site: the scores a query that the widest
+              # sort of the program's selection takes
+              **({"select_width": width} if width is not None else {})}]
+            for tag, t0, t1, t_launch, rows, bucket_rows, width
+            in capture.events
             if t1 is not None
         )
         spans.extend(
@@ -1395,6 +1399,15 @@ class Engine:
         /ps/stats); None when no field has served such a search."""
         fields = {name: info for name, index in self.indexes.items()
                   if (info := index.refine_info()) is not None}
+        return {"fields": fields} if fields else None
+
+    def select_info(self) -> dict[str, Any] | None:
+        """The full-scan dispatches of each vector field by site and by
+        how wide the widest sort of the program's selection was
+        (index/ivf.py `select_info`; surfaced in /ps/stats); None when
+        no field has made one."""
+        fields = {name: info for name, index in self.indexes.items()
+                  if (info := index.select_info()) is not None}
         return {"fields": fields} if fields else None
 
     def tiering_info(self) -> dict[str, Any] | None:

@@ -125,6 +125,13 @@ class VectorIndex(abc.ABC):
         every other index and before the first such search."""
         return None
 
+    def select_info(self) -> dict[str, Any] | None:
+        """Full-scan dispatches by site and by how wide the widest sort
+        of the program's selection was (index/ivf.py IVFPQ and what
+        derives from it), None for every other index and before the
+        first such dispatch."""
+        return None
+
     def tiering_info(self) -> dict[str, Any] | None:
         """Tiered-storage summary (per-tier hit/miss/pin counters,
         residency bytes — see docs/TIERING.md), None when this index

@@ -201,7 +201,7 @@ class IVFRaBitQIndex(IVFPQIndex):
             # the mmap + coalesced-readahead path (tiering/readahead.py
             # via store.get_rows) — the raw base never enters HBM
             t0 = time.monotonic()
-            ivf_ops.note_dispatch("binary_refine_scan")
+            self._note_full_scan("binary_refine_scan", r0, n_pad)
             _, cand_i = binary_ops.binary_refine_candidates(
                 qd, planes, p_scale, p_vsq, approx8, m_scale, m_vsq,
                 valid, r0, r1, metric, self.mirror_storage,
@@ -232,7 +232,7 @@ class IVFRaBitQIndex(IVFPQIndex):
              "plane_bytes": self._bits.placed_bytes(),
              "mirror_bytes": self._mirror.placed_bytes()},
             request_only=True)
-        ivf_ops.note_dispatch("binary_refine_rerank")
+        self._note_full_scan("binary_refine_rerank", r0, n_pad)
         scores, ids = binary_ops.binary_refine_rerank(
             qd, planes, p_scale, p_vsq, approx8, m_scale, m_vsq, valid,
             base, base_sqnorm, r0, r1, k,
@@ -293,7 +293,8 @@ class IVFRaBitQIndex(IVFPQIndex):
         qd, b = mesh_lib.shard_queries(mesh, np.asarray(q, np.float32))
         ivf_ops.note_mesh_phase("place", t_place0, time.monotonic())
         t0 = time.monotonic()
-        ivf_ops.note_dispatch("sharded_binary_refine_rerank")
+        self._note_full_scan("sharded_binary_refine_rerank", r0,
+                             cap // int(mesh.shape["data"]))
         scores, ids = sharded_binary_refine(
             mesh, planes, p_scale, p_vsq, a8, m_scale, m_vsq, valid_sh,
             base, base_sqn, qd, r0, r1, min(k, r1),

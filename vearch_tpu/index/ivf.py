@@ -44,6 +44,7 @@ from vearch_tpu.ops import kmeans as km
 from vearch_tpu.ops import perf_model
 from vearch_tpu.ops import pq as pq_ops
 from vearch_tpu.ops.distance import to_device_mask
+from vearch_tpu.tools import lockcheck
 
 
 #: rows of one piece of a bulk absorb: the default training sample's
@@ -616,6 +617,28 @@ class IVFPQIndex(_IVFBase):
         ).lower()
         self._mirror = Int8Mirror(store.dimension,
                                   storage=self.mirror_storage)
+        # full-scan dispatches: {site tag: {select_width: dispatches}}
+        self._select: dict[str, dict[int, int]] = {}
+        self._select_lock = lockcheck.make_lock("index_select_info")
+
+    def _note_full_scan(self, tag: str, r: int, n_pad: int) -> None:
+        """`note_dispatch` of a full-scan site, which hands `_select_topk`
+        an [B, n_pad] score matrix at depth r: the dispatch carries, and
+        `select_info` counts, how wide the widest sort of that selection
+        is (`perf_model.select_width`: fixed by the shapes the bucket's
+        program is compiled for, no property of the data)."""
+        width = perf_model.select_width(r, n_pad)
+        with self._select_lock:
+            by_width = self._select.setdefault(tag, {})
+            by_width[width] = by_width.get(width, 0) + 1
+        ivf_ops.note_dispatch(tag, select_width=width)
+
+    def select_info(self) -> dict[str, Any] | None:
+        """{site tag: {select_width: dispatches}} of the full-scan
+        dispatches so far; None before the first."""
+        with self._select_lock:
+            return {tag: {str(w): n for w, n in sorted(by_width.items())}
+                    for tag, by_width in self._select.items()} or None
 
     @staticmethod
     def _norm_mesh_serving(value) -> str:
@@ -884,7 +907,7 @@ class IVFPQIndex(_IVFBase):
                 # (two dispatches paid launch latency twice and
                 # round-tripped nothing for it)
                 base, base_sqnorm, _ = self.store.device_buffer()
-                ivf_ops.note_dispatch("fused_scan_rerank")
+                self._note_full_scan("fused_scan_rerank", max(r, k), n_pad)
                 scores, ids = ivf_ops.int8_scan_rerank(
                     jnp.asarray(q), approx8, scale, vsq, valid,
                     base, base_sqnorm, max(r, k), k,
@@ -899,7 +922,7 @@ class IVFPQIndex(_IVFBase):
                 if self.mirror_storage == "int8"
                 else ivf_ops.int4_scan_candidates
             )
-            ivf_ops.note_dispatch("scan")
+            self._note_full_scan("scan", max(r, k), n_pad)
             cand_s, cand_i = scan(
                 jnp.asarray(q), approx8, scale, vsq, valid,
                 max(r, k), metric,
@@ -1082,14 +1105,14 @@ class IVFPQIndex(_IVFBase):
             assign_sh = self._assign_sharded(mesh, n)
         qd, b = mesh_lib.shard_queries(mesh, np.asarray(q, np.float32))
         r = min(self._rerank_depth(k, params), max(n, 1))
+        local_n = cap // int(mesh.shape["data"])  # a shard's rows
         if path != "ivfpq_mesh_scan":
             base, base_sqn, _ = self.store.device_buffer_sharded(mesh)
             # rows a device row of the raw shard as placed (row_pack)
             note_place(raw_pack=base.shape[1] // qd.shape[1])
-            ivf_ops.note_dispatch(
+            self._note_full_scan(
                 "sharded_probe_scan_rerank" if probe
-                else "sharded_fused_scan_rerank"
-            )
+                else "sharded_fused_scan_rerank", max(r, k), local_n)
             scores, ids = sharded_ivf_search(
                 mesh, cents, assign_sh, a8, scale, vsq, valid_sh,
                 base, base_sqn, qd, max(r, k),
@@ -1101,7 +1124,7 @@ class IVFPQIndex(_IVFBase):
             scores, ids = jax.device_get((scores, ids))
             return self._pad_to_k(scores[:b], ids[:b], k)
         note_place()
-        ivf_ops.note_dispatch("sharded_scan")
+        self._note_full_scan("sharded_scan", max(r, k), local_n)
         cand_s, cand_i = sharded_int8_search(
             mesh, a8, scale, vsq, valid_sh, qd, max(r, k), metric,
             storage=self.mirror_storage,

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 from vearch_tpu.engine.types import MetricType
 from vearch_tpu.ops.distance import dot_precision, sqnorms
-from vearch_tpu.ops.perf_model import register_jit
+from vearch_tpu.ops.perf_model import BLOCK, GROUP, GROUP_MIN_R, register_jit
 
 NEG_INF = float("-inf")
 
@@ -90,8 +90,8 @@ class DispatchCapture:
         # probe scan: "pallas" | "xla") — the profile reports it so a
         # reader can tell which program a dispatch tag really launched
         self.kernels: dict[str, str] = {}
-        # [tag, start, end | None, launched | None, rows, bucket_rows],
-        # stamps in monotonic seconds — consumers
+        # [tag, start, end | None, launched | None, rows, bucket_rows,
+        # select_width | None], stamps in monotonic seconds — consumers
         # (engine._record_dispatch_trace) anchor to the epoch via
         # utils.mono_us when emitting spans
         self.events: list[list] = []
@@ -117,12 +117,13 @@ class DispatchCapture:
         # note_phase, replayed under their own names
         self.phases: list[tuple[str, float, float, dict | None]] = []
 
-    def note(self, tag: str, kernel: str | None = None) -> None:
+    def note(self, tag: str, kernel: str | None = None,
+             select_width: int | None = None) -> None:
         now = time.monotonic()
         if self.events and self.events[-1][2] is None:
             self.events[-1][2] = now
         self.events.append([tag, now, None, None, self.rows,
-                            self.bucket_rows])
+                            self.bucket_rows, select_width])
         if kernel is not None:
             self.kernels[tag] = kernel
 
@@ -170,7 +171,11 @@ def end_capture() -> DispatchCapture | None:
     return cap
 
 
-def note_dispatch(tag: str, kernel: str | None = None) -> None:
+def note_dispatch(tag: str, kernel: str | None = None,
+                  select_width: int | None = None) -> None:
+    """One device-program launch. `select_width`: for a full-scan site,
+    `perf_model.select_width` of the program it launches (the scores a
+    query that the widest sort of `_select_topk` takes there)."""
     if _dispatch_ledger is not None:
         _dispatch_ledger.append(tag)
     obs = _dispatch_observer
@@ -178,7 +183,7 @@ def note_dispatch(tag: str, kernel: str | None = None) -> None:
         obs(tag)
     cap = getattr(_capture_tls, "capture", None)
     if cap is not None:
-        cap.note(tag, kernel)
+        cap.note(tag, kernel, select_width)
 
 
 # Optional phase observer (the PS installs one): takes the windows
@@ -473,9 +478,6 @@ def ivfpq_candidates(
     return best_s, jnp.where(jnp.isfinite(best_s), best_i, -1)
 
 
-BLOCK = 128  # block of the two-stage top-k: the lanes of one score tile
-
-
 @functools.partial(jax.jit, static_argnames=("r", "metric"))
 def int8_scan_candidates(
     queries: jax.Array,    # [B, d] f32
@@ -522,7 +524,8 @@ def _select_topk(scores: jax.Array, r: int) -> tuple[jax.Array, jax.Array]:
     One `lax.top_k` over the row is a multi-pass sort of the whole
     matrix: it serves below 4 * max(r, 128) blocks (65,536 rows at
     r <= 128) and an N that is no multiple of BLOCK; from there on
-    `_blocked_topk` does (rows of equal score may order otherwise)."""
+    `_blocked_topk` does (rows of equal score may order otherwise).
+    `perf_model.select_width` says how wide the widest sort is."""
     n_pad = scores.shape[1]
     r = min(r, n_pad)
     if n_pad % BLOCK == 0 and n_pad // BLOCK >= 4 * max(r, 128):
@@ -535,22 +538,35 @@ def _select_topk(scores: jax.Array, r: int) -> tuple[jax.Array, jax.Array]:
     return top_s, jnp.where(jnp.isfinite(top_s), ids, -1)
 
 
+def _pick(table: jax.Array, col: jax.Array) -> jax.Array:
+    """`table[b, col[b, i]]` for a [B, n] i32 table and [B, r] columns,
+    by compare-and-sum over the n columns: an element gather of B * r
+    entries costs the chip ten times this loop (0.17 ms against 0.01 at
+    B=64)."""
+    hit = col[:, :, None] == jnp.arange(table.shape[1])
+    return jnp.sum(jnp.where(hit, table[:, None, :], 0), axis=2)
+
+
 def _blocked_topk(scores: jax.Array, r: int) -> tuple[jax.Array, jax.Array]:
-    """Two-stage top-r of a [B, N] f32 matrix whose N is a multiple of
-    BLOCK, r <= N:
+    """Exact top-r of a [B, N] f32 matrix whose N is a multiple of
+    BLOCK, r <= N, by levels of maxima:
 
     1. `block_max`: the maximum of every BLOCK-wide block of a row, in
-       f32, and the r blocks with the largest maxima.
-    2. `select`: those r blocks gathered, and `lax.top_k` over their
-       r * BLOCK scores (32,768 at rerank 256).
+       f32, and the r blocks with the largest maxima, gathered.
+    2. `select`, from r = GROUP_MIN_R on: the r * BLOCK gathered scores
+       of a row in groups of GROUP (`_grouped_topk`), the r groups with
+       the largest maxima brought together, and `lax.top_k` over their
+       GROUP * r scores. Below it, `lax.top_k` over the r * BLOCK.
 
-    The result is the exact top-r of the row: a score among the r
-    largest has a block maximum no smaller than itself, so fewer than r
-    blocks can rank before its block.
+    The result is the exact top-r of the row at either level: a score
+    among the r largest has a group maximum no smaller than itself, so
+    fewer than r groups can rank before its group, whatever the groups
+    are. No sort of the program is wider than
+    max(N / BLOCK, r * BLOCK / GROUP) scores a row (16 r).
 
     LAYOUT. On the TPU the score fusion writes [B, N] f32 in tiles of 8
-    rows x 128 lanes, physically [B/8, N/128, 8, 128]. Both stages read
-    it through exactly that 4-d view, so the transpose below is a
+    rows x 128 lanes, physically [B/8, N/128, 8, 128]. The first level
+    reads it through exactly that 4-d view, so the transpose below is a
     bitcast and the matrix is never copied: the maxima are a lane
     reduction, and a gathered block is one 128-lane row of one tile.
     Any other blocked view ([B, N/BLOCK, BLOCK], block-major, bf16) is
@@ -572,15 +588,48 @@ def _blocked_topk(scores: jax.Array, r: int) -> tuple[jax.Array, jax.Array]:
         _, top_blocks = jax.lax.top_k(bmax, nb)  # [B, nb]
         row = jnp.arange(b)[:, None]
         gathered = tiles[row // sub, top_blocks, row % sub, :]
-        top_s, pos = jax.lax.top_k(gathered.reshape(b, nb * BLOCK), r)
-        # block of each winner by compare-and-sum over the nb chosen
-        # blocks: an element gather of B*r block ids costs the chip
-        # ten times this loop (0.17 ms against 0.01 at B=64)
-        chosen = (pos // BLOCK)[:, :, None] == jnp.arange(nb)
-        ids = jnp.sum(jnp.where(chosen, top_blocks[:, None, :], 0),
-                      axis=2) * BLOCK + pos % BLOCK
+        if nb == r and r >= GROUP_MIN_R and r % GROUP == 0:
+            top_s, slot, lane = _grouped_topk(gathered, r)
+            ids = _pick(top_blocks, slot) * BLOCK + lane
+        else:
+            top_s, pos = jax.lax.top_k(gathered.reshape(b, nb * BLOCK), r)
+            ids = _pick(top_blocks, pos // BLOCK) * BLOCK + pos % BLOCK
         ids = ids.astype(jnp.int32)
     return top_s, ids
+
+
+def _grouped_topk(
+    gathered: jax.Array, r: int,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The second level of `_blocked_topk`: top-r of the [B, r, BLOCK]
+    gathered blocks of a row as (scores, block slot, lane), each [B, r].
+
+    A group is GROUP consecutive lanes of one gathered block, 16 to a
+    block. `top_k` over the 16 r group maxima picks r groups; the block
+    of each is gathered once more, from the gathered array (a 128-lane
+    row, as the first gather's: the chip has no cheaper unit), its 15
+    other groups are masked out, and the last `top_k` runs over the
+    GROUP * r scores that are left. Slot and lane come back by
+    arithmetic from the chosen group.
+
+    Measured on one TPU v5e, `_select_topk` alone at B=64 (PERF.md
+    section 6, PR 35): 2.23 -> 1.28 ms at 1,000,448 x r 256, 3.60 ->
+    1.42 at 500,224 x r 512, 1.66 -> 1.02 at r 128, 1.11 -> 0.87 at
+    r 64, 0.66 -> 0.62 at r 32: GROUP_MIN_R is 64, the least depth with
+    a clear gain. Forms that bring the groups' scores together
+    otherwise (8 x 128 tiles gathered and a lane chosen; sublane groups
+    read through a transposed copy) cost 0.02 to 0.34 ms more."""
+    b = gathered.shape[0]
+    per = BLOCK // GROUP  # groups of one block
+    gmax = jnp.max(gathered.reshape(b, r, per, GROUP), axis=3)
+    _, groups = jax.lax.top_k(gmax.reshape(b, r * per), r)  # slot * per + g
+    blocks = gathered[jnp.arange(b)[:, None], groups // per]  # [B, r, BLOCK]
+    mine = (groups % per)[:, :, None, None] == jnp.arange(per)[:, None]
+    members = jnp.max(jnp.where(
+        mine, blocks.reshape(b, r, per, GROUP), NEG_INF), axis=2)
+    top_s, pos = jax.lax.top_k(members.reshape(b, r * GROUP), r)
+    group = _pick(groups, pos // GROUP)
+    return top_s, group // per, group % per * GROUP + pos % GROUP
 
 
 def unpack_int4(packed: jax.Array) -> jax.Array:

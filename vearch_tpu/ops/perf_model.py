@@ -34,8 +34,11 @@ import threading
 import time
 from typing import Any, Callable
 
-# must match ops/ivf.py BLOCK (tests/test_perf_gates.py holds them equal)
-BLOCK = 128
+# the selection of ops/ivf.py (`_select_topk` takes them from here)
+BLOCK = 128  # its first level: the lanes of one score tile
+GROUP = 8  # its second level: lanes of one group of a gathered block
+#: the depth from which the second level is taken (`_grouped_topk`)
+GROUP_MIN_R = 64
 
 F32 = 4
 
@@ -418,10 +421,28 @@ def tier_h2d_bytes(misses: int, cap: int, d: int) -> int:
 
 
 def blockmax_selected_blocks(r: int, n_pad: int) -> int:
-    """Blocks of BLOCK scores whose r * BLOCK entries stage 2 of
-    ops/ivf.py _select_topk sorts, per query: r of them (the top-r of a
-    row lies in the r blocks of largest maxima), never more than exist."""
+    """Blocks of BLOCK scores that the first level of ops/ivf.py
+    _blocked_topk gathers, per query: r of them (the top-r of a row
+    lies in the r blocks of largest maxima), never more than exist."""
     return min(min(r, n_pad), max(n_pad // BLOCK, 1))
+
+
+def select_width(r: int, n_pad: int) -> int:
+    """Scores a query that the widest sort of ops/ivf.py _select_topk
+    takes at depth r over n_pad columns: the row itself where plain
+    `lax.top_k` serves; else the N / BLOCK block maxima or what follows
+    the gather, whichever is wider: the r * BLOCK gathered scores, or,
+    where the second level engages, the r * BLOCK / GROUP group maxima
+    (GROUP * r scores after them). tests/test_chip_compile.py
+    holds the compiled programs to it, and every `kernel.{tag}` span of
+    a full-scan site carries it."""
+    r = min(r, n_pad)
+    nblk = n_pad // BLOCK
+    if n_pad % BLOCK or nblk < 4 * max(r, 128):
+        return n_pad
+    if r >= GROUP_MIN_R and r % GROUP == 0:
+        return max(nblk, r * BLOCK // GROUP, r * GROUP)
+    return max(nblk, r * BLOCK)
 
 
 def scan_peak_bytes(b: int, n_pad: int) -> int:
