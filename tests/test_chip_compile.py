@@ -151,6 +151,27 @@ def _widest_sort_input(compiled, b):
     return widest
 
 
+def _fold(text, b, width):
+    """A probe scan's fold of [b, width] = [b, r + tile] scores a step, in
+    a compiled program's text (fused computations included): for each
+    sort over [b, width] the operands that no `iota` produces, and every
+    `gather` out of an `s32[b, width]` operand (ids taken at the
+    positions a `top_k` picked)."""
+    instructions = re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$",
+        text, re.M)
+    made = {name: (shape, op) for name, shape, op, _ in instructions}
+    cat = rf"\[{b},{width}\]"
+    sorts, gathers = [], []
+    for name, shape, op, rest in instructions:
+        operands = re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])
+        if op == "sort" and re.search(cat, shape):
+            sorts.append([o for o in operands if made[o][1] != "iota"])
+        elif op == "gather" and re.match(r"s32" + cat, made[operands[0]][0]):
+            gathers.append(name)
+    return sorts, gathers
+
+
 @pytest.mark.parametrize("b,r", [(8, RERANK), (64, RERANK), (256, RERANK),
                                  (1024, RERANK), (64, RERANK_SHALLOW)])
 def test_fused_scan_rerank_compiles(one_chip, widths, b, r):
@@ -208,9 +229,14 @@ def test_xla_probe_scan_and_rerank_compile(one_chip, widths):
     """`probe_kernel: "xla"` and the exact rerank both probe kernels
     hand their candidates to."""
     S = _shapes(one_chip)
-    _report("ivfpq_candidates[B=64]", ivf_ops.ivfpq_candidates.lower(
+    compiled = ivf_ops.ivfpq_candidates.lower(
         *_probe_args(S, 64, widths["nlist"], 512, widths["n_store"]),
-        widths["nprobe"], RERANK, L2).compile())
+        widths["nprobe"], RERANK, L2).compile()
+    _report("ivfpq_candidates[B=64]", compiled)
+    # the fold as in the IVFFLAT scan: one sort that carries the ids
+    sorts, gathers = _fold(compiled.as_text(), 64, RERANK + 512)
+    assert len(sorts) == 1 and len(sorts[0]) >= 2 and not gathers, (
+        sorts, gathers)
     _report("exact_rerank[B=64]", ivf_ops.exact_rerank.lower(
         S((64, D), jnp.float32), S((64, RERANK), jnp.int32),
         S((widths["n_store"], D), jnp.float32),
@@ -250,7 +276,13 @@ def test_ivfflat_probe_scan_compiles_in_tiles(one_chip, widths, b, cap):
     module has no docid-indexed `pred[n_store]` operand, and no gather
     of single mask elements out of a 1-d operand (the 131,072 lookups
     a step that were 77 % of the program on the chip, PERF.md section
-    6)."""
+    6).
+
+    The fold sorts [B, r + tile] scores a step ONCE, and that sort
+    carries the ids beside its key: no gather takes [B, r] ids out of
+    the `s32[B, r + tile]` list afterwards (`top_k` and
+    `take_along_axis`: 0.166 ms of a 0.420 ms step on the chip, PERF.md
+    section 6)."""
     nlist, nprobe = 1024, 32
     tile = ivf_ops.probe_tile(cap, D * 4)
     assert tile == min(cap, 2048) == ivf_ops.probe_tile_rows(D * 4)
@@ -276,6 +308,9 @@ def test_ivfflat_probe_scan_compiles_in_tiles(one_chip, widths, b, cap):
         (op_shape,) = set(re.findall(
             rf"%?{re.escape(operand)} = (pred\[[\d,]+\])", text))
         assert op_shape == f"pred[{nlist * cap // tile},{tile}]", op_shape
+    sorts, gathers = _fold(text, b, RERANK + tile)
+    assert len(sorts) == 1 and len(sorts[0]) >= 2, sorts  # key and ids
+    assert not gathers, gathers
     step_gather = b * tile * D
     written = [
         (name, shape) for name, shape, op in re.findall(

@@ -628,7 +628,7 @@ def _plain_probe_scan(q, cents, vecs, ids, ok, nprobe, r, metric, probes):
 
 @pytest.mark.parametrize("case", [
     "all_alive", "mask_60pct", "mask_2pct", "all_false", "several_tiles",
-    "padded_probe_slots", "inner_product"])
+    "padded_probe_slots", "inner_product", "tied_rows"])
 def test_probe_scan_under_the_slot_major_mask_is_the_plain_reference(
         case, monkeypatch):
     """`ivfflat_candidates` takes its validity mask in the table's own
@@ -637,15 +637,25 @@ def test_probe_scan_under_the_slot_major_mask_is_the_plain_reference(
     reference's whatever share of the slots the mask lets through,
     however many tiles a list is scanned in, with `-1` probe slots and
     under inner product. The padding holds rows (`_probe_table`): a
-    slot scored where the mask says false would be served."""
+    slot scored where the mask says false would be served. Rows of equal
+    score come in the order they were scanned in (`tied_rows`), whatever
+    their ids."""
     import jax.numpy as jnp
 
     from vearch_tpu.ops import ivf as ivf_ops
 
     rng = np.random.default_rng(33)
     nlist, d, b, nprobe, r = 8, 16, 5, 3, 16
-    cap = 48 if case == "several_tiles" else 32
+    tiled = case in ("several_tiles", "tied_rows")
+    cap = 48 if tiled else 32
     cents, vecs, ids = _probe_table(rng, nlist, cap, d)
+    # one row four times: twice in the first tile of list 0, once in its
+    # second tile, once in list 5, each copy under a smaller id than the
+    # one scanned before it
+    copies = ([0, 0, 0, 5], [3, 7, 19, 2])
+    if case == "tied_rows":
+        vecs[copies] = vecs[0, 3]
+        ids[copies] = np.sort(ids[copies])[::-1]
     n = int(ids.max()) + 1
     share = {"mask_60pct": 0.6, "mask_2pct": 0.02, "all_false": 0.0}
     valid = rng.random(n) < share.get(case, 1.0)
@@ -660,7 +670,11 @@ def test_probe_scan_under_the_slot_major_mask_is_the_plain_reference(
     if case == "padded_probe_slots":
         probes = np.array([[2, -1, 5], [-1, -1, 0], [7, 1, -1],
                            [0, -1, -1], [3, 4, 6]], np.int32)
-    if case == "several_tiles":
+    if case == "tied_rows":
+        # query 0 is the row: its four copies tie for the first places
+        q[0] = vecs[0, 3]
+        probes = np.array([[0, 5, 1]] * b, np.int32)
+    if tiled:
         monkeypatch.setattr(ivf_ops, "PROBE_SLICE_BYTES", 16 * d * 4)
         assert ivf_ops.probe_tile(cap, d * 4) == 16  # three steps a list
     scores, out = ivf_ops.ivfflat_candidates(
@@ -682,6 +696,89 @@ def test_probe_scan_under_the_slot_major_mask_is_the_plain_reference(
         assert found.any() and not found.all()
     else:
         assert found.all()
+    if case == "tied_rows":  # scan order, which is descending id order
+        assert np.asarray(out)[0, :4].tolist() == ids[copies].tolist()
+        assert len(set(np.asarray(scores)[0, :4].tolist())) == 1
+
+
+def _plain_fold(best_s, best_i, scores, ids):
+    """The running top list after one block, in plain numpy: a stable
+    descending order over [best | block], the best r kept."""
+    s_cat = np.concatenate([best_s, scores], axis=1)
+    i_cat = np.concatenate([best_i, ids], axis=1)
+    order = np.argsort(-s_cat, axis=1, kind="stable")[:, :best_s.shape[1]]
+    return (np.take_along_axis(s_cat, order, axis=1),
+            np.take_along_axis(i_cat, order, axis=1))
+
+
+@pytest.mark.parametrize("case", [
+    "r256_c2048", "small", "ties_in_block", "ties_across", "neg_inf"])
+def test_fold_is_a_stable_descending_order(case):
+    """`_fold_topk`, one scan step's fold, against a stable descending
+    argsort of [best | block]: higher score first, of equal scores the
+    running list before the block and a lower slot before a higher one,
+    `-inf` (masked slots, `-1` ids) last. The running list is what a
+    fold hands on: sorted, and at the scan's start all `-inf`."""
+    import jax.numpy as jnp
+
+    from vearch_tpu.ops import ivf as ivf_ops
+
+    rng = np.random.default_rng(37)
+    b, r, c = (4, 256, 2048) if case == "r256_c2048" else (3, 4, 6)
+    n_ids = b * (r + c)
+    ids = rng.permutation(n_ids).astype(np.int32).reshape(b, r + c)
+    if case in ("r256_c2048", "ties_in_block", "ties_across"):
+        # few distinct values (none of them zero): ties everywhere
+        s = (rng.integers(-6, 6, (b, r + c)) + 0.5).astype(np.float32)
+    else:
+        s = rng.standard_normal((b, r + c)).astype(np.float32)
+    if case == "ties_in_block":
+        s[:, :r] = rng.standard_normal((b, r)) * 100
+    if case == "ties_across":
+        s[:, r:r + 2] = s[:, 1:3]  # the block repeats the list's scores
+    if case in ("r256_c2048", "neg_inf"):
+        masked = rng.random((b, r + c)) < 0.4
+        s[masked], ids[masked] = -np.inf, -1
+    if case == "neg_inf":
+        s[0, :r], ids[0, :r] = -np.inf, -1  # the scan's first step
+    order = np.argsort(-s[:, :r], axis=1, kind="stable")
+    best_s = np.take_along_axis(s[:, :r], order, axis=1)
+    best_i = np.take_along_axis(ids[:, :r], order, axis=1)
+    block_s, block_i = s[:, r:], ids[:, r:]
+    got_s, got_i = ivf_ops._fold_topk(
+        (jnp.asarray(best_s), jnp.asarray(best_i)),
+        jnp.asarray(block_s), jnp.asarray(block_i))
+    want_s, want_i = _plain_fold(best_s, best_i, block_s, block_i)
+    assert want_i.shape == (b, r)
+    np.testing.assert_array_equal(np.asarray(got_s), want_s)
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
+    if case in ("r256_c2048", "neg_inf"):
+        assert (np.asarray(got_i)[np.isneginf(want_s)] == -1).all()
+
+
+def test_fold_orders_signed_zeros_and_infinities_as_top_k():
+    """The fold orders floats as `lax.top_k` does, by their bits: 0.0
+    before -0.0, inf first, -inf last; and its scores come back bit for
+    bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from vearch_tpu.ops import ivf as ivf_ops
+
+    values = np.array([0.0, -0.0, np.inf, -np.inf, 1.5, -1.5, 1e-45,
+                       -1e-45], np.float32)
+    rng = np.random.default_rng(7)
+    s = values[rng.integers(0, len(values), (6, 40))]
+    ids = rng.permutation(6 * 40).astype(np.int32).reshape(6, 40)
+    r = 12
+    top_s, pos = jax.lax.top_k(jnp.asarray(s), r)
+    want_i = np.take_along_axis(ids, np.asarray(pos), axis=1)
+    got_s, got_i = ivf_ops._fold_topk(
+        (jnp.asarray(s[:, :r]), jnp.asarray(ids[:, :r])),
+        jnp.asarray(s[:, r:]), jnp.asarray(ids[:, r:]))
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
+    assert (np.asarray(got_s).view(np.int32)
+            == np.asarray(top_s).view(np.int32)).all()
 
 
 # -- the slot-major mask's cache and the guarantees behind it -----------------

@@ -488,6 +488,9 @@ class PSServer:
         # matter how many spaces this node hosts. Exact per-space
         # numbers ride /ps/stats and the heartbeat usage block.
         self._accountant = accounting.install()
+        # the reserved shadow-recall space renders from the 1st scrape:
+        # its first sampled query must move values, not mint a label
+        self._accountant.touch(accounting.QUALITY_SPACE)
 
         def _usage(meter: str, scale: float = 1.0):
             return lambda: self._accountant.labelled(meter, scale)

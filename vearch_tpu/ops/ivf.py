@@ -15,10 +15,11 @@ Search structure: coarse top-nprobe as one matmul + top_k, then a
 ([B, cap, ...] — contiguous row DMA, the gather XLA handles well), scores
 it (matvec batch on MXU for IVFFLAT; LUT gather for IVFPQ), masks
 padding/deleted slots, and folds into a running [B, r] top-k via
-concat + top_k. Candidates then get an exact rerank against the raw
-device buffer — TPU keeps raw vectors resident anyway, so rerank is one
-more gather+matmul and buys back the PQ recall loss (the reference's
-fine-grained rerank via raw vectors, gamma_index_ivfpq.h).
+concat + one stable sort that carries the ids. Candidates then get an
+exact rerank against the raw device buffer — TPU keeps raw vectors
+resident anyway, so rerank is one more gather+matmul and buys back the
+PQ recall loss (the reference's fine-grained rerank via raw vectors,
+gamma_index_ivfpq.h).
 
 Everything is static-shaped: nprobe/k/cap are trace-time constants;
 per-request nprobe changes recompile once per distinct value (cached).
@@ -287,17 +288,44 @@ def _coarse_probes(
     return probes
 
 
+def _descending_key(scores: jax.Array) -> jax.Array:
+    """int32 keys whose ascending order is the descending order of the
+    f32 `scores` as `lax.top_k` compares them: by their bits, so -0.0
+    ranks below 0.0 and -inf last. `_key_scores` inverts it."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return ~jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def _key_scores(key: jax.Array) -> jax.Array:
+    bits = ~key
+    return jax.lax.bitcast_convert_type(
+        jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits), jnp.float32)
+
+
 def _fold_topk(
     best: tuple[jax.Array, jax.Array],
     scores: jax.Array,
     ids: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fold a new [B, c] candidate block into the running [B, r] top list."""
+    """Fold a new [B, c] candidate block into the running [B, r] top list.
+
+    Exactly `lax.top_k` over [best | block] and the ids at the positions
+    it picks: higher score first, of equal scores the earlier column
+    (the running list before the block, a lower slot before a higher
+    one), -inf last. It is ONE stable sort that carries the ids: `top_k`
+    returns positions, and taking the ids at them is an element gather
+    of B * r entries that cost the chip more than the sort itself
+    (0.166 against 0.113 ms a scan step at B=64, r 256, c 2048; the step
+    0.420 -> 0.288 ms, one TPU v5e, PERF.md section 6). The key is an
+    integer: sorted as floats, every compare would canonicalise -0.0
+    and NaN first (0.012 ms more a step)."""
     best_s, best_i = best
-    s_cat = jnp.concatenate([best_s, scores], axis=1)
+    r = best_s.shape[1]
+    key = _descending_key(jnp.concatenate([best_s, scores], axis=1))
     i_cat = jnp.concatenate([best_i, ids], axis=1)
-    top_s, pos = jax.lax.top_k(s_cat, best_s.shape[1])
-    return top_s, jnp.take_along_axis(i_cat, pos, axis=1)
+    key, i_cat = jax.lax.sort((key, i_cat), dimension=1, is_stable=True,
+                              num_keys=1)
+    return _key_scores(key[:, :r]), i_cat[:, :r]
 
 
 #: the widest slice one step of the IVFFLAT probe scan gathers per
