@@ -434,6 +434,108 @@ def test_three_stage_refinement_on_plain_layouts_copies_both_stores(
     assert temp > GIST_ROWS * GIST_D * 4
 
 
+# benchmark/configs/gist1m-960-hnsw.json: the same corpus, cosine,
+# efSearch 128 (the candidate depth r of HNSW's device scan); the raw
+# store holds exactly the restored rows, the int8 rows are 512-aligned
+HNSW_EF = 128
+COSINE, IP = MetricType.COSINE, MetricType.INNER_PRODUCT
+
+
+def _hnsw_args(S, b, rows, packed: bool):
+    """`int8_scan_rerank`'s arguments as `HNSWIndex._search_scan` hands
+    them over at `rows` x 960: the int8 rows as a matrix product wants
+    them (`[n, 960]`) and the raw store as placed for a gather
+    (`packed`: `[rows / 2, 1920]`) or plain."""
+    n = -(-rows // 512) * 512
+    pack = row_pack(GIST_D) if packed else 1
+    return n, (
+        S((b, GIST_D), jnp.float32), S((n, GIST_D), jnp.int8),
+        S((n,), jnp.float32), S((n,), jnp.float32), S((n,), jnp.bool_),
+        S((rows // pack, pack * GIST_D), jnp.float32),
+        S((rows,), jnp.float32))
+
+
+@pytest.mark.parametrize("b", [8, 64, 256])
+def test_hnsw_scan_compiles_without_a_whole_store_copy(one_chip, widths, b):
+    """The serving program of `gist1m-960-hnsw` (module
+    `jit_int8_scan_rerank`, cosine: an inner-product scan over the
+    normalised int8 rows, an exact cosine rerank of efSearch = 128 rows)
+    at the published 1,000,000 x 960, at every row bucket its mix warms
+    (64, 128 -> 256, 256) and the write check's 8. The rerank gathers
+    from the raw store as placed for a gather, `[N / 2, 1920]`: that
+    parameter is row-major, and no instruction is as large as the store
+    (handed `[N, 960]` the chip's column-major layout makes the gather
+    copy all 3.84 GB in every dispatch, below). Temp is the `[B, N]`
+    f32 score matrix once, and the whole fits the chip beside it."""
+    S = _shapes(one_chip)
+    n, args = _hnsw_args(S, b, GIST_ROWS, packed=True)
+    r = min(max(HNSW_EF, widths["fetch_k"]), GIST_ROWS)
+    compiled = ivf_ops.int8_scan_rerank.lower(
+        *args, r, widths["fetch_k"], scan_metric=IP, rerank_metric=COSINE,
+        storage="int8").compile()
+    args_b, temp = _report(f"int8_scan_rerank[hnsw 1M x 960, B={b}]",
+                           compiled)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_int8_scan_rerank")
+    entry = text.splitlines()[0]
+    assert f"f32[{GIST_ROWS // 2},{2 * GIST_D}]{{1,0:" in entry
+    assert _as_large_as_a_store(compiled, GIST_ROWS, GIST_D) == []
+    matrix = perf_model.scan_peak_bytes(b, n)
+    assert temp < 1.25 * matrix, (temp, matrix)
+    if b > 8:
+        written = _score_sized(compiled, b, n, "f32")
+        assert len(written) == 1 and matrix <= temp, (written, temp)
+    assert args_b + temp < 0.45 * HBM_BYTES
+    assert 0 < _widest_sort_input(compiled, b) \
+        <= perf_model.select_width(r, n) < r * ivf_ops.BLOCK
+
+
+def test_hnsw_scan_on_the_plain_store_copies_it(one_chip, widths):
+    """Why HNSW's rerank takes the store as super-rows: the same program
+    handed the plain `[N, 960]` store (what the two-program path before
+    it gathered from) lays it out column-major and copies it whole,
+    row-major, before the gather: an instruction as large as the store,
+    3.84 GB of temp beside the score matrix."""
+    _, args = _hnsw_args(_shapes(one_chip), 64, GIST_ROWS, packed=False)
+    compiled = ivf_ops.int8_scan_rerank.lower(
+        *args, HNSW_EF, widths["fetch_k"], scan_metric=IP,
+        rerank_metric=COSINE, storage="int8").compile()
+    _, temp = _report("int8_scan_rerank[hnsw B=64, plain store]", compiled)
+    entry = compiled.as_text().splitlines()[0]
+    assert f"f32[{GIST_ROWS},{GIST_D}]{{0,1:" in entry
+    copies = _as_large_as_a_store(compiled, GIST_ROWS, GIST_D)
+    assert len(copies) == 1 and copies[0].startswith("copy"), copies
+    assert temp > GIST_ROWS * GIST_D * 4
+
+
+@pytest.mark.parametrize("d,metrics", [(D, (L2, L2)), (768, (IP, COSINE))])
+def test_fused_site_packed_store_is_the_plain_one_at_whole_lanes(
+        one_chip, widths, d, metrics):
+    """IVFPQ's fused site asks the store for its gather placement
+    (`device_buffer(packed=True)`); at sift's 128 and cohere's 768
+    dimensions `row_pack` is 1 and that IS the plain buffer, the same
+    array: the program those cells compile is the one they compiled
+    before (the same arguments lower to the same text)."""
+    assert row_pack(d) == 1
+    store = RawVectorStore(d)
+    store.add(np.ones((1000, d), np.float32))
+    plain = store.device_buffer()
+    assert store.device_buffer(packed=True)[0] is plain[0]
+    S = _shapes(one_chip)
+    n, b, n_store = widths["n_mirror"], 64, widths["n_store"]
+    texts = []
+    for pack in (1, row_pack(d)):  # the plain store, the gather's
+        args = (S((b, d), jnp.float32), S((n, d), jnp.int8),
+                S((n,), jnp.float32), S((n,), jnp.float32),
+                S((n,), jnp.bool_), S((n_store // pack, pack * d),
+                                      jnp.float32),
+                S((n_store,), jnp.float32))
+        texts.append(ivf_ops.int8_scan_rerank.lower(
+            *args, RERANK, widths["fetch_k"], scan_metric=metrics[0],
+            rerank_metric=metrics[1], storage="int8").as_text())
+    assert texts[0] == texts[1]
+
+
 @pytest.mark.parametrize("step", ["train_kmeans", "assign_sample",
                                   "assign_all_rows", "train_pq",
                                   "encode_pq"])

@@ -208,6 +208,27 @@ def test_graft_entry_returns_the_served_program():
     assert len(args) == 7 and args[5].shape == (args[1].shape[0], 128)
 
 
+@pytest.mark.parametrize("path,params", [
+    ("hnsw_scan_fused", {}),
+    ("hnsw_scan_disk", {"store_type": "RocksDB", "graph": False}),
+    ("hnsw_graph", {"graph": True})])
+def test_hnsw_paths_launch_documented_dispatches(tmp_path, path, params):
+    """HNSW's device scan over a RAM store (`graph: auto`) is ONE program,
+    the fused int8 scan + exact rerank; over a disk store its rerank's
+    rows are gathered on the host between two; the host graph launches
+    nothing."""
+    from vearch_tpu.native import hnsw_graph
+
+    if path == "hnsw_graph" and not hnsw_graph.available():
+        pytest.skip("no native toolchain for the host graph")
+    eng, vecs = _build("HNSW", {"efSearch": 32, **params}, n=1000,
+                       data_dir=str(tmp_path))
+    ledger = _search(eng, vecs)
+    assert ledger.tags == perf_model.DOCUMENTED_DISPATCHES[path]
+    assert (eng.indexes["emb"]._graph is not None) == (path == "hnsw_graph")
+    eng.close()
+
+
 def test_ivfflat_and_flat_dispatch_counts():
     doc = perf_model.DOCUMENTED_DISPATCHES
     eng, vecs = _build("IVFFLAT", {

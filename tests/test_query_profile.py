@@ -121,8 +121,15 @@ def test_profile_dispatches_match_documented_per_ivfpq_path(tmp_path):
 
 def test_path_for_dispatches_reverse_lookup():
     doc = perf_model.DOCUMENTED_DISPATCHES
+    first: dict[tuple, str] = {}
     for path, tags in doc.items():
-        assert perf_model.path_for_dispatches(list(tags)) == path
+        first.setdefault(tuple(tags), path)
+        # a sequence two paths launch (HNSW's scan and IVFPQ's fused
+        # full scan, HNSW's graph and a cache hit) names the first
+        assert perf_model.path_for_dispatches(list(tags)) == \
+            first[tuple(tags)]
+    assert set(first.values()) == set(doc) - {
+        "hnsw_scan_fused", "hnsw_scan_disk", "hnsw_graph"}
     assert perf_model.path_for_dispatches(["nope"]) is None
     # the empty sequence is now a *documented* path: a cache hit
     # launches zero device programs by design

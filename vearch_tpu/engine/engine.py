@@ -569,12 +569,14 @@ class Engine:
         """Kick off a background train+absorb once the training threshold is
         crossed (reference: the Indexing thread trains when doc volume
         passes training_threshold, engine.cc:1106). CAS-style guard mirrors
-        the reference's IDLE->STARTING state machine (engine.cc:967)."""
+        the reference's IDLE->STARTING state machine (engine.cc:967).
+        An engine whose indexes train nothing (FLAT, HNSW) is built, i.e.
+        absorbed and marked INDEXED, at the same threshold."""
+        trains = any(idx.needs_training for idx in self.indexes.values())
         needs = [
             (name, idx)
             for name, idx in self.indexes.items()
-            if idx.needs_training
-            and not idx.trained
+            if (not idx.trained or not trains)
             and self.vector_stores[name].count >= self._training_threshold(idx)
         ]
         if not needs or self.status != IndexStatus.UNINDEXED:

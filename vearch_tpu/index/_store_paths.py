@@ -6,8 +6,9 @@ RocksDB): device-mirrored RAM stores and mmap'd disk stores
 reimplementing the disk case:
 
 - `rerank_against_store`: exact rerank of candidate ids — against the
-  HBM-resident raw buffer for RAM stores, or via a host mmap gather +
-  one [B, r, d] upload for disk stores;
+  HBM-resident raw buffer as placed for a row gather (`device_buffer(
+  packed=True)`) for RAM stores, or via a host mmap gather + one
+  [B, r, d] upload for disk stores;
 - `disk_brute_force`: chunked exact scan streaming the mmap through the
   device in fixed-shape chunks (the FLAT / pre-training fallback for
   beyond-RAM stores; fixed chunk shape = one XLA compile).
@@ -48,7 +49,7 @@ def rerank_against_store(
             jnp.asarray(q, jnp.float32), jnp.asarray(ci),
             jnp.asarray(vecs), k, metric,
         )
-    base, base_sqnorm, _ = store.device_buffer()
+    base, base_sqnorm, _ = store.device_buffer(packed=True)
     return ivf_ops.exact_rerank(
         jnp.asarray(q, dtype=base.dtype), cand_i, base, base_sqnorm,
         k, metric,

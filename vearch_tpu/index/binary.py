@@ -36,7 +36,7 @@ import numpy as np
 
 from vearch_tpu.engine.raw_vector import RawVectorStore
 from vearch_tpu.engine.types import IndexParams, MetricType
-from vearch_tpu.index.int8_mirror import Int8Mirror
+from vearch_tpu.index.int8_mirror import ABSORB_ROWS, Int8Mirror
 from vearch_tpu.index.ivf import IVFFlatIndex, IVFPQIndex
 from vearch_tpu.index.registry import register_index
 from vearch_tpu.ops import binary_scan as binary_ops
@@ -67,10 +67,6 @@ class BinaryIVFIndex(IVFFlatIndex):
         packed = np.asarray(batch, dtype=np.uint8)
         bits = np.unpackbits(packed, axis=1, count=self.store.dimension)
         return bits.astype(np.float32)
-
-
-#: rows `_absorb_rows` quantises at a time
-ABSORB_ROWS = 4096
 
 
 @register_index("IVFRABITQ")

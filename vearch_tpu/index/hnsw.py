@@ -30,10 +30,12 @@ import numpy as np
 
 from vearch_tpu.engine.raw_vector import RawVectorStore
 from vearch_tpu.engine.types import IndexParams, MetricType
+from vearch_tpu.index._store_paths import is_disk_store, rerank_against_store
 from vearch_tpu.index.base import VectorIndex
-from vearch_tpu.index.int8_mirror import Int8Mirror
+from vearch_tpu.index.int8_mirror import ABSORB_ROWS, Int8Mirror
 from vearch_tpu.index.registry import register_index
 from vearch_tpu.ops import ivf as ivf_ops
+from vearch_tpu.ops import perf_model
 from vearch_tpu.ops.distance import to_device_mask
 
 
@@ -52,7 +54,6 @@ class HNSWIndex(VectorIndex):
         self._graph = None
         mode = params.get("graph", "auto")
         if mode == "auto":
-            from vearch_tpu.index._store_paths import is_disk_store
             from vearch_tpu.native import hnsw_graph
 
             self.use_graph = is_disk_store(store) and hnsw_graph.available()
@@ -78,14 +79,19 @@ class HNSWIndex(VectorIndex):
             if upto <= self.indexed_count:
                 return
             start = self.indexed_count
-            rows = self._maybe_normalize(
-                np.asarray(self.store.host_view()[start:upto],
-                           dtype=np.float32)
-            )
+            host = self.store.host_view()
             if self._graph is not None:
-                self._graph.add(rows)
+                self._graph.add(self._maybe_normalize(
+                    np.asarray(host[start:upto], dtype=np.float32)))
             else:
-                self._mirror.append(rows, start=start)
+                # in pieces: after a restore the first search absorbs
+                # every row, and a whole normalised copy of a million
+                # 960-d rows is 3.84 GB before quantize_rows' temporaries
+                self._mirror.reserve(upto)
+                for lo in range(start, upto, ABSORB_ROWS):
+                    hi = min(lo + ABSORB_ROWS, upto)
+                    self._mirror.append(self._maybe_normalize(
+                        np.asarray(host[lo:hi], dtype=np.float32)), start=lo)
             self.indexed_count = upto
 
     def search(
@@ -95,13 +101,13 @@ class HNSWIndex(VectorIndex):
         valid_mask: np.ndarray | None,
         params: dict | None = None,
     ) -> tuple[np.ndarray, np.ndarray]:
-        self.absorb(self.store.count)
         p = params or {}
         ef = max(int(p.get("efSearch", p.get("ef_search", self.ef_search))), k)
-        q = self._maybe_normalize(np.asarray(queries, np.float32))
         if self._graph is not None:
+            self.absorb(self.store.count)
+            q = self._maybe_normalize(np.asarray(queries, np.float32))
             return self._search_graph(q, k, ef, valid_mask)
-        return self._search_scan(q, k, ef, valid_mask)
+        return self._search_scan(queries, k, ef, valid_mask)
 
     def _search_graph(
         self, q: np.ndarray, k: int, ef: int, valid_mask
@@ -123,24 +129,51 @@ class HNSWIndex(VectorIndex):
         return scores, ids.astype(np.int64)
 
     def _search_scan(
-        self, q: np.ndarray, k: int, ef: int, valid_mask
+        self, queries: np.ndarray, k: int, ef: int, valid_mask
     ) -> tuple[np.ndarray, np.ndarray]:
+        """Every row scored from its int8 form, the top `ef` reranked
+        exactly against the raw rows: ONE device program for a RAM store
+        (`int8_scan_rerank`, which gathers the `ef` rows from the store
+        as placed for a gather); a disk store's rows are gathered on the
+        host between a scan and a rerank program."""
+        # a RAM store's place phase: from the index's entry to the launch
+        # (the tail absorb, the mirror's flush, the raw store's placement,
+        # the mask, the query's normalisation and upload)
+        place = ivf_ops.phase("hnsw.place", request_only=True)
+        self.absorb(self.store.count)
+        qh = self._maybe_normalize(np.asarray(queries, np.float32))
+        q = jnp.asarray(qh)
         a8, scale, vsq = self._mirror.flush()
+        n_pad = a8.shape[0]
+        # cosine scans as IP over the normalised rows; the rerank scores
+        # the user-facing metric against the rows as stored
         metric = (
             MetricType.INNER_PRODUCT
             if self.metric is MetricType.COSINE
             else self.metric
         )
-        valid = to_device_mask(valid_mask, self.indexed_count, a8.shape[0])
-        cand_s, cand_i = ivf_ops.int8_scan_candidates(
-            jnp.asarray(q), a8, scale, vsq, valid,
-            min(ef, max(self.indexed_count, 1)), metric,
-        )
-        from vearch_tpu.index._store_paths import rerank_against_store
-
-        scores, ids = rerank_against_store(
-            self.store, q, cand_i, k, self.metric,
-        )
+        valid = to_device_mask(valid_mask, self.indexed_count, n_pad)
+        r = min(ef, max(self.indexed_count, 1))
+        if is_disk_store(self.store):
+            ivf_ops.note_dispatch("scan")
+            _, cand_i = ivf_ops.int8_scan_candidates(
+                q, a8, scale, vsq, valid, r, metric)
+            ivf_ops.note_dispatch("rerank")
+            scores, ids = rerank_against_store(
+                self.store, qh, cand_i, k, self.metric)
+        else:
+            base, base_sqnorm, _ = self.store.device_buffer(packed=True)
+            place.close({"ef": r, "rows": self.indexed_count,
+                         "mirror_bytes": self._mirror.placed_bytes(),
+                         "store_pack": base.shape[1] // self.store.dimension})
+            ivf_ops.note_dispatch(
+                "fused_scan_rerank",
+                select_width=perf_model.select_width(r, n_pad))
+            scores, ids = ivf_ops.int8_scan_rerank(
+                q, a8, scale, vsq, valid, base, base_sqnorm, r, k,
+                scan_metric=metric, rerank_metric=self.metric,
+            )
+            ivf_ops.capture_launched()
         scores, ids = jax.device_get((scores, ids))
         if scores.shape[1] < k:
             pad = k - scores.shape[1]

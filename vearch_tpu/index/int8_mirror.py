@@ -17,6 +17,10 @@ from vearch_tpu.ops import perf_model
 from vearch_tpu.parallel.mesh import row_pack
 from vearch_tpu.tools import lockcheck
 
+#: rows an index's absorb quantises at a time (index/binary.py,
+#: index/hnsw.py): each piece's `[rows, d]` f32 temporaries stay small
+ABSORB_ROWS = 4096
+
 
 def quantize_rows(rows: np.ndarray) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Per-row symmetric int8 quantization; returns (q8, scale, vsq)."""

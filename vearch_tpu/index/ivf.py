@@ -903,8 +903,8 @@ class IVFPQIndex(_IVFBase):
             if path == "ivfpq_full_fused":
                 # default hot path: scan + rerank as ONE device program
                 # (two dispatches paid launch latency twice and
-                # round-tripped nothing for it)
-                base, base_sqnorm, _ = self.store.device_buffer()
+                # round-tripped nothing for it); its rerank gathers rows
+                base, base_sqnorm, _ = self.store.device_buffer(packed=True)
                 self._note_full_scan("fused_scan_rerank", max(r, k), n_pad)
                 scores, ids = ivf_ops.int8_scan_rerank(
                     jnp.asarray(q), approx8, scale, vsq, valid,

@@ -137,6 +137,16 @@ DOCUMENTED_DISPATCHES: dict[str, list[str]] = {
     # candidate merge, sharded exact rerank + pmax — ONE shard_map
     # program (parallel/sharded.py sharded_binary_refine)
     "ivfrabitq_mesh_three_stage": ["sharded_binary_refine_rerank"],
+    # HNSW's device scan (RAM store, index/hnsw.py): int8 scan of every
+    # row + exact rerank of the top efSearch, ONE program (the IVFPQ
+    # fused full scan's own, `int8_scan_rerank`)
+    "hnsw_scan_fused": ["fused_scan_rerank"],
+    # HNSW's scan over a disk store: the rerank's rows gathered on the
+    # host between the two programs
+    "hnsw_scan_disk": ["scan", "rerank"],
+    # HNSW's host graph (a disk store with the native toolchain, or
+    # `graph: true`): the walk runs on the host, ZERO device programs
+    "hnsw_graph": [],
 }
 
 
@@ -144,7 +154,10 @@ def path_for_dispatches(tags: list[str]) -> str | None:
     """Reverse lookup: which documented serving path launched exactly
     this dispatch sequence? None when the sequence matches no documented
     path (e.g. a multi-field search concatenates several paths) — the
-    profile surface reports that as drift instead of guessing."""
+    profile surface reports that as drift instead of guessing. A
+    sequence several paths launch (HNSW's scan and IVFPQ's fused full
+    scan; HNSW's graph and a cache hit) names the first that documents
+    it: the same programs, so the same prediction."""
     seq = list(tags)
     for path, doc in DOCUMENTED_DISPATCHES.items():
         if seq == doc:
