@@ -250,6 +250,36 @@ def _flat_args(S, b, nlist, cap):
             S((nlist, cap), jnp.int32), S((nlist, cap), jnp.bool_))
 
 
+def _computations(text):
+    """{name: instruction lines} of every computation of a compiled
+    program's text, the entry included."""
+    comps, name, lines = {}, None, []
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            name, lines = m.group(1), []
+        elif line == "}" and name is not None:
+            comps[name], name = "\n".join(lines), None
+        elif name is not None:
+            lines.append(line)
+    return comps
+
+
+def _loop_bodies(text):
+    """Text of every `while` body of a compiled program and of every
+    computation it calls (fusions, reductions, comparators)."""
+    comps = _computations(text)
+    todo = re.findall(r"\bbody=%([\w.\-]+)", text)
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                               comps[name])
+    return "\n".join(comps[name] for name in sorted(seen))
+
+
 # benchmark/configs/sift1m-ivfflat.json: nlist 1024, nprobe 32, r 256.
 # cap: one tile (what ISSUE 32 expected of 1M rows), the 8192 the chip
 # run published (longest list 7,824, seed 3200000011), and a longer
@@ -259,8 +289,9 @@ def test_ivfflat_probe_scan_compiles_in_tiles(one_chip, widths, b, cap):
     """The serving program of `sift1m-ivfflat`: XLA module
     `jit_ivfflat_candidates` (what the benchmark finds it by on the
     device trace), its stages under their scopes, and no instruction
-    that writes more than ONE scan step's [B, tile, d] gather: no
-    [B, nprobe, cap, d], and no slice of the [nlist, cap, d] table.
+    that writes more than ONE scan step's [B, tile, d] gather, but for
+    the score matrix itself: no [B, nprobe, cap, d], and no slice of
+    the [nlist, cap, d] table.
 
     Untiled (a step gathering [B, cap, d] whole) the chip's compiler
     keeps the gather in one piece up to 1 MiB a slice (cap 2048: temp
@@ -268,21 +299,24 @@ def test_ivfflat_probe_scan_compiles_in_tiles(one_chip, widths, b, cap):
     table, copied in every step: at cap 10368 six `mini-gather-slice`
     results of [1024, 1792, 128] f32 and 4.7 GB of temp beside a 5.4 GB
     table (PERF.md section 6, PR 32). `probe_tile` keeps every step's
-    slice inside the 1 MiB, so temp stays under the step's own gather
-    plus the running top list whatever the longest list.
+    slice inside the 1 MiB.
 
     The validity mask arrives slot-major, `pred[nlist, cap]`, and a
-    step gathers it by list row as it gathers the ids (PR 33): the
+    step gathers it by list row as it gathers the norms: the
     module has no docid-indexed `pred[n_store]` operand, and no gather
     of single mask elements out of a 1-d operand (the 131,072 lookups
     a step that were 77 % of the program on the chip, PERF.md section
     6).
 
-    The fold sorts [B, r + tile] scores a step ONCE, and that sort
-    carries the ids beside its key: no gather takes [B, r] ids out of
-    the `s32[B, r + tile]` list afterwards (`top_k` and
-    `take_along_axis`: 0.166 ms of a 0.420 ms step on the chip, PERF.md
-    section 6)."""
+    The loop selects nothing: no `sort` or `TopK` in its body,
+    and no ids gathered there. A step writes its int32 keys into the
+    `s32[steps, B, tile]` score matrix in scan order; after the loop the
+    selection sorts no row wider than `perf_model.probe_select_width`
+    (block maxima, group maxima, what the last level keeps: never the
+    row), and no instruction copies the matrix into another layout or
+    transposes it (the compiler may move it whole into another memory
+    space, a `copy-start` that keeps its layout). Temp holds the matrix
+    beside at most two steps' gathers and the selection's working set."""
     nlist, nprobe = 1024, 32
     tile = ivf_ops.probe_tile(cap, D * 4)
     assert tile == min(cap, 2048) == ivf_ops.probe_tile_rows(D * 4)
@@ -292,7 +326,7 @@ def test_ivfflat_probe_scan_compiles_in_tiles(one_chip, widths, b, cap):
     _, temp = _report(f"ivfflat_candidates[B={b},cap={cap}]", compiled)
     text = compiled.as_text()
     assert text.startswith("HloModule jit_ivfflat_candidates")
-    for scope in ("coarse", "gather", "score", "fold"):
+    for scope in ("coarse", "gather", "score", "select"):
         assert f"/{scope}/" in text, scope
     entry = text.splitlines()[0]  # the module's own operands
     assert f"pred[{nlist},{cap}]" in entry
@@ -308,23 +342,45 @@ def test_ivfflat_probe_scan_compiles_in_tiles(one_chip, widths, b, cap):
         (op_shape,) = set(re.findall(
             rf"%?{re.escape(operand)} = (pred\[[\d,]+\])", text))
         assert op_shape == f"pred[{nlist * cap // tile},{tile}]", op_shape
-    sorts, gathers = _fold(text, b, RERANK + tile)
-    assert len(sorts) == 1 and len(sorts[0]) >= 2, sorts  # key and ids
-    assert not gathers, gathers
+    body = _loop_bodies(text)
+    assert "dynamic-update-slice(" in body  # a step's write, in place
+    assert not re.search(r" sort\(|\"TopK\"", body), "a fold in the loop"
+    assert not re.search(r"= s32\[[\d,]+\]\S* gather\(", body), \
+        "ids gathered a step"
+    n = nprobe * cap
+    assert 0 < _widest_sort_input(compiled, b) \
+        <= perf_model.probe_select_width(RERANK, n, tile) < n
+    steps = n // tile
+    matrix = f"s32[{steps},{b},{tile}]"
     step_gather = b * tile * D
-    written = [
-        (name, shape) for name, shape, op in re.findall(
-            r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(",
-            text, re.M)
-        if op not in ("bitcast", "parameter", "get-tuple-element", "tuple",
-                      "while")
-        and any(np.prod([int(x) for x in dims.split(",")]) > step_gather
-                for dims in re.findall(r"\b\w+\[([\d,]+)\]", shape))]
+    instructions = re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(",
+        text, re.M)
+    written, relaid = [], []
+    for name, shape, op in instructions:
+        if op in ("bitcast", "parameter", "get-tuple-element", "tuple",
+                  "while"):
+            continue
+        sizes = [(dt, np.prod([int(x) for x in dims.split(",")]))
+                 for dt, dims in re.findall(r"\b(\w+)\[([\d,]+)\]", shape)]
+        if matrix in shape:
+            layouts = {re.sub(r"S\(\d+\)", "", x) for x in re.findall(
+                rf"{re.escape(matrix)}(\{{[^}}]*\}})", shape)}
+            if op not in ("dynamic-update-slice", "fusion", "custom-call",
+                          "copy-start", "copy-done") or len(layouts) > 1:
+                relaid.append((name, op, shape))
+        elif op in ("copy", "copy-start", "transpose", "reshape",
+                    "concatenate", "slice", "pad") and any(
+                dt == "s32" and k >= steps * b * tile for dt, k in sizes):
+            relaid.append((name, op, shape))  # the keys in another shape
+        elif any(k > step_gather for _, k in sizes):
+            written.append((name, shape))
+    assert not relaid, relaid
     assert not written, written
-    top_list = b * RERANK * (4 + 4)
-    assert temp < 1.25 * (b * cap * D * 4 + top_list), temp
-    # stronger, as compiled today: under two steps' own gathers
-    assert temp < 2 * step_gather * 4 + (64 << 20), temp
+    # as compiled: the matrix in HBM (in fast memory at cap 2048), beside
+    # at most two steps' gathers and the selection's working set
+    matrix_bytes = steps * b * tile * 4
+    assert temp < matrix_bytes + 2 * step_gather * 4 + (64 << 20), temp
 
 
 # benchmark/configs/gist1m-960-ivfrabitq.json: 960-d, r0 512 (the
@@ -690,3 +746,49 @@ def test_deep_mesh_program_compiles_for_four_chips(topo, widths, rows, b):
     # a shard's selection: the block maxima or the 16 r group maxima
     assert 0 < _widest_sort_input(compiled, b) \
         <= perf_model.select_width(RERANK, local_n)
+
+
+def test_full_scan_programs_do_not_reach_the_probe_selection(monkeypatch):
+    """The probe scan's tie-exact selection (`ops/ivf.py`
+    `_scan_order_topk`, `perf_model.probe_select_blocked`) is its own
+    path: every full-scan program (`int8_scan_rerank`, the three-stage
+    `binary_refine_rerank`, the mesh program) traces, on the CPU, with
+    both made to raise, so they select through `_select_topk` alone.
+    At shapes no other test traces, so that nothing comes from a cache
+    of traces."""
+    def refuse(*_, **__):
+        raise AssertionError("a full-scan program reached the probe path")
+
+    monkeypatch.setattr(ivf_ops, "_scan_order_topk", refuse)
+    monkeypatch.setattr(ivf_ops, "probe_select_blocked", refuse)
+    monkeypatch.setattr(perf_model, "probe_select_blocked", refuse)
+    S = jax.ShapeDtypeStruct
+    b, n, d = 16, 128 * 1031, 128
+    for r in (10, RERANK):
+        ivf_ops.int8_scan_rerank.lower(
+            S((b, d), jnp.float32), S((n, d), jnp.int8),
+            S((n,), jnp.float32), S((n,), jnp.float32), S((n,), jnp.bool_),
+            S((n, d), jnp.float32), S((n,), jnp.float32),
+            r, 16, scan_metric=L2, rerank_metric=L2, storage="int8")
+    gd = 960
+    binary_ops.binary_refine_rerank.lower(
+        S((b, gd), jnp.float32), S((n, gd // 8), jnp.uint8),
+        S((n,), jnp.float32), S((n,), jnp.float32),
+        S((n // 2, 2 * gd), jnp.int8), S((n,), jnp.float32),
+        S((n,), jnp.float32), S((n,), jnp.bool_),
+        S((n // 2, 2 * gd), jnp.float32), S((n,), jnp.float32),
+        GIST_R0, RERANK, 16, scan_metric=L2, rerank_metric=L2,
+        storage="int8")
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(4, 1),
+                ("data", "query"))
+
+    def M(shape, dt, *spec):
+        return S(shape, dt, sharding=NamedSharding(mesh, P(*spec)))
+
+    sharded._ivf_search_fn(mesh, RERANK, 16, L2, L2, "int8", 0).lower(
+        M((4 * n, d), jnp.int8, "data", None),
+        M((4 * n,), jnp.float32, "data"), M((4 * n,), jnp.float32, "data"),
+        M((4 * n,), jnp.bool_, "data"),
+        M((4 * n, d), jnp.float32, "data", None),
+        M((4 * n,), jnp.float32, "data"), M((b, d), jnp.float32, "query",
+                                            None))

@@ -701,6 +701,150 @@ def test_probe_scan_under_the_slot_major_mask_is_the_plain_reference(
         assert len(set(np.asarray(scores)[0, :4].tolist())) == 1
 
 
+def _total_order_key(scores):
+    """The int32 key `lax.top_k` orders f32 scores by, in numpy: the
+    bits, negative ones flipped (-0.0 below 0.0, -inf below all)."""
+    bits = np.asarray(scores, np.float32).view(np.int32)
+    return np.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def _scan_order_reference(q, vecs, ids, ok, probes, r, metric):
+    """`ivfflat_candidates` in plain numpy over integer-valued rows,
+    where every float32 score is exact: each query's row of scores in
+    scan order (its probes in order, a list's slots in order; -inf where
+    the mask says false or the probe slot is -1), a stable argsort of
+    the row's descending total-order keys, the best r kept."""
+    b = q.shape[0]
+    pr = np.maximum(probes, 0)
+    v = vecs[pr]  # [B, nprobe, cap, d]
+    dots = np.einsum("bd,bpcd->bpc", q, v)
+    if metric is MetricType.L2:
+        s = -((q ** 2).sum(1)[:, None, None] - 2 * dots + (v ** 2).sum(-1))
+    else:
+        s = dots
+    live = ok[pr] & (probes >= 0)[:, :, None]
+    s = np.where(live, s, -np.inf).astype(np.float32).reshape(b, -1)
+    key = _total_order_key(s).astype(np.int64)
+    order = np.argsort(-key, axis=1, kind="stable")[:, :r]
+    top = np.take_along_axis(s, order, axis=1)
+    got = np.take_along_axis(ids[pr].reshape(b, -1), order, axis=1)
+    if r > s.shape[1]:
+        top = np.pad(top, ((0, 0), (0, r - s.shape[1])),
+                     constant_values=-np.inf)
+        got = np.pad(got, ((0, 0), (0, r - s.shape[1])))
+    return top, np.where(np.isfinite(top), got, -1)
+
+
+@pytest.mark.parametrize("case,b,nprobe,cap,r", [
+    ("blocked_cap4096", 64, 32, 4096, 256),
+    ("blocked_cap8192", 8, 32, 8192, 256),
+    ("blocked_r10", 5, 32, 4096, 10),
+    ("blocked_inner_product", 8, 32, 2048, 64),
+    ("blocked_padded_probe_slots", 8, 32, 4096, 256),
+    ("blocked_all_masked", 5, 32, 2048, 64),
+    ("top_k_r2048", 8, 32, 4096, 2048),
+    ("top_k_small_table", 5, 3, 48, 16),
+    ("top_k_depth_past_the_row", 8, 3, 40, 128),
+])
+def test_probe_selection_is_a_stable_argsort_of_scan_order(
+        case, b, nprobe, cap, r, monkeypatch):
+    """`ivfflat_candidates` selects after its loop, in levels of maxima
+    where the row is long enough (`perf_model.probe_select_blocked`),
+    else by one `top_k`: either way its ids and score bits are those of
+    a stable descending argsort of each query's scan-order total-order
+    keys. Rows in {-1, 0, 1} tie everywhere: inside a block of 128, across
+    blocks, groups of 8, scan steps and lists; one row is copied into
+    three lists and twice into one list's later tile, each copy under a
+    smaller id than the one scanned before it, so a tie broken by id or
+    by level order would serve another row. An exact match scores -0.0
+    under L2, and query 0 matches the copies alone."""
+    import jax.numpy as jnp
+
+    from vearch_tpu.ops import ivf as ivf_ops
+    from vearch_tpu.ops import perf_model
+
+    rng = np.random.default_rng(cap + r + b)
+    nlist, d = max(nprobe, 8) + 8, 4
+    monkeypatch.setattr(ivf_ops, "PROBE_SLICE_BYTES", 1024 * d * 4)
+    tile = ivf_ops.probe_tile(cap, d * 4)
+    blocked = perf_model.probe_select_blocked(min(r, nprobe * cap),
+                                              nprobe * cap, tile)
+    assert blocked == case.startswith("blocked"), (case, tile)
+    vecs = rng.integers(-1, 2, (nlist, cap, d)).astype(np.float32)
+    lengths = rng.integers(cap // 3, cap + 1, nlist)
+    lengths[:6] = cap
+    ids = np.full((nlist, cap), -1, np.int32)
+    slot_ok = np.arange(cap)[None, :] < lengths[:, None]
+    ids[slot_ok] = rng.permutation(int(slot_ok.sum())).astype(np.int32)
+    copies = ([0, 0, 0, 3, 5], [3, 7, cap - 2, 1, cap // 2])
+    vecs[copies] = 2.0  # a row no other slot holds
+    ids[copies] = np.sort(ids[copies])[::-1]
+    ok = slot_ok & (rng.random((nlist, cap)) < 0.7)
+    ok[copies] = True
+    if case == "blocked_all_masked":
+        ok[:] = False
+    metric = (MetricType.INNER_PRODUCT if "inner_product" in case
+              else MetricType.L2)
+    probes = np.stack([rng.permutation(nlist)[:nprobe]
+                       for _ in range(b)]).astype(np.int32)
+    probes[0, :3] = [0, 3, 5]  # query 0 scans the copies' lists first
+    q = rng.integers(-1, 2, (b, d)).astype(np.float32)
+    q[0] = vecs[0, 3]
+    if case == "blocked_padded_probe_slots":
+        probes[1, 5:] = -1
+        probes[2, :] = -1  # a query with nothing to scan
+        probes[3, ::2] = -1
+    s, i = map(np.asarray, ivf_ops.ivfflat_candidates(
+        jnp.asarray(q), jnp.zeros((nlist, d)), jnp.asarray(vecs),
+        jnp.asarray((vecs ** 2).sum(-1)), jnp.asarray(ids),
+        jnp.asarray(ok), nprobe, r, metric, probes=jnp.asarray(probes)))
+    want_s, want_i = _scan_order_reference(q, vecs, ids, ok, probes, r,
+                                           metric)
+    assert s.shape == i.shape == (b, r)
+    np.testing.assert_array_equal(i, want_i)
+    np.testing.assert_array_equal(s.view(np.int32), want_s.view(np.int32))
+    if case == "blocked_all_masked":
+        assert (i == -1).all() and np.isneginf(s).all()
+    elif case == "blocked_padded_probe_slots":
+        assert (i[2] == -1).all()
+    elif metric is MetricType.L2:  # the copies tie at -0.0, scan order
+        assert i[0, :5].tolist() == ids[copies].tolist()
+        assert np.signbit(s[0, :5]).all() and (s[0, :5] == 0).all()
+
+
+@pytest.mark.parametrize("s,b,t,r", [
+    (16, 8, 256, 16), (64, 8, 256, 64), (64, 5, 256, 64), (16, 64, 96, 100),
+])
+def test_scan_order_selection_orders_signed_zeros_and_infinities(s, b, t, r):
+    """The selection orders scores as `lax.top_k` does, by their bits:
+    inf first, 0.0 before -0.0, -inf last, and of equal keys the earlier
+    scan position first, in levels (the first two shapes; b=5 is no
+    multiple of the 8-row tile) and by one `top_k` (t=96 is no whole
+    block). Eight values only: every level meets ties."""
+    import jax
+    import jax.numpy as jnp
+
+    from vearch_tpu.ops import ivf as ivf_ops
+    from vearch_tpu.ops import perf_model
+
+    values = np.array([0.0, -0.0, np.inf, -np.inf, 1.5, -1.5, 1e-45,
+                       -1e-45], np.float32)
+    rng = np.random.default_rng(s * b + t)
+    scores = values[rng.integers(0, len(values), (s, b, t))]
+    assert perf_model.probe_select_blocked(r, s * t, t) == (t % 128 == 0)
+    key = jax.jit(ivf_ops._ascending_key)(jnp.asarray(scores))
+    top, pos = map(np.asarray, jax.jit(
+        ivf_ops._scan_order_topk, static_argnums=1)(key, r))
+    row = scores.transpose(1, 0, 2).reshape(b, s * t)
+    order = np.argsort(-_total_order_key(row).astype(np.int64), axis=1,
+                       kind="stable")[:, :r]
+    np.testing.assert_array_equal(pos, order)
+    np.testing.assert_array_equal(
+        np.asarray(ivf_ops._ascending_scores(jnp.asarray(top))).view(
+            np.int32),
+        np.take_along_axis(row, order, axis=1).view(np.int32))
+
+
 def _plain_fold(best_s, best_i, scores, ids):
     """The running top list after one block, in plain numpy: a stable
     descending order over [best | block], the best r kept."""

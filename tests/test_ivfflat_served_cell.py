@@ -22,6 +22,7 @@ import pytest
 
 from benchmark import cells, check, corpus, data, ivfflat_reference, loadgen
 from vearch_tpu.ops import ivf as ivf_ops
+from vearch_tpu.ops import perf_model
 
 CELL = "sift1m-ivfflat.b64x4-closed"
 ROWS, B, SEED = 20_000, 64, 3_141_592_653
@@ -418,3 +419,6 @@ def test_the_dispatch_span_carries_its_launch_and_the_probe_phase_its_tags(
     # the table was scanned in tiles: cap / 128 steps a probed list
     assert stats["cap"] > TILE_ROWS
     assert ivf_ops.probe_tile(stats["cap"], 128 * 4) == TILE_ROWS
+    # and its scores selected after the loop, no sort wider than this
+    assert probe.tags["select_width"] == perf_model.probe_select_width(
+        w.params["rerank"], 32 * stats["cap"], TILE_ROWS)

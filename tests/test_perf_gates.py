@@ -912,6 +912,44 @@ def test_blockmax_selection_matches_kernel_constants():
             == perf_model.select_width(r, n_pad), (r, n_pad)
 
 
+def _sort_widths(jaxpr):
+    """Columns of the operand of every `top_k` or `sort` of a jaxpr,
+    nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("top_k", "sort"):
+            yield eqn.invars[0].aval.shape[-1]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _sort_widths(sub)
+
+
+def test_probe_selection_width_matches_the_code():
+    """`perf_model.probe_select_width` is the widest `top_k` or `sort`
+    of ops/ivf.py `_scan_order_topk`, the IVFFLAT probe scan's selection
+    after its loop, on either side of every rule it has: levels of
+    maxima where a step's `tile` scores are whole 128-lane blocks and
+    the first level keeps at most half of them, the group level from
+    r = GROUP_MIN_R (r % GROUP == 0), else one `top_k` over the row of
+    `nprobe * cap` scores. At the benchmark's cell (nprobe 32, cap 8192,
+    tile 2048, r 256): the 4,096 group maxima, not the 262,144-score
+    row."""
+    assert perf_model.probe_select_width(256, 32 * 8192, 2048) == 4_096
+    assert perf_model.probe_select_width(256, 32 * 12288, 2048) == 4_096
+    assert perf_model.probe_select_width(256, 32 * 2048, 2048) == 4_096
+    assert perf_model.probe_select_width(2048, 32 * 8192, 2048) == 262_144
+    assert perf_model.probe_select_width(10, 32 * 8192, 2048) == 2_048
+    assert not perf_model.probe_select_blocked(16, 3 * 48, 48)
+    for r, steps, tile in [(256, 128, 2048), (256, 4, 256), (256, 4, 255),
+                           (256, 2, 256), (64, 1, 16384), (10, 8, 128),
+                           (100, 16, 128), (2048, 32, 1024), (16, 3, 48),
+                           (63, 64, 128), (64, 2, 128)]:
+        jaxpr = jax.make_jaxpr(
+            lambda k, r=r: ivf_ops._scan_order_topk(k, r))(
+                jax.ShapeDtypeStruct((steps, 8, tile), jnp.int32))
+        assert max(_sort_widths(jaxpr.jaxpr)) == \
+            perf_model.probe_select_width(r, steps * tile, tile), (
+                r, steps, tile)
+
+
 # -- gate 4: HBM footprint model ---------------------------------------------
 
 

@@ -521,15 +521,22 @@ class IVFFlatIndex(_IVFBase):
             else self.metric
         )
         host_probes = self._host_probes(q, nprobe)
+        depth = min(max(r, k), 2048)
+        cap = published["cap"]
         # the probe phase: from the index's entry to the launch (a
         # publish if one was due, the mask, host probe selection), with
         # what the program is about to scan: `nprobe` lists of `cap`
         # slots a query, `fill` of them holding a row, under a slot-
-        # major mask that was found again (`hit`) or `built`
+        # major mask that was found again (`hit`) or `built`, and the
+        # widest sort of its selection
         probe.close(
-            {"nprobe": nprobe, "cap": published["cap"],
+            {"nprobe": nprobe, "cap": cap,
              "fill": published["fill"], "mask": published["mask"],
-             "mask_ms": published["mask_ms"]})
+             "mask_ms": published["mask_ms"],
+             "select_width": perf_model.probe_select_width(
+                 depth, nprobe * cap, ivf_ops.probe_tile(
+                     cap, self.store.dimension
+                     * self.store.store_dtype.itemsize))})
         ivf_ops.note_dispatch("ivfflat_scan")
         scores, ids = ivf_ops.ivfflat_candidates(
             jnp.asarray(q, dtype=self.store.store_dtype),
@@ -539,7 +546,7 @@ class IVFFlatIndex(_IVFBase):
             bucket_ids,
             bucket_ok,
             nprobe,
-            min(max(r, k), 2048),
+            depth,
             metric,
             probes=None if host_probes is None
             else jnp.asarray(host_probes),

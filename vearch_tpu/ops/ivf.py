@@ -13,9 +13,11 @@ batch). Layout contract (built by index/ivf.py on publish):
 Search structure: coarse top-nprobe as one matmul + top_k, then a
 `lax.scan` over probe ranks. Each step gathers one bucket row per query
 ([B, cap, ...] — contiguous row DMA, the gather XLA handles well), scores
-it (matvec batch on MXU for IVFFLAT; LUT gather for IVFPQ), masks
-padding/deleted slots, and folds into a running [B, r] top-k via
-concat + one stable sort that carries the ids. Candidates then get an
+it (matvec batch on MXU for IVFFLAT; LUT gather for IVFPQ) and masks
+padding/deleted slots. IVFFLAT writes the step's scores into one
+scan-order score matrix and selects its top-r once after the loop;
+IVFPQ folds them into a running [B, r] top-k via concat + one stable
+sort that carries the ids. Candidates then get an
 exact rerank against the raw device buffer — TPU keeps raw vectors
 resident anyway, so rerank is one more gather+matmul and buys back the
 PQ recall loss (the reference's fine-grained rerank via raw vectors,
@@ -36,7 +38,8 @@ import jax.numpy as jnp
 
 from vearch_tpu.engine.types import MetricType
 from vearch_tpu.ops.distance import dot_precision, sqnorms
-from vearch_tpu.ops.perf_model import BLOCK, GROUP, GROUP_MIN_R, register_jit
+from vearch_tpu.ops.perf_model import (
+    BLOCK, GROUP, GROUP_MIN_R, probe_select_blocked, register_jit)
 
 NEG_INF = float("-inf")
 
@@ -288,18 +291,27 @@ def _coarse_probes(
     return probes
 
 
+def _ascending_key(scores: jax.Array) -> jax.Array:
+    """int32 keys in the order of the f32 `scores` as `lax.top_k`
+    compares them: by their bits, so -0.0 ranks below 0.0 and -inf
+    below every other score. `_ascending_scores` inverts it."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def _ascending_scores(key: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(
+        jnp.where(key < 0, key ^ 0x7FFFFFFF, key), jnp.float32)
+
+
 def _descending_key(scores: jax.Array) -> jax.Array:
     """int32 keys whose ascending order is the descending order of the
-    f32 `scores` as `lax.top_k` compares them: by their bits, so -0.0
-    ranks below 0.0 and -inf last. `_key_scores` inverts it."""
-    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
-    return ~jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    f32 `scores` (`_ascending_key` flipped). `_key_scores` inverts it."""
+    return ~_ascending_key(scores)
 
 
 def _key_scores(key: jax.Array) -> jax.Array:
-    bits = ~key
-    return jax.lax.bitcast_convert_type(
-        jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits), jnp.float32)
+    return _ascending_scores(~key)
 
 
 def _fold_topk(
@@ -384,7 +396,7 @@ def ivfflat_candidates(
 
     `bucket_ok` is the validity mask in the table's own order: true
     where the slot holds a row that is alive and passes the request's
-    filter, false on padding. A step reads it as it reads `bucket_ids`,
+    filter, false on padding. A step reads it as it reads the norms,
     one row a query: looked up by docid (`valid[ids]`) the same 131,072
     elements a step were 77 % of the program on the chip (PERF.md
     section 6, PR 33).
@@ -393,9 +405,17 @@ def ivfflat_candidates(
     tiles, one scan step each: the table is read as
     [nlist * tiles, tile, d] (a view: `cap` is a multiple of the tile)
     and a step gathers [B, tile, d]. Every slot of every probed list is
-    scored and folded either way; the stages carry `jax.named_scope`s
-    (`coarse`, `gather`, `score`, `fold`), as the full-scan programs'
-    do."""
+    scored, and a step writes its [B, tile] scores (masked slots -inf)
+    into the dispatch's score matrix in SCAN ORDER: probe rank, then
+    tile, then slot. After the loop ONE selection takes the top-r of
+    each query's row of it (`_scan_order_topk`): exactly `lax.top_k`
+    over the row, ties to the row scanned first, and only the r
+    winners' ids are looked up (position p is slot p % cap of probed
+    list p // cap). A top list folded in every step would sort the r
+    running candidates again 128-192 times a dispatch (54 % of the
+    program on the chip, PERF.md section 6). The stages carry
+    `jax.named_scope`s (`coarse`, `gather`, `score`, `select`), as the
+    full-scan programs' do."""
     b = queries.shape[0]
     if probes is None:
         with jax.named_scope("coarse"):
@@ -407,17 +427,11 @@ def ivfflat_candidates(
     nlist, cap, d = bucket_vecs.shape
     tile = probe_tile(cap, d * bucket_vecs.dtype.itemsize)
     tiles = cap // tile
-    bucket_vecs = bucket_vecs.reshape(nlist * tiles, tile, d)
-    bucket_ids = bucket_ids.reshape(nlist * tiles, tile)
-    bucket_sqnorm = bucket_sqnorm.reshape(nlist * tiles, tile)
-    bucket_ok = bucket_ok.reshape(nlist * tiles, tile)
+    table = bucket_vecs.reshape(nlist * tiles, tile, d)
+    table_sqnorm = bucket_sqnorm.reshape(nlist * tiles, tile)
+    table_ok = bucket_ok.reshape(nlist * tiles, tile)
 
-    init = (
-        jnp.full((b, r), NEG_INF, jnp.float32),
-        jnp.full((b, r), -1, jnp.int32),
-    )
-
-    def step(best, s):
+    def step(_, s):
         with jax.named_scope("gather"):
             c = probes[:, s // tiles]  # [B]
             # c == -1 marks a padded probe slot (host HNSW selection
@@ -426,10 +440,9 @@ def ivfflat_candidates(
             # the top-k
             cell_ok = c >= 0
             c = jnp.maximum(c, 0) * tiles + s % tiles
-            vecs = bucket_vecs[c]  # [B, tile, d]
-            ids = bucket_ids[c]  # [B, tile]
-            vsq = bucket_sqnorm[c]  # [B, tile]
-            ok = bucket_ok[c] & cell_ok[:, None]  # [B, tile]
+            vecs = table[c]  # [B, tile, d]
+            vsq = table_sqnorm[c]  # [B, tile]
+            ok = table_ok[c] & cell_ok[:, None]  # [B, tile]
         with jax.named_scope("score"):
             dots = jax.lax.dot_general(
                 queries, vecs, (((1,), (2,)), ((0,), (0,))),
@@ -440,14 +453,82 @@ def ivfflat_candidates(
                 scores = -(q_sq[:, None] - 2.0 * dots + vsq)
             else:
                 scores = dots
-            scores = jnp.where(ok, scores, NEG_INF)
-        with jax.named_scope("fold"):
-            return _fold_topk(best, scores, ids), None
+            return None, _ascending_key(jnp.where(ok, scores, NEG_INF))
 
-    (best_s, best_i), _ = jax.lax.scan(
-        step, init, jnp.arange(nprobe * tiles))
+    _, keys = jax.lax.scan(step, None, jnp.arange(nprobe * tiles))
+    with jax.named_scope("select"):
+        n = nprobe * cap
+        top, pos = _scan_order_topk(keys, min(r, n))
+        best_s = _ascending_scores(top)
+        lists = jnp.maximum(_pick(probes, pos // cap), 0)
+        best_i = bucket_ids[lists, pos % cap]
+        if r > n:  # fewer slots than the depth: -inf / -1 after them
+            best_s = jnp.pad(best_s, ((0, 0), (0, r - n)),
+                             constant_values=NEG_INF)
+            best_i = jnp.pad(best_i, ((0, 0), (0, r - n)))
     # masked slots keep -inf scores; null their ids so rerank skips them
     return best_s, jnp.where(jnp.isfinite(best_s), best_i, -1)
+
+
+def _scan_order_topk(
+    keys: jax.Array, r: int,
+) -> tuple[jax.Array, jax.Array]:
+    """Top-r of every query's scan-order row of a probe scan's stacked
+    [S, B, T] int32 keys (`_ascending_key`; step s, query b, slot t is
+    position s * T + t of row b): (keys [B, r], positions [B, r]),
+    exactly `lax.top_k` over the row [B, S * T], i.e. the total order
+    (key descending, position ascending).
+
+    Where `perf_model.probe_select_blocked` says so, in the levels of
+    `_blocked_topk` and `_grouped_topk`, kept in scan order so that
+    ties break as `top_k` breaks them: the block maxima are laid out in
+    scan order (blocks of BLOCK lanes are contiguous and disjoint, so
+    of equal maxima `top_k` takes the earlier block, whose best element
+    is the earlier one), and the r chosen blocks and then the r chosen
+    groups are SORTED by index before they are gathered, so every level
+    sees its scores in scan order. A top-r element has a block, and a
+    group, whose best element ranks no lower than itself under that
+    order: fewer than r blocks or groups rank above it, so it survives
+    every level. No sort is wider than `perf_model.probe_select_width`.
+
+    LAYOUT. The loop writes [S, B, T] in tiles of 8 rows x 128 lanes,
+    physically [S, B/8, T/128, 8, 128]; the maxima and the gathers read
+    it through exactly that view (a bitcast), so the matrix is never
+    copied (`_blocked_topk`'s lesson). Elsewhere one `lax.top_k`
+    serves, over the row the matrix is transposed into."""
+    s, b, t = keys.shape
+    n = s * t
+    if not probe_select_blocked(r, n, t):
+        return jax.lax.top_k(keys.transpose(1, 0, 2).reshape(b, n), r)
+    per_step = t // BLOCK
+    sub = 8 if b % 8 == 0 else 1
+    tiles = keys.reshape(s, b // sub, sub, per_step, BLOCK).transpose(
+        0, 1, 3, 2, 4)  # [S, B/sub, per_step, sub, BLOCK]
+    with jax.named_scope("block_max"):
+        bmax = jnp.max(tiles, axis=4).transpose(1, 3, 0, 2).reshape(
+            b, s * per_step)  # block s * per_step + j, in scan order
+    _, blocks = jax.lax.top_k(bmax, r)
+    blocks = jnp.sort(blocks, axis=1)
+    row = jnp.arange(b)[:, None]
+    gathered = tiles[blocks // per_step, row // sub, blocks % per_step,
+                     row % sub, :]  # [B, r, BLOCK]
+    if r >= GROUP_MIN_R and r % GROUP == 0:
+        per = BLOCK // GROUP  # groups of one block
+        gmax = jnp.max(gathered.reshape(b, r, per, GROUP), axis=3)
+        _, groups = jax.lax.top_k(gmax.reshape(b, r * per), r)
+        groups = jnp.sort(groups, axis=1)  # slot * per + g
+        chosen = gathered[row, groups // per]  # [B, r, BLOCK]
+        mine = (groups % per)[:, :, None, None] == jnp.arange(per)[:, None]
+        members = jnp.max(jnp.where(
+            mine, chosen.reshape(b, r, per, GROUP),
+            jnp.iinfo(jnp.int32).min), axis=2)  # [B, r, GROUP]
+        top, pos = jax.lax.top_k(members.reshape(b, r * GROUP), r)
+        group = _pick(groups, pos // GROUP)
+        slot, lane = group // per, group % per * GROUP + pos % GROUP
+    else:
+        top, pos = jax.lax.top_k(gathered.reshape(b, r * BLOCK), r)
+        slot, lane = pos // BLOCK, pos % BLOCK
+    return top, _pick(blocks, slot) * BLOCK + lane
 
 
 @functools.partial(jax.jit, static_argnames=("nprobe", "r", "metric"))

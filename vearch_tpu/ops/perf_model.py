@@ -453,9 +453,36 @@ def select_width(r: int, n_pad: int) -> int:
     nblk = n_pad // BLOCK
     if n_pad % BLOCK or nblk < 4 * max(r, 128):
         return n_pad
+    return _levels_width(r, nblk)
+
+
+def _levels_width(r: int, nblk: int) -> int:
+    """The widest sort of the levels of maxima over nblk blocks at
+    depth r: the block maxima, or what follows the gather."""
     if r >= GROUP_MIN_R and r % GROUP == 0:
         return max(nblk, r * BLOCK // GROUP, r * GROUP)
     return max(nblk, r * BLOCK)
+
+
+def probe_select_blocked(r: int, n: int, tile: int) -> bool:
+    """Whether ops/ivf.py _scan_order_topk selects the top-r of a probe
+    scan's n = nprobe * cap scan-order scores a query, written `tile`
+    a step, in levels of maxima: where a step's scores are whole blocks
+    and the first level keeps at most half the blocks. Else one
+    `lax.top_k` over the row."""
+    return tile % BLOCK == 0 and n // BLOCK >= 2 * r
+
+
+def probe_select_width(r: int, n: int, tile: int) -> int:
+    """Scores a query that the widest sort of the probe scan's
+    selection (ops/ivf.py _scan_order_topk) takes at depth r: the row
+    itself, or `select_width`'s levels over the n / BLOCK block maxima.
+    tests/test_chip_compile.py holds the compiled program to it, and the
+    `ivf.probe` span of an IVFFLAT search carries it."""
+    r = min(r, n)
+    if not probe_select_blocked(r, n, tile):
+        return n
+    return _levels_width(r, n // BLOCK)
 
 
 def scan_peak_bytes(b: int, n_pad: int) -> int:
